@@ -43,8 +43,12 @@ def restore_snapshot(snap):
     """A fresh donation-safe copy of a `snapshot_state` result. The copy
     (rather than the snapshot itself) is handed to the replay dispatch so
     the snapshot survives — a replay at a mid-ladder gear can shed again
-    and need yet another restore."""
-    return snapshot_state(snap)
+    and need yet another restore. Traced as `shadow.restore`: the
+    per-leaf device copies sit between two chunks' dispatches."""
+    from shadow_tpu.obs.runtime import span_or_null
+
+    with span_or_null(None, "restore"):
+        return snapshot_state(snap)
 
 
 def _params_digest(params) -> str:

@@ -1609,13 +1609,24 @@ def _pmin(x, axis):
     word — the int64 order exactly."""
     if not axis:
         return x
-    if x.dtype != jnp.int64:
-        return lax.pmin(x, axis)
-    hi = (x >> 32).astype(jnp.int32)
-    lo = (x & 0xFFFFFFFF).astype(jnp.uint32)
-    g_hi = lax.pmin(hi, axis)
-    g_lo = lax.pmin(jnp.where(hi == g_hi, lo, jnp.uint32(0xFFFFFFFF)), axis)
-    return (g_hi.astype(jnp.int64) << 32) | g_lo.astype(jnp.int64)
+    with jax.named_scope("shadow_collective"):
+        if x.dtype != jnp.int64:
+            return lax.pmin(x, axis)
+        hi = (x >> 32).astype(jnp.int32)
+        lo = (x & 0xFFFFFFFF).astype(jnp.uint32)
+        g_hi = lax.pmin(hi, axis)
+        g_lo = lax.pmin(
+            jnp.where(hi == g_hi, lo, jnp.uint32(0xFFFFFFFF)), axis
+        )
+        return (g_hi.astype(jnp.int64) << 32) | g_lo.astype(jnp.int64)
+
+
+def _psum(x, axis):
+    """Sum over the mesh; `x` itself off the mesh."""
+    if not axis:
+        return x
+    with jax.named_scope("shadow_collective"):
+        return lax.psum(x, axis)
 
 
 def _run_chunk(cfg: EngineConfig, model, axis, state: SimState, params: EngineParams):
@@ -1689,7 +1700,8 @@ def _run_guarded_chunk(
             # the probe sees only the LOCAL shard's model state; the loop
             # decision must be global or shards exit at different rounds and
             # the survivors deadlock in the next round's collectives
-            probe = lax.pmax(probe.astype(jnp.int32), axis) > 0
+            with jax.named_scope("shadow_collective"):
+                probe = lax.pmax(probe.astype(jnp.int32), axis) > 0
         ok = (
             (~stc.done)
             & (i < cfg.effective_rounds_per_chunk)
@@ -1732,7 +1744,10 @@ def _compute_window(cfg: EngineConfig, axis, st: SimState, faults=None):
 
 
 def _round_step(cfg: EngineConfig, model, axis, st: SimState, params: EngineParams):
-    window_end, done = _compute_window(cfg, axis, st, _hold_faults(cfg, params))
+    with jax.named_scope("shadow_window"):
+        window_end, done = _compute_window(
+            cfg, axis, st, _hold_faults(cfg, params)
+        )
     return _window_step(cfg, model, axis, st, params, window_end, done)
 
 
@@ -1743,7 +1758,10 @@ def _round_step_capture(
     sent this round, for host-side pcap synthesis (the modeled-sim analogue
     of the reference's per-interface capture, network_interface.c). One
     dispatch per round: capture runs trade throughput for observability."""
-    window_end, done = _compute_window(cfg, axis, st, _hold_faults(cfg, params))
+    with jax.named_scope("shadow_window"):
+        window_end, done = _compute_window(
+            cfg, axis, st, _hold_faults(cfg, params)
+        )
     return _window_step(
         cfg, model, axis, st, params, window_end, done, capture=True
     )
@@ -1904,7 +1922,7 @@ def _window_step(
             + stats.a2a_shed[0]
             + stats.ob_dropped[0]
         )
-        total = lax.psum(local, axis) if axis else local
+        total = _psum(local, axis)
         stats = stats._replace(pressure=total[None])
     if cfg.integrity:
         # integrity sentinel (core/integrity.py): evaluate the per-round
@@ -1917,7 +1935,7 @@ def _window_step(
         iv_viol, iv_m = _integrity_round_check(
             cfg, axis, st, st_m, st_x, stats, window_end, done, ob_hwm
         )
-        iv_total = lax.psum(iv_viol, axis) if axis else iv_viol
+        iv_total = _psum(iv_viol, axis)
         stats = stats._replace(
             integrity=stats.integrity + iv_total[None],
             iv_mask=stats.iv_mask | iv_m[None],
@@ -1945,7 +1963,9 @@ def _window_step(
             fl_bg_bytes=stats.fl_bg_bytes + bg_dlv,
             fl_bg_dropped=stats.fl_bg_dropped + bg_drp,
         )
-    min_used = _pmin(st_x.min_used_lat, axis)
+    with jax.named_scope("shadow_window"):
+        # the next window's lookahead: the barrier's other all-reduce
+        min_used = _pmin(st_x.min_used_lat, axis)
     out = st_x._replace(
         now=jnp.where(done, st.now, window_end),
         done=done,
@@ -2227,7 +2247,7 @@ def _fluid_fg_link_bytes(cfg: EngineConfig, axis, ob: Outbox,
         size.reshape(-1)
     )
     tot = up + down
-    return lax.psum(tot, axis) if axis else tot
+    return _psum(tot, axis)
 
 
 def _hold_faults(cfg: EngineConfig, params: EngineParams):
@@ -2376,7 +2396,8 @@ def _event_body(cfg, model, c: _EvCarry, params, host_gid, window_end, ev,
         params=params.model,
         rng=c.rng,
     )
-    out = model.handle(ctx)
+    with jax.named_scope("shadow_handler"):
+        out = model.handle(ctx)
     rng, model_state = out.rng, out.state
     seq = c.seq
     sent_round = c.sent_round
@@ -3185,7 +3206,7 @@ def _gear_sliced_outbox(cfg, axis, ob: Outbox, sent_round):
 
     gc = cfg.gear_cols
     local = gear_shed_count(sent_round, gc)
-    shed = lax.psum(local, axis) if axis else local
+    shed = _psum(local, axis)
     sliced = Outbox(
         dst=ob.dst[:, :gc],
         t=ob.t[:, :gc],
@@ -3205,10 +3226,11 @@ def _exchange(cfg, axis, st: SimState):
     ob_full = st.outbox
     ob, gear_shed = _gear_sliced_outbox(cfg, axis, ob_full, st.sent_round)
     if axis:
-        g = jax.tree.map(
-            lambda a: lax.all_gather(a, axis, tiled=True),
-            Outbox(ob.dst, ob.t, ob.order, ob.kind, ob.payload, ob.count),
-        )
+        with jax.named_scope("shadow_collective"):
+            g = jax.tree.map(
+                lambda a: lax.all_gather(a, axis, tiled=True),
+                Outbox(ob.dst, ob.t, ob.order, ob.kind, ob.payload, ob.count),
+            )
     else:
         g = ob
     h_local = st.queue.t.shape[0]
@@ -3387,7 +3409,8 @@ def _exchange_alltoall(cfg, axis, st: SimState):
 
     blocks, _seg_len, shed = _dshard_pack_blocks(ob, h_local, world, k)
 
-    recv = lax.all_to_all(blocks, axis, split_axis=0, concat_axis=0)
+    with jax.named_scope("shadow_collective"):
+        recv = lax.all_to_all(blocks, axis, split_axis=0, concat_axis=0)
     flat_rows = recv.reshape(world * k, -1)
     r_dst = flat_rows[:, 0]
     r_t, r_order, r_kind, r_payload = _unpack_words_rows(
@@ -3397,7 +3420,7 @@ def _exchange_alltoall(cfg, axis, st: SimState):
     r_valid = (r_t != TIME_MAX) & (local >= 0) & (local < h_local)
     flat = (local, r_t, r_order, r_kind, r_payload, r_valid)
 
-    has_sends = lax.psum(jnp.sum(ob.count), axis) > 0
+    has_sends = _psum(jnp.sum(ob.count), axis) > 0
     with jax.named_scope("shadow_merge"):
         queue = _merge_into_queue(cfg, st.queue, flat, has_sends)
     stats = st.stats._replace(
@@ -3513,7 +3536,9 @@ def _exchange_hierarchical(cfg, axis, st: SimState):
         blocks, seg_len, shed = _dshard_pack_blocks(ob, h_local, world, k)
     sent_counts = jnp.minimum(seg_len, k).astype(jnp.int32)
 
-    with jax.named_scope("shadow_hier_inter"):
+    with jax.named_scope("shadow_hier_inter"), jax.named_scope(
+        "shadow_collective"
+    ):
         recv_counts = lax.all_to_all(
             sent_counts, axis, split_axis=0, concat_axis=0
         )
@@ -3529,7 +3554,7 @@ def _exchange_hierarchical(cfg, axis, st: SimState):
     r_valid = by_count & (r_t != TIME_MAX) & (local >= 0) & (local < h_local)
     flat = (local, r_t, r_order, r_kind, r_payload, r_valid)
 
-    has_sends = lax.psum(jnp.sum(ob.count), axis) > 0
+    has_sends = _psum(jnp.sum(ob.count), axis) > 0
     with jax.named_scope("shadow_merge"):
         queue = _merge_into_queue(cfg, st.queue, flat, has_sends)
 
@@ -3543,7 +3568,7 @@ def _exchange_hierarchical(cfg, axis, st: SimState):
         # geared block overflow rides the gear-abort path, not a2a_shed:
         # the driver replays one gear up, whose wider k re-derives the
         # block — the exact-escape contract the docstring argues
-        shed_g = lax.psum(shed, axis)
+        shed_g = _psum(shed, axis)
         stats = stats._replace(
             gear_shed=stats.gear_shed + (gear_shed + shed_g)[None]
         )

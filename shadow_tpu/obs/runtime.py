@@ -50,8 +50,10 @@ attribution verdict; `tools/bench_compare.py` diffs the bench rows'
 from __future__ import annotations
 
 import time
-from contextlib import contextmanager, nullcontext
+from contextlib import contextmanager
 from typing import Any
+
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 
 # WallLedger span names. `host_python` is the residual: whatever part of
 # a chunk's wall no explicit span covered (heartbeats, counter reads,
@@ -247,9 +249,32 @@ class CompileLedger:
 
 
 def span_or_null(wall: "WallLedger | None", name: str):
-    """`with span_or_null(wall, "dispatch"):` — nullcontext when the
-    observatory is off, so driver loops carry one code path."""
-    return wall.span(name) if wall is not None else nullcontext()
+    """`with span_or_null(wall, "dispatch"):` — always a profiler
+    annotation `shadow.<name>`, and the ledger's span too when the
+    observatory is on and `name` is one of SPAN_NAMES (any other span's
+    seconds stay in the ledger's host_python residual), so driver loops
+    carry one code path.
+
+    The annotation lands on the host plane of a running profiler session
+    (`observability.profile_dir`, or any `jax.profiler.start_trace`), on
+    the clock of the device ops, so a trace names the host work behind
+    every gap between dispatches. With no session it costs ~1 us."""
+    ann = TraceAnnotation(f"shadow.{name}")
+    if wall is None or name not in SPAN_NAMES:
+        return ann
+    return _both(ann, wall.span(name))
+
+
+@contextmanager
+def _both(outer, inner):
+    with outer, inner:
+        yield
+
+
+def chunk_span(step: int):
+    """`shadow.chunk`, one iteration of a driver loop, as a profiler step
+    (`step_num` = the chunk's index)."""
+    return StepTraceAnnotation("shadow.chunk", step_num=step)
 
 
 class WallLedger:

@@ -290,14 +290,15 @@ def merge_empty_plan(q_t, p_words: int):
 def merge_apply(q: EventQueue, take, g, dropped_add) -> EventQueue:
     """Write a `merge_plan` into the queue (one masked slab pass)."""
     p_words = q.payload.shape[2]
-    g_t, g_order, g_kind, g_payload = _unpack_words(g, p_words)
-    return EventQueue(
-        t=jnp.where(take, g_t, q.t),
-        order=jnp.where(take, g_order, q.order),
-        kind=jnp.where(take, g_kind, q.kind),
-        payload=jnp.where(take[:, :, None], g_payload, q.payload),
-        dropped=q.dropped + dropped_add,
-    )
+    with jax.named_scope("shadow_merge_write"):
+        g_t, g_order, g_kind, g_payload = _unpack_words(g, p_words)
+        return EventQueue(
+            t=jnp.where(take, g_t, q.t),
+            order=jnp.where(take, g_order, q.order),
+            kind=jnp.where(take, g_kind, q.kind),
+            payload=jnp.where(take[:, :, None], g_payload, q.payload),
+            dropped=q.dropped + dropped_add,
+        )
 
 
 def merge_flat_events(
@@ -555,53 +556,61 @@ def _merge_gather_plan(
     num_hosts, cap = q_t.shape
     n = dst.shape[0]
     r_cap = min(max_inserts, cap)
-    dst_key = jnp.where(valid, dst.astype(jnp.int32), jnp.int32(num_hosts))
-    iota = jnp.arange(n, dtype=jnp.int32)
+    # steps 1-3 and step 4 carry scopes of their own, so that a device
+    # trace splits the merge between ordering rows and writing slabs
+    with jax.named_scope("shadow_merge_order"):
+        dst_key = jnp.where(valid, dst.astype(jnp.int32), jnp.int32(num_hosts))
+        iota = jnp.arange(n, dtype=jnp.int32)
 
-    # -- 1. group rows by destination: ONE single-key stable sort (rows of a
-    # destination keep their source order; invalid rows sort last). The
-    # urgency order inside a destination is a per-host row sort below, not
-    # a (dst, t, order) key of this sort: the TPU compiler's cost for a
-    # sort grows steeply with its key words, and that 5-word key at the
-    # flagship's 250k rows did not compile in 10 minutes.
-    s_dst, s_idx = lax.sort((dst_key, iota), num_keys=1, is_stable=True)
+        # -- 1. group rows by destination: ONE single-key stable sort (rows of
+        # a destination keep their source order; invalid rows sort last). The
+        # urgency order inside a destination is a per-host row sort below, not
+        # a (dst, t, order) key of this sort: the TPU compiler's cost for a
+        # sort grows steeply with its key words, and that 5-word key at the
+        # flagship's 250k rows did not compile in 10 minutes.
+        s_dst, s_idx = lax.sort((dst_key, iota), num_keys=1, is_stable=True)
 
-    # -- 2. segment bounds: host h's rows are s_idx[start[h]:start[h + 1]]
-    start = jnp.searchsorted(
-        s_dst, jnp.arange(num_hosts + 1, dtype=jnp.int32), side="left"
-    ).astype(jnp.int32)
-    seg_len = start[1:] - start[:-1]  # i32[H]
+        # -- 2. segment bounds: host h's rows are s_idx[start[h]:start[h + 1]]
+        start = jnp.searchsorted(
+            s_dst, jnp.arange(num_hosts + 1, dtype=jnp.int32), side="left"
+        ).astype(jnp.int32)
+        seg_len = start[1:] - start[:-1]  # i32[H]
 
-    # -- 3. host h takes its n_ins most urgent rows. `merge_rows` truncates
-    # the (dst, t, order)-sorted layout in which one token per host leads
-    # its segment, so host h's first row sits at position start[h] + h + 1
-    # there (the scatter path mirrors the same cut).
-    m = n + num_hosts + 1
-    k = m if merge_rows <= 0 else min(merge_rows, m)
-    first = start[:-1] + jnp.arange(num_hosts, dtype=jnp.int32)
-    n_ins = jnp.minimum(
-        jnp.minimum(seg_len, r_cap), jnp.maximum(k - 1 - first, 0)
-    )  # i32[H]
-    if shed_urgency:
-        sel = _segment_urgency_rows(s_idx, start[:-1], seg_len, t, order, r_cap)
-    else:
-        rr = start[:-1, None] + jnp.arange(r_cap, dtype=jnp.int32)[None, :]
-        sel = s_idx[jnp.minimum(rr, n - 1)]  # [H, r]; rows past n_ins unused
+        # -- 3. host h takes its n_ins most urgent rows. `merge_rows` truncates
+        # the (dst, t, order)-sorted layout in which one token per host leads
+        # its segment, so host h's first row sits at position start[h] + h + 1
+        # there (the scatter path mirrors the same cut).
+        m = n + num_hosts + 1
+        k = m if merge_rows <= 0 else min(merge_rows, m)
+        first = start[:-1] + jnp.arange(num_hosts, dtype=jnp.int32)
+        n_ins = jnp.minimum(
+            jnp.minimum(seg_len, r_cap), jnp.maximum(k - 1 - first, 0)
+        )  # i32[H]
+        if shed_urgency:
+            sel = _segment_urgency_rows(
+                s_idx, start[:-1], seg_len, t, order, r_cap
+            )
+        else:
+            rr = start[:-1, None] + jnp.arange(r_cap, dtype=jnp.int32)[None, :]
+            # [H, r]; rows past n_ins unused
+            sel = s_idx[jnp.minimum(rr, n - 1)]
 
-    # -- 4. the r-th free slot of host h takes row sel[h, r]: a one-hot
-    # select over r (per-row dynamic gathers lower to slow custom kernels
-    # on TPU), then ONE row gather of all event fields bit-packed into an
-    # [N, W] i32 matrix
-    free = q_t == TIME_MAX  # [H, C]
-    free_rank = jnp.cumsum(free.astype(jnp.int32), axis=1) - 1  # [H, C]
-    take = free & (free_rank < n_ins[:, None])
-    oh = free_rank[:, :, None] == jnp.arange(r_cap, dtype=jnp.int32)
-    row = jnp.sum(jnp.where(oh, sel[:, None, :], 0), axis=2)
-    row = jnp.where(take, row, 0)
-    words = _pack_words(t, order, kind.astype(jnp.int32), payload)
-    g = words[row]  # [H, C, W]
+    with jax.named_scope("shadow_merge_write"):
+        # -- 4. the r-th free slot of host h takes row sel[h, r]: a one-hot
+        # select over r (per-row dynamic gathers lower to slow custom kernels
+        # on TPU), then ONE row gather of all event fields bit-packed into an
+        # [N, W] i32 matrix
+        free = q_t == TIME_MAX  # [H, C]
+        free_rank = jnp.cumsum(free.astype(jnp.int32), axis=1) - 1  # [H, C]
+        take = free & (free_rank < n_ins[:, None])
+        oh = free_rank[:, :, None] == jnp.arange(r_cap, dtype=jnp.int32)
+        row = jnp.sum(jnp.where(oh, sel[:, None, :], 0), axis=2)
+        row = jnp.where(take, row, 0)
+        words = _pack_words(t, order, kind.astype(jnp.int32), payload)
+        g = words[row]  # [H, C, W]
 
-    # -- overflow accounting (elementwise: order-independent, deterministic)
-    inserted = jnp.sum(take.astype(jnp.int32), axis=1)
-    dropped_add = (seg_len - inserted).astype(jnp.int64)
+        # -- overflow accounting (elementwise: order-independent,
+        # deterministic)
+        inserted = jnp.sum(take.astype(jnp.int32), axis=1)
+        dropped_add = (seg_len - inserted).astype(jnp.int64)
     return take, g, dropped_add

@@ -800,7 +800,7 @@ class Simulation:
         # snapshot / replay / compile, residual = host_python) plus a
         # per-chunk realtime-factor series feeding the `rt=` heartbeat
         # field. Host-side observer only.
-        from shadow_tpu.obs.runtime import span_or_null
+        from shadow_tpu.obs.runtime import chunk_span, span_or_null
 
         wallled = None
         if cfg.observability.runtime:
@@ -979,199 +979,228 @@ class Simulation:
         from shadow_tpu.core.integrity import IntegrityAbort
         from shadow_tpu.core.pressure import PressureAbort
 
-        try:
-            while not bool(self.state.done):
-                t_chunk = time.monotonic()
-                if wallled is not None:
-                    wallled.chunk_start()
-                if capture is not None:
-                    with span_or_null(wallled, "dispatch"):
-                        self.state, sent = capture.step(
-                            self.state, self.params
-                        )
-                        if wallled is not None:
-                            jax.block_until_ready(self.state)
-                    with span_or_null(wallled, "export"):
-                        capture.write_round(sent)
-                elif sup is not None:
-                    from shadow_tpu.core.supervisor import SupervisorAbort
+        def _heartbeat(now_ns, wall):
+            # the stat reads are device-to-host copies made between two
+            # dispatches (traced as `shadow.heartbeat`)
+            ev = int(np.asarray(self.state.stats.events).sum())
+            # event-density telemetry (the K-way microstep's target
+            # quantities): microsteps per round is how serialized the
+            # round loop is, events per microstep is how well the
+            # K-fold amortizes — the same two numbers bench.py tracks
+            msteps = int(np.asarray(self.state.stats.microsteps).sum())
+            rounds = int(self.state.stats.rounds)
+            ici = int(np.asarray(self.state.stats.ici_bytes).sum())
+            qhwm = int(np.asarray(self.state.stats.q_occ_hwm).max())
+            # faults= rides along only when the fault plane is
+            # active, gear= only on adaptive runs (old-format
+            # lines stay byte-identical; parse_shadow reads both)
+            # xw= rides along only on hierarchical-exchange runs:
+            # cumulative (intra compaction, inter wire) tier bytes
+            xw = None
+            if self.engine_cfg.hier_active:
+                xw = (
+                    int(np.asarray(self.state.stats.ici_intra).sum()),
+                    int(np.asarray(self.state.stats.ici_inter).sum()),
+                )
+            fault = None
+            if self.engine_cfg.faults_active:
+                fd = int(np.asarray(self.state.stats.faults_dropped).sum())
+                fy = int(np.asarray(self.state.stats.faults_delayed).sum())
+                fault = (fd, fy)
+            # cap= rides along only on pressure-plane runs (the
+            # ACTIVE capacity — escalation regrows it mid-run)
+            cap = (
+                self.state.queue.t.shape[1]
+                if pressure_on else None
+            )
+            # hbm= rides along only on memory-observatory runs:
+            # the per-shard HBM high-water so far (bytes)
+            hbm = (
+                monitor.hwm_bytes() if monitor is not None else None
+            )
+            # ek= (timer/packet event counts) and fct= (flows
+            # completed) ride along only on network-observatory
+            # runs (fct only when a flow ledger is active)
+            ek = fct = None
+            if self.engine_cfg.netobs:
+                ek = (
+                    int(np.asarray(self.state.stats.ec_timer).sum()),
+                    int(np.asarray(self.state.stats.ec_pkt).sum()),
+                )
+                if self.engine_cfg.flow_ledger_active:
+                    fct = int(
+                        np.asarray(self.state.stats.fl_done).sum()
+                    )
+            # bg= rides along only on fluid-traffic-plane runs:
+            # cumulative background bytes delivered/dropped
+            # (replicated scalars — read, never summed)
+            bg = None
+            if self.engine_cfg.fluid_active:
+                bg = (
+                    int(np.asarray(self.state.stats.fl_bg_bytes)),
+                    int(np.asarray(
+                        self.state.stats.fl_bg_dropped
+                    )),
+                )
+            # iv= rides along only on integrity-sentinel runs:
+            # (transient SDC survived, sentinel replays) so far
+            iv = (
+                (resilience.iv_transients, resilience.iv_replays)
+                if integrity_on and resilience is not None else None
+            )
+            # rt= rides along only on runtime-observatory runs:
+            # the LAST chunk's realtime factor (sim-s/wall-s)
+            rt = (
+                wallled.rt_last if wallled is not None else None
+            )
+            print(
+                heartbeat_line(
+                    now_ns, wall, ev, msteps, rounds, ici, qhwm,
+                    xw=xw, fault=fault, gear=last_gear, cap=cap,
+                    hbm=hbm, ek=ek, fct=fct, bg=bg, iv=iv, rt=rt,
+                ),
+                file=log,
+            )
+            if simlog is not None:
+                simlog.info(
+                    now_ns, "manager",
+                    f"heartbeat events={ev} "
+                    f"rounds={int(self.state.stats.rounds)}",
+                )
 
-                    try:
-                        with span_or_null(wallled, "dispatch"):
-                            self.state = sup.run_chunk(
-                                self.state, _chunk_step
+        try:
+            with span_or_null(wallled, "run"):
+                while True:
+                    with chunk_span(chunks):
+                        with span_or_null(wallled, "sync"):
+                            done = bool(self.state.done)
+                        if done:
+                            break
+                        t_chunk = time.monotonic()
+                        if wallled is not None:
+                            wallled.chunk_start()
+                        if capture is not None:
+                            with span_or_null(wallled, "dispatch"):
+                                self.state, sent = capture.step(
+                                    self.state, self.params
+                                )
+                                if wallled is not None:
+                                    jax.block_until_ready(self.state)
+                            with span_or_null(wallled, "export"):
+                                capture.write_round(sent)
+                        elif sup is not None:
+                            from shadow_tpu.core.supervisor import (
+                                SupervisorAbort,
                             )
-                    except IntegrityAbort as e:
-                        _policy_abort(e, t_chunk, kind="integrity")
-                        break
-                    except PressureAbort as e:
-                        _policy_abort(e, t_chunk)
-                        break
-                    except SupervisorAbort as e:
-                        # graceful abort: export the completed prefix from
-                        # the supervisor's snapshot, not the in-hand state
-                        # (abort_export_state docs the poisoned/donation
-                        # rationale)
-                        print(f"[supervisor] aborting run: {e}", file=log)
-                        good = sup.abort_export_state()
-                        if good is not None:
-                            self.state = good
+
+                            try:
+                                with span_or_null(wallled, "dispatch"):
+                                    self.state = sup.run_chunk(
+                                        self.state, _chunk_step
+                                    )
+                            except IntegrityAbort as e:
+                                _policy_abort(e, t_chunk, kind="integrity")
+                                break
+                            except PressureAbort as e:
+                                _policy_abort(e, t_chunk)
+                                break
+                            except SupervisorAbort as e:
+                                # graceful abort: export the completed
+                                # prefix from the supervisor's snapshot,
+                                # not the in-hand state (abort_export_state
+                                # docs the poisoned/donation rationale)
+                                print(
+                                    f"[supervisor] aborting run: {e}",
+                                    file=log,
+                                )
+                                good = sup.abort_export_state()
+                                if good is not None:
+                                    self.state = good
+                                    if tracer is not None:
+                                        # chunks that succeeded AFTER the
+                                        # snapshot were already drained;
+                                        # drop their rows so the trace
+                                        # covers exactly the exported
+                                        # prefix (truncate_to_round docs
+                                        # this)
+                                        tracer.truncate_to_round(
+                                            int(self.state.stats.rounds)
+                                        )
+                                    if flowcol is not None:
+                                        # same contract for flow records:
+                                        # the exported state's ledger
+                                        # cursor is the truth of what its
+                                        # prefix completed — and the
+                                        # trace's flow track re-seats on
+                                        # the kept record set
+                                        flowcol.truncate_to_cursor(
+                                            np.asarray(jax.device_get(
+                                                self.state.flows.cursor
+                                            ))
+                                        )
+                                        if tracer is not None:
+                                            tracer.reset_flows(
+                                                flowcol.records()
+                                            )
+                                self._aborted = True
+                                break
+                        else:
+                            try:
+                                with span_or_null(wallled, "dispatch"):
+                                    self.state = _chunk_step(self.state)
+                                    if wallled is not None:
+                                        # async dispatch: without the
+                                        # block the device time would leak
+                                        # into whichever span syncs first
+                                        jax.block_until_ready(self.state)
+                            except IntegrityAbort as e:
+                                _policy_abort(e, t_chunk, kind="integrity")
+                                break
+                            except PressureAbort as e:
+                                _policy_abort(e, t_chunk)
+                                break
+                        with span_or_null(wallled, "export"):
                             if tracer is not None:
-                                # chunks that succeeded AFTER the snapshot
-                                # were already drained; drop their rows so
-                                # the trace covers exactly the exported
-                                # prefix (truncate_to_round docs this)
-                                tracer.truncate_to_round(
-                                    int(self.state.stats.rounds)
+                                # pair the drained rounds with the true
+                                # wall span of this dispatch (block: async
+                                # dispatch would pin the span to enqueue
+                                # time, not device time)
+                                jax.block_until_ready(self.state)
+                                tracer.drain(
+                                    self.state.trace,
+                                    wall_t0=t_chunk, wall_t1=time.monotonic(),
                                 )
                             if flowcol is not None:
-                                # same contract for flow records: the
-                                # exported state's ledger cursor is the
-                                # truth of what its prefix completed —
-                                # and the trace's flow track re-seats on
-                                # the kept record set
-                                flowcol.truncate_to_cursor(
-                                    np.asarray(jax.device_get(
-                                        self.state.flows.cursor
-                                    ))
+                                jax.block_until_ready(self.state)
+                                _drain_flows()
+                            if monitor is not None:
+                                t_s = time.monotonic()
+                                shard_bytes = monitor.sample(
+                                    modeled_bytes=(
+                                        self._modeled_shard_bytes()
+                                    ),
+                                    wall_t=t_s,
                                 )
                                 if tracer is not None:
-                                    tracer.reset_flows(flowcol.records())
-                        self._aborted = True
-                        break
-                else:
-                    try:
-                        with span_or_null(wallled, "dispatch"):
-                            self.state = _chunk_step(self.state)
-                            if wallled is not None:
-                                # async dispatch: without the block the
-                                # device time would leak into whichever
-                                # span syncs first
-                                jax.block_until_ready(self.state)
-                    except IntegrityAbort as e:
-                        _policy_abort(e, t_chunk, kind="integrity")
-                        break
-                    except PressureAbort as e:
-                        _policy_abort(e, t_chunk)
-                        break
-                with span_or_null(wallled, "export"):
-                    if tracer is not None:
-                        # pair the drained rounds with the true wall span
-                        # of this dispatch (block: async dispatch would
-                        # pin the span to enqueue time, not device time)
-                        jax.block_until_ready(self.state)
-                        tracer.drain(
-                            self.state.trace,
-                            wall_t0=t_chunk, wall_t1=time.monotonic(),
-                        )
-                    if flowcol is not None:
-                        jax.block_until_ready(self.state)
-                        _drain_flows()
-                    if monitor is not None:
-                        t_s = time.monotonic()
-                        shard_bytes = monitor.sample(
-                            modeled_bytes=self._modeled_shard_bytes(),
-                            wall_t=t_s,
-                        )
-                        if tracer is not None:
-                            tracer.note_memory(t_s, shard_bytes)
-                chunks += 1
-                now_ns = int(self.state.now)
-                if wallled is not None:
-                    # close the chunk (heartbeat/progress printing below
-                    # lands in the NEXT chunk's host_python residual)
-                    wallled.chunk_end(now_ns)
-                wall = time.monotonic() - t0
-                if hb_ns and now_ns >= next_hb:
-                    ev = int(np.asarray(self.state.stats.events).sum())
-                    # event-density telemetry (the K-way microstep's target
-                    # quantities): microsteps per round is how serialized the
-                    # round loop is, events per microstep is how well the
-                    # K-fold amortizes — the same two numbers bench.py tracks
-                    msteps = int(np.asarray(self.state.stats.microsteps).sum())
-                    rounds = int(self.state.stats.rounds)
-                    ici = int(np.asarray(self.state.stats.ici_bytes).sum())
-                    qhwm = int(np.asarray(self.state.stats.q_occ_hwm).max())
-                    # faults= rides along only when the fault plane is
-                    # active, gear= only on adaptive runs (old-format
-                    # lines stay byte-identical; parse_shadow reads both)
-                    # xw= rides along only on hierarchical-exchange runs:
-                    # cumulative (intra compaction, inter wire) tier bytes
-                    xw = None
-                    if self.engine_cfg.hier_active:
-                        xw = (
-                            int(np.asarray(self.state.stats.ici_intra).sum()),
-                            int(np.asarray(self.state.stats.ici_inter).sum()),
-                        )
-                    fault = None
-                    if self.engine_cfg.faults_active:
-                        fd = int(np.asarray(self.state.stats.faults_dropped).sum())
-                        fy = int(np.asarray(self.state.stats.faults_delayed).sum())
-                        fault = (fd, fy)
-                    # cap= rides along only on pressure-plane runs (the
-                    # ACTIVE capacity — escalation regrows it mid-run)
-                    cap = (
-                        self.state.queue.t.shape[1]
-                        if pressure_on else None
-                    )
-                    # hbm= rides along only on memory-observatory runs:
-                    # the per-shard HBM high-water so far (bytes)
-                    hbm = (
-                        monitor.hwm_bytes() if monitor is not None else None
-                    )
-                    # ek= (timer/packet event counts) and fct= (flows
-                    # completed) ride along only on network-observatory
-                    # runs (fct only when a flow ledger is active)
-                    ek = fct = None
-                    if self.engine_cfg.netobs:
-                        ek = (
-                            int(np.asarray(self.state.stats.ec_timer).sum()),
-                            int(np.asarray(self.state.stats.ec_pkt).sum()),
-                        )
-                        if self.engine_cfg.flow_ledger_active:
-                            fct = int(
-                                np.asarray(self.state.stats.fl_done).sum()
-                            )
-                    # bg= rides along only on fluid-traffic-plane runs:
-                    # cumulative background bytes delivered/dropped
-                    # (replicated scalars — read, never summed)
-                    bg = None
-                    if self.engine_cfg.fluid_active:
-                        bg = (
-                            int(np.asarray(self.state.stats.fl_bg_bytes)),
-                            int(np.asarray(
-                                self.state.stats.fl_bg_dropped
-                            )),
-                        )
-                    # iv= rides along only on integrity-sentinel runs:
-                    # (transient SDC survived, sentinel replays) so far
-                    iv = (
-                        (resilience.iv_transients, resilience.iv_replays)
-                        if integrity_on and resilience is not None else None
-                    )
-                    # rt= rides along only on runtime-observatory runs:
-                    # the LAST chunk's realtime factor (sim-s/wall-s)
-                    rt = (
-                        wallled.rt_last if wallled is not None else None
-                    )
-                    print(
-                        heartbeat_line(
-                            now_ns, wall, ev, msteps, rounds, ici, qhwm,
-                            xw=xw, fault=fault, gear=last_gear, cap=cap,
-                            hbm=hbm, ek=ek, fct=fct, bg=bg, iv=iv, rt=rt,
-                        ),
-                        file=log,
-                    )
-                    if simlog is not None:
-                        simlog.info(
-                            now_ns, "manager",
-                            f"heartbeat events={ev} "
-                            f"rounds={int(self.state.stats.rounds)}",
-                        )
-                    next_hb = (now_ns // hb_ns + 1) * hb_ns
-                if show_progress:
-                    pct = min(100.0, 100.0 * now_ns / max(cfg.general.stop_time, 1))
-                    print(f"\rprogress: {pct:5.1f}% ", end="", file=log, flush=True)
+                                    tracer.note_memory(t_s, shard_bytes)
+                        chunks += 1
+                        with span_or_null(wallled, "sync"):
+                            now_ns = int(self.state.now)
+                        if wallled is not None:
+                            # close the chunk (heartbeat/progress
+                            # printing below lands in the NEXT chunk's
+                            # host_python residual)
+                            wallled.chunk_end(now_ns)
+                        wall = time.monotonic() - t0
+                        if hb_ns and now_ns >= next_hb:
+                            with span_or_null(wallled, "heartbeat"):
+                                _heartbeat(now_ns, wall)
+                            next_hb = (now_ns // hb_ns + 1) * hb_ns
+                        if show_progress:
+                            pct = min(100.0, 100.0 * now_ns / max(
+                                cfg.general.stop_time, 1))
+                            print(f"\rprogress: {pct:5.1f}% ", end="",
+                                  file=log, flush=True)
         finally:
             if profiling:
                 jax.profiler.stop_trace()
