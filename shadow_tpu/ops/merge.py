@@ -13,13 +13,19 @@ on v5e the original scatter formulation was ~60% of total round cost):
      entries sort to the end). The TPU compiler's time for a sort grows
      steeply with its key words: the earlier (dst, time, order) key — five
      32-bit words once i64 is split — did not compile in 10 minutes at the
-     flagship's 250k rows, where this 1-key sort takes ~18 s (PR 21);
-  2. per-host segment starts via an H-sized searchsorted over the sorted
-     dst column;
+     flagship's 250k rows, where this 1-key sort takes ~18 s. The
+     sort carries each entry's source index and the words of its time and
+     order as one [5, N] operand (the key repeated per row; stable, so
+     every row takes the same permutation): the grouped [N, 5] matrix;
+  2. per-host segment bounds by a blocked search over the sorted dst
+     column (`_segment_starts`): an [H + 1, N / 128] compare-and-sum
+     finds each host's 128-key block, ONE row gather reads it, and a
+     128-wide compare-count finishes the bound. No search loop;
   3. each host's `r_cap` most urgent entries by (time, order) come from a
      batched [H, 2 r_cap] row sort over its segment, chunk by chunk (one
      chunk in a round that sheds nothing), so under overflow pressure the
-     *latest* events are shed, never the most urgent ones;
+     *latest* events are shed, never the most urgent ones. A chunk is ONE
+     row gather of the grouped matrix: index, time and order together;
   4. each host's r-th free slot *gathers* its r-th most urgent entry, all
      event fields bit-packed into one [N, W] i32 row matrix (row gathers
      move contiguous words, amortizing the per-element index cost that
@@ -55,6 +61,19 @@ the record — all three looked faster in isolated microbenches and were not):
   - vmap(dynamic_slice) per-host contiguous blocks: lowers to a
     10k-iteration while LOOP on TPU (~0.45 s per field per chunk);
   - lax.gather with multi-element slice_sizes: same while-loop lowering.
+
+Replaced (measured in a v5e trace of the 10k-host PHOLD cells):
+  - segment bounds by `jnp.searchsorted(s_dst, arange(H + 1))`: its scan
+    method is a while loop of ceil(log2(N + 1)) dependent passes, each an
+    (H + 1)-element gather, ~6.7 ns an element (17-18 x 10,001 elements,
+    1.13-1.20 ms a round);
+  - the window read as an index gather then a row gather, `s_idx[pos]`
+    (~6.6 ns an element, 0.80 ms a round sparse) then `tw[idx]` of 4-word
+    rows (~11.8 ns a row, 1.42 ms): the grouping sort permuted a bare iota;
+  - the words as four more [N] operands of the grouping sort: the same
+    permutation, but the described-v5e compile grows ~16 s per [N] operand
+    (27 s -> 72 s for the 10k-host PHOLD chunk); the [5, N] operand under
+    a repeated key compiles as fast as the two-operand sort.
 """
 
 from __future__ import annotations
@@ -208,10 +227,11 @@ def dshard_segments(dshard, t, order, world: int, k: int):
             lambda: valid,
         )
     key = jnp.where(keep, 2 * dshard, 2 * dshard + 1)
-    s_key, s_idx = lax.sort(
+    _, s_idx = lax.sort(
         (key, jnp.arange(n_loc, dtype=jnp.int32)), num_keys=1, is_stable=True
     )
-    start = jnp.searchsorted(s_key, 2 * gi, side="left").astype(jnp.int32)
+    # every key below 2j belongs to a valid row of a group before j
+    start = jnp.cumsum(seg_len) - seg_len
     return s_idx, start, seg_len
 
 
@@ -504,30 +524,54 @@ def merge_scatter_free(
     return lax.cond(fits, fast, fallback, q)
 
 
-def _segment_urgency_rows(s_idx, start, seg_len, t, order, r: int):
+_BLOCK = 128  # lanes of a TPU vector register
+
+
+def _segment_starts(s_key, n_bounds: int):
+    """i32[n_bounds]: start[q] = #(s_key < q) for an ascending i32 `s_key`,
+    with no search loop (`jnp.searchsorted(s_key, arange(n_bounds))`
+    exactly, side="left").
+
+    The keys, padded with a sentinel no query exceeds, are viewed as
+    [nb, 128] blocks. The blocks whose last key is below q all lie wholly
+    below it; their count j[q] is one [n_bounds, nb] compare-and-sum. The
+    rest of the answer lies in block j[q]: one row gather of it and a
+    128-wide compare-count. When every key is below q, j[q] = nb only
+    without padding, and the clamped last block then counts all 128."""
+    n = s_key.shape[0]
+    nb = max(1, -(-n // _BLOCK))
+    blocks = jnp.pad(
+        s_key, (0, nb * _BLOCK - n), constant_values=jnp.iinfo(jnp.int32).max
+    ).reshape(nb, _BLOCK)
+    q = jnp.arange(n_bounds, dtype=jnp.int32)[:, None]
+    j = jnp.minimum(
+        jnp.sum((blocks[None, :, -1] < q).astype(jnp.int32), axis=1), nb - 1
+    )
+    return j * _BLOCK + jnp.sum((blocks[j] < q).astype(jnp.int32), axis=1)
+
+
+def _segment_urgency_rows(rows, start, seg_len, r: int):
     """[H, r] original row indices of each destination segment's `r` most
     urgent rows in ascending (t, order); -1 pads short segments.
 
-    Segment h is `s_idx[start[h] : start[h] + seg_len[h]]` (rows grouped by
-    destination, source order inside). Each chunk of `r` segment rows is
-    merged into the running top-r by one batched [H, 2r] row sort (one
-    traced sort: the TPU compiler charges per sort instance). A drop-free
-    round has seg_len <= r_cap everywhere, so it runs one iteration; a
-    round that sheds pays one more per extra chunk of its longest
-    segment. Exact: live order keys are unique, so the only ties are
-    between pads."""
+    `rows` is the grouped [N, 5] i32 matrix (source index, then the
+    `_split64` words of t and order); segment h is
+    `rows[start[h] : start[h] + seg_len[h]]` (source order inside). Each
+    chunk of `r` segment rows is read by ONE row gather and merged into
+    the running top-r by one batched [H, 2r] row sort (one traced sort:
+    the TPU compiler charges per sort instance). A drop-free round has
+    seg_len <= r_cap everywhere, so it runs one iteration; a round that
+    sheds pays one more per extra chunk of its longest segment. Exact:
+    live order keys are unique, so the only ties are between pads."""
     cols = jnp.arange(r, dtype=jnp.int32)
-    # t and order travel together as one 4-word row gather
-    tw = jnp.concatenate([_split64(t), _split64(order)], axis=1)
 
     def chunk(c):
         off = c * r + cols[None, :]
         inside = off < seg_len[:, None]
-        pos = jnp.where(inside, start[:, None] + off, 0)
-        idx = jnp.where(inside, s_idx[pos], -1)
-        w = tw[jnp.maximum(idx, 0)]  # [H, r, 4]
-        ct = jnp.where(inside, _join64(w[..., 0:2]), TIME_MAX)
-        co = jnp.where(inside, _join64(w[..., 2:4]), ORDER_MAX)
+        w = rows[jnp.where(inside, start[:, None] + off, 0)]  # [H, r, 5]
+        idx = jnp.where(inside, w[..., 0], -1)
+        ct = jnp.where(inside, _join64(w[..., 1:3]), TIME_MAX)
+        co = jnp.where(inside, _join64(w[..., 3:5]), ORDER_MAX)
         return ct, co, idx
 
     def top(ct, co, ci):
@@ -567,33 +611,43 @@ def _merge_gather_plan(
         # urgency order inside a destination is a per-host row sort below, not
         # a (dst, t, order) key of this sort: the TPU compiler's cost for a
         # sort grows steeply with its key words, and that 5-word key at the
-        # flagship's 250k rows did not compile in 10 minutes.
-        s_dst, s_idx = lax.sort((dst_key, iota), num_keys=1, is_stable=True)
+        # flagship's 250k rows did not compile in 10 minutes. The source
+        # index and the words of t and order are sorted with it, so that a
+        # host's window is one row gather of the grouped [N, 5] matrix: as
+        # ONE [5, N] operand under the key repeated per row (a stable sort
+        # gives every row the same permutation): the compiler's time grows
+        # with every extra [N] operand, not with the rows of one.
+        carried = jnp.concatenate(
+            [iota[None], _split64(t).T, _split64(order).T], axis=0
+        )
+        s_key, s_carried = lax.sort(
+            (jnp.broadcast_to(dst_key, carried.shape), carried),
+            dimension=1, num_keys=1, is_stable=True,
+        )
+        s_dst, rows = s_key[0], s_carried.T  # i32[N], i32[N, 5]
 
-        # -- 2. segment bounds: host h's rows are s_idx[start[h]:start[h + 1]]
-        start = jnp.searchsorted(
-            s_dst, jnp.arange(num_hosts + 1, dtype=jnp.int32), side="left"
-        ).astype(jnp.int32)
-        seg_len = start[1:] - start[:-1]  # i32[H]
+        # -- 2. segment bounds: host h's rows are rows[start[h]:start[h + 1]]
+        with jax.named_scope("shadow_merge_bounds"):
+            start = _segment_starts(s_dst, num_hosts + 1)
+            seg_len = start[1:] - start[:-1]  # i32[H]
 
         # -- 3. host h takes its n_ins most urgent rows. `merge_rows` truncates
         # the (dst, t, order)-sorted layout in which one token per host leads
         # its segment, so host h's first row sits at position start[h] + h + 1
         # there (the scatter path mirrors the same cut).
-        m = n + num_hosts + 1
-        k = m if merge_rows <= 0 else min(merge_rows, m)
-        first = start[:-1] + jnp.arange(num_hosts, dtype=jnp.int32)
-        n_ins = jnp.minimum(
-            jnp.minimum(seg_len, r_cap), jnp.maximum(k - 1 - first, 0)
-        )  # i32[H]
-        if shed_urgency:
-            sel = _segment_urgency_rows(
-                s_idx, start[:-1], seg_len, t, order, r_cap
-            )
-        else:
-            rr = start[:-1, None] + jnp.arange(r_cap, dtype=jnp.int32)[None, :]
-            # [H, r]; rows past n_ins unused
-            sel = s_idx[jnp.minimum(rr, n - 1)]
+        with jax.named_scope("shadow_merge_window"):
+            m = n + num_hosts + 1
+            k = m if merge_rows <= 0 else min(merge_rows, m)
+            first = start[:-1] + jnp.arange(num_hosts, dtype=jnp.int32)
+            n_ins = jnp.minimum(
+                jnp.minimum(seg_len, r_cap), jnp.maximum(k - 1 - first, 0)
+            )  # i32[H]
+            if shed_urgency:
+                sel = _segment_urgency_rows(rows, start[:-1], seg_len, r_cap)
+            else:
+                rr = start[:-1, None] + jnp.arange(r_cap, dtype=jnp.int32)
+                # [H, r]; rows past n_ins unused
+                sel = rows[jnp.minimum(rr, n - 1), 0]
 
     with jax.named_scope("shadow_merge_write"):
         # -- 4. the r-th free slot of host h takes row sel[h, r]: a one-hot

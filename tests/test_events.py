@@ -3,6 +3,7 @@ src/main/core/work/event_queue.rs tests + event.rs ordering tests)."""
 
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from shadow_tpu.ops import (
     make_queue,
@@ -312,3 +313,75 @@ def test_dshard_segments_block_takes_most_urgent_rows():
             want = rows[np.lexsort((order[rows], t[rows]))][:k]
             got = s_idx[start[g] : start[g] + min(rows.size, k)]
             assert sorted(got) == sorted(want), (trial, g)
+
+
+def _sorted_keys(case):
+    """Ascending destination keys (invalid rows = H) of a bounds case."""
+    rng = np.random.default_rng(31)
+    if case == "hosts_without_rows":  # H=50, only every 7th host holds rows
+        return 50, np.sort(rng.choice(np.arange(0, 50, 7), 300))
+    if case == "all_rows_invalid":
+        return 20, np.full(260, 20)
+    if case == "rows_below_one_block":  # N < 128, not a multiple of 128
+        return 9, np.sort(rng.integers(0, 10, 77))
+    if case == "one_host_holds_every_row":
+        return 12, np.full(384, 5)
+    if case == "every_row_below_a_whole_block":  # N = 2 x 128, no pad
+        return 3, np.sort(rng.integers(0, 3, 256))
+    # segments of 90-170 rows, so most start or end across a block edge
+    lens = rng.integers(90, 170, 16)
+    return 16, np.repeat(np.arange(16), lens)
+
+
+@pytest.mark.parametrize("case", [
+    "hosts_without_rows", "all_rows_invalid", "rows_below_one_block",
+    "one_host_holds_every_row", "every_row_below_a_whole_block",
+    "segments_cross_block_edges",
+])
+def test_segment_starts_equal_searchsorted(case):
+    """The blocked segment bounds are `searchsorted(side="left")` exactly."""
+    from shadow_tpu.ops.merge import _segment_starts
+
+    hh, keys = _sorted_keys(case)
+    got = np.asarray(_segment_starts(jnp.asarray(keys, jnp.int32), hh + 1))
+    want = np.searchsorted(keys, np.arange(hh + 1), side="left")
+    assert np.array_equal(got, want), case
+
+
+@pytest.mark.parametrize("shed", [True, False])
+@pytest.mark.parametrize("merge_rows", [0, 40])
+def test_merge_gather_sheds_long_segment_like_scatter(shed, merge_rows):
+    """A host whose segment spans several r_cap chunks (the urgency loop
+    runs more than once) sheds exactly as the scatter path does."""
+    rng = np.random.default_rng(41)
+    hh, cc, n = 5, 4, 60
+    q = make_queue(hh, cc)
+    occ = rng.random((hh, cc)) < 0.3
+    q = q._replace(
+        t=jnp.asarray(np.where(occ, rng.integers(1, 900, (hh, cc)), np.asarray(q.t))),
+        order=jnp.asarray(np.where(occ, rng.integers(0, 1 << 40, (hh, cc)), np.asarray(q.order))),
+    )
+    # host 2 receives every second row, over 7 x r_cap; the rest spread
+    dst = np.where(np.arange(n) % 2 == 0, 2, rng.integers(0, hh, n))
+    assert (dst == 2).sum() > 2 * cc
+    # three distinct times: the shed cut falls among equal times, where
+    # the order key decides
+    t = jnp.asarray(rng.integers(1, 4, n), jnp.int64)
+    order = jnp.asarray(
+        [int(pack_order(0, int(rng.integers(0, hh)), 300 + i)) for i in range(n)],
+        jnp.int64,
+    )
+    kind = jnp.asarray(rng.integers(0, 5, n), jnp.int32)
+    payload = jnp.asarray(rng.integers(0, 100, (n, EVENT_PAYLOAD_WORDS)), jnp.int32)
+    valid = jnp.asarray(rng.random(n) < 0.9)
+    a, b = (
+        merge_flat_events(
+            q, jnp.asarray(dst, jnp.int32), t, order, kind, payload, valid,
+            max_inserts=cc, shed_urgency=shed, force_path=path,
+            merge_rows=merge_rows,
+        )
+        for path in ("gather", "scatter")
+    )
+    assert int(np.asarray(a.dropped).sum()) > 0
+    for fa, fb, name in zip(a, b, a._fields):
+        assert np.array_equal(np.asarray(fa), np.asarray(fb)), name

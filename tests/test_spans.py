@@ -55,8 +55,8 @@ def _tpu_chunk_hlo(monkeypatch, sim) -> str:
 
 def test_chunk_hlo_carries_round_scopes(monkeypatch):
     names = _op_names(_tpu_chunk_hlo(monkeypatch, _sim(_phold())))
-    for scope in ("shadow_window", "shadow_merge_order", "shadow_merge_write",
-                  "shadow_handler"):
+    for scope in ("shadow_window", "shadow_merge_order", "shadow_merge_bounds",
+                  "shadow_merge_window", "shadow_merge_write", "shadow_handler"):
         assert any(scope in n.split("/") for n in names), scope
     # the merge's halves sit inside the existing markers, the handler
     # inside the microstep loop
@@ -69,6 +69,8 @@ def test_chunk_hlo_carries_round_scopes(monkeypatch):
     assert nested("shadow_exchange", "shadow_merge")
     assert nested("shadow_merge", "shadow_merge_order")
     assert nested("shadow_merge", "shadow_merge_write")
+    assert nested("shadow_merge_order", "shadow_merge_bounds")
+    assert nested("shadow_merge_order", "shadow_merge_window")
     assert nested("shadow_microsteps", "shadow_handler")
     # one chip: no collective
     assert not any("shadow_collective" in n for n in names)
