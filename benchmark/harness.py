@@ -8,6 +8,14 @@ metric is a file of its own, found by the name `BENCHMARK.json` gives:
     benchmark/reference/<model>.py      the plain reference of the model
     benchmark/metrics/<metric>.py       a reader: read(ctx) -> number | None
 
+A reader is handed a `TraceContext`: the trace's `Reduction` (scopes,
+every `shadow_*` marker, every `shadow.*` host span, busy time), the
+traced window's seconds, segments, rounds and microsteps, each traced
+segment's full report from `Simulation.run` (`model_report` included),
+the resolved `EngineConfig` the chunk program was compiled with, and the
+device kind's row of `peaks.json`. A `per_layer` entry with a `workloads`
+list is read in those cells only.
+
 The timed entry is a replayed segment: set-up builds one `Simulation`,
 keeps a device copy of its initial state, and runs one whole segment to
 compile and warm the one chunk program. The window then restores a fresh
@@ -165,7 +173,8 @@ class CompileClock:
 
 
 def outcome(report: dict) -> dict:
-    """What one segment produced, as the program reports it."""
+    """What one segment produced, as the program reports it; the whole
+    report is kept under `report` for the per-layer readers."""
     out = {
         "digest": report["determinism_digest"],
         "events": int(report["events_processed"]),
@@ -173,6 +182,7 @@ def outcome(report: dict) -> dict:
         "microsteps": int(report["microsteps"]),
     }
     out["drops"] = sum(int(report.get(k, 0)) for k in DROP_KEYS)
+    out["report"] = report
     return out
 
 
@@ -246,3 +256,6 @@ class TraceContext:
     segments: int
     rounds: int
     microsteps: int
+    reports: list  # each traced segment's `Simulation.run` report
+    engine_cfg: object  # the chunk program's resolved EngineConfig
+    peaks: dict | None  # the device kind's row of peaks.json

@@ -55,19 +55,20 @@ def parse(argv):
 
 
 def devices(chips: int, root: str, require_tpu: bool):
-    """The devices JAX found, refused unless they are enough known TPUs."""
+    """The devices JAX found and their kind's row of peaks.json, refused
+    unless they are enough known TPUs."""
     import jax
 
     devs = jax.devices()
+    peaks = H.load_json(H.bench_file(root, "peaks.json")).get(devs[0].device_kind)
     if require_tpu:
         if devs[0].platform != "tpu":
             raise H.BenchError(f"no TPU: JAX found {devs[0].platform}")
-        peaks = H.load_json(H.bench_file(root, "peaks.json"))
-        if devs[0].device_kind not in peaks:
+        if peaks is None:
             raise H.BenchError(f"device kind {devs[0].device_kind!r} is not in peaks.json")
     if len(devs) < chips:
         raise H.BenchError(f"the cell needs {chips} chips, JAX found {len(devs)}")
-    return devs
+    return devs, peaks
 
 
 def peak_bytes(dev) -> int:
@@ -119,7 +120,7 @@ def run(argv=None, *, root: str = ROOT, require_tpu: bool = True, t_start: float
     import jax  # noqa: F401
 
     tb = time.perf_counter()
-    devs = devices(cell.chips, root, require_tpu)
+    devs, peaks = devices(cell.chips, root, require_tpu)
     tc = time.perf_counter()
     sys.path.insert(0, root)
     try:
@@ -139,6 +140,7 @@ def run(argv=None, *, root: str = ROOT, require_tpu: bool = True, t_start: float
     log(f"[setup] cell={cell.name} seed={args.seed} build_s={t1 - t0:.3f} "
         f"warm_segment_s={t2 - t1:.3f} compiles={clock.count} "
         f"compile_s={clock.seconds:.3f} setup_s={setup_s:.3f}")
+    warm.pop("report")
     log(f"[warm] {warm}")
 
     hlo = chunk_hlo(rp) if args.trace else None
@@ -157,6 +159,7 @@ def run(argv=None, *, root: str = ROOT, require_tpu: bool = True, t_start: float
     log(f"[memory] peak_bytes_in_use={mem}")
 
     answer = rp.answer()
+    engine_cfg = rp.sim.engine.cfg
     rp.free()
     del rp
     gc.collect()
@@ -177,9 +180,12 @@ def run(argv=None, *, root: str = ROOT, require_tpu: bool = True, t_start: float
         ctx = H.TraceContext(
             reduction=red, window_s=wall, segments=len(segments), rounds=n_rounds,
             microsteps=sum(s["microsteps"] for s in segments),
+            reports=[s["report"] for s in segments], engine_cfg=engine_cfg, peaks=peaks,
         )
         metrics = {}
         for entry, read in H.metric_readers(root):
+            if cell.name not in entry.get("workloads", [cell.name]):
+                continue
             value = read(ctx)
             if value is not None:
                 metrics[entry["name"]] = {"value": value, "unit": entry["unit"]}
