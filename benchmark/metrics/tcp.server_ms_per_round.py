@@ -1,0 +1,9 @@
+"""tcp.server_ms_per_round: device seconds of the chunk program's ops under
+`shadow_tcp_server` (inside `shadow_handler`: the tgen-TCP handler's server
+lane, SYN, DATA arrival and reassembly, FIN, delayed ACK) per round of the
+traced segments, in ms."""
+
+
+def read(ctx):
+    s = ctx.reduction.markers.get("shadow_tcp_server", 0.0)
+    return 1e3 * s / ctx.rounds if s > 0 and ctx.rounds else None

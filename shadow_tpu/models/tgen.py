@@ -58,6 +58,7 @@ stale segments from a previous flow are discarded, not misdelivered.
 
 from __future__ import annotations
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
@@ -268,429 +269,432 @@ class TgenTcpModel:
         my_phase = st["c_phase"]
         L = p["flow_segs"]
 
-        # ================= server lane (pure reaction to arrivals) ======
-        syn_in = seg & (ftype == FT_SYN)
-        data_in = seg & (ftype == FT_DATA)
-        fin_in = seg & (ftype == FT_FIN)
+        with jax.named_scope("shadow_tcp_server"):
+            # ================= server lane (pure reaction to arrivals) ======
+            syn_in = seg & (ftype == FT_SYN)
+            data_in = seg & (ftype == FT_DATA)
+            fin_in = seg & (ftype == FT_FIN)
 
-        listen = st["sv_state"] == 0
-        same_conn = (st["sv_peer"] == src) & (st["sv_phase"] == ph)
-        new_conn = syn_in & listen
-        dup_syn = syn_in & ~listen & same_conn  # SYN-ACK was lost: resend
-        synack_out = new_conn | dup_syn
-        # busy server (established with another client): drop the SYN; the
-        # client retries on RTO — listen-queue-full semantics.
+            listen = st["sv_state"] == 0
+            same_conn = (st["sv_peer"] == src) & (st["sv_phase"] == ph)
+            new_conn = syn_in & listen
+            dup_syn = syn_in & ~listen & same_conn  # SYN-ACK was lost: resend
+            synack_out = new_conn | dup_syn
+            # busy server (established with another client): drop the SYN; the
+            # client retries on RTO — listen-queue-full semantics.
 
-        data_ok = data_in & (st["sv_state"] == 1) & same_conn
-        rel = w_seq - st["rcv_nxt"]
-        inorder = data_ok & (rel == 0)
-        ooo = data_ok & (rel > 0) & (rel <= 32)
-        dup_seg = data_ok & ((rel < 0) | (rel > 32))  # past or beyond window
-        bm = st["sv_bm"]
-        bm_set = jnp.where(
-            ooo,
-            bm | (jnp.uint32(1) << jnp.clip(rel - 1, 0, 31).astype(jnp.uint32)),
-            bm,
-        )
-        # in-order arrival: also drain the contiguous run buffered beyond it
-        run = _ctz32(~bm_set).astype(jnp.int32)  # buffered segs now in order
-        adv = jnp.where(inorder, 1 + run, 0)
-        rcv_nxt2 = st["rcv_nxt"] + adv
-        shift = jnp.clip(adv, 0, 32).astype(jnp.uint32)
-        bm2 = jnp.where(
-            inorder,
-            jnp.where(shift >= 32, jnp.uint32(0), bm_set >> shift),
-            bm_set,
-        )
-        # FIN: accept when the full flow is in order; a re-FIN after the
-        # server already closed (our FIN-ACK was lost) answers statelessly.
-        fin_acc = (
-            fin_in & (st["sv_state"] == 1) & same_conn
-            & (st["rcv_nxt"] == w_seq)
-        )
-        fin_stateless = fin_in & listen
-        finack_out = fin_acc | fin_stateless
+            data_ok = data_in & (st["sv_state"] == 1) & same_conn
+            rel = w_seq - st["rcv_nxt"]
+            inorder = data_ok & (rel == 0)
+            ooo = data_ok & (rel > 0) & (rel <= 32)
+            dup_seg = data_ok & ((rel < 0) | (rel > 32))  # past or beyond window
+            bm = st["sv_bm"]
+            bm_set = jnp.where(
+                ooo,
+                bm | (jnp.uint32(1) << jnp.clip(rel - 1, 0, 31).astype(jnp.uint32)),
+                bm,
+            )
+            # in-order arrival: also drain the contiguous run buffered beyond it
+            run = _ctz32(~bm_set).astype(jnp.int32)  # buffered segs now in order
+            adv = jnp.where(inorder, 1 + run, 0)
+            rcv_nxt2 = st["rcv_nxt"] + adv
+            shift = jnp.clip(adv, 0, 32).astype(jnp.uint32)
+            bm2 = jnp.where(
+                inorder,
+                jnp.where(shift >= 32, jnp.uint32(0), bm_set >> shift),
+                bm_set,
+            )
+            # FIN: accept when the full flow is in order; a re-FIN after the
+            # server already closed (our FIN-ACK was lost) answers statelessly.
+            fin_acc = (
+                fin_in & (st["sv_state"] == 1) & same_conn
+                & (st["rcv_nxt"] == w_seq)
+            )
+            fin_stateless = fin_in & listen
+            finack_out = fin_acc | fin_stateless
 
-        # ---- delayed ACK (RFC 1122: ack at least every 2nd segment; OOO
-        # and duplicate segments ack immediately so fast-retransmit timing
-        # is unchanged). `delack` 0 disables coalescing entirely.
-        da_dis = p["delack"] == 0
-        # a hole-filling arrival (non-empty pre-insert bitmap) must ack
-        # IMMEDIATELY (RFC 5681: gap-fill acks end recovery without delay;
-        # the CPU-plane machine has the same had_runs carve-out)
-        filling = bm != 0
-        ack_2nd = inorder & (st["da_pend"] | da_dis | filling)
-        hold = inorder & ~st["da_pend"] & ~da_dis & ~filling
-        ack_imm = ooo | dup_seg
-        da_fire = da_ev & st["da_pend"] & (t >= st["da_t"])
-        da_repush = da_ev & st["da_pend"] & (t < st["da_t"])
-        ack_out = ack_2nd | ack_imm | da_fire
-        da_t_new = jnp.where(hold, t + p["delack"], st["da_t"])
-        da_arm = hold & ~st["da_alive"]
-        st["da_pend"] = jnp.where(
-            hold, True,
-            jnp.where(ack_out | fin_acc | new_conn, False, st["da_pend"]),
-        )
-        st["da_t"] = da_t_new
-        st["da_alive"] = jnp.where(
-            da_ev, da_repush, jnp.where(da_arm, True, st["da_alive"])
-        )
+            # ---- delayed ACK (RFC 1122: ack at least every 2nd segment; OOO
+            # and duplicate segments ack immediately so fast-retransmit timing
+            # is unchanged). `delack` 0 disables coalescing entirely.
+            da_dis = p["delack"] == 0
+            # a hole-filling arrival (non-empty pre-insert bitmap) must ack
+            # IMMEDIATELY (RFC 5681: gap-fill acks end recovery without delay;
+            # the CPU-plane machine has the same had_runs carve-out)
+            filling = bm != 0
+            ack_2nd = inorder & (st["da_pend"] | da_dis | filling)
+            hold = inorder & ~st["da_pend"] & ~da_dis & ~filling
+            ack_imm = ooo | dup_seg
+            da_fire = da_ev & st["da_pend"] & (t >= st["da_t"])
+            da_repush = da_ev & st["da_pend"] & (t < st["da_t"])
+            ack_out = ack_2nd | ack_imm | da_fire
+            da_t_new = jnp.where(hold, t + p["delack"], st["da_t"])
+            da_arm = hold & ~st["da_alive"]
+            st["da_pend"] = jnp.where(
+                hold, True,
+                jnp.where(ack_out | fin_acc | new_conn, False, st["da_pend"]),
+            )
+            st["da_t"] = da_t_new
+            st["da_alive"] = jnp.where(
+                da_ev, da_repush, jnp.where(da_arm, True, st["da_alive"])
+            )
 
-        st["sv_state"] = jnp.where(
-            new_conn, 1, jnp.where(fin_acc, 0, st["sv_state"])
-        )
-        st["sv_peer"] = jnp.where(new_conn, src, st["sv_peer"])
-        st["sv_phase"] = jnp.where(new_conn, ph, st["sv_phase"])
-        st["rcv_nxt"] = jnp.where(
-            new_conn, 0, jnp.where(fin_acc, 0, rcv_nxt2)
-        )
-        st["sv_bm"] = jnp.where(new_conn | fin_acc, jnp.uint32(0), bm2)
-        st["segs_rcvd"] = st["segs_rcvd"] + inorder + ooo
-        st["dup_segs"] = st["dup_segs"] + dup_seg
-        # actual payload bytes from the wire size word (the SENDER's mss
-        # sets segment size; crediting the receiver's own mss would be
-        # wrong under heterogeneous mss args)
-        wire_sz = ctx.payload[:, 0]
-        st["bytes_rcvd"] = st["bytes_rcvd"] + jnp.where(
-            inorder | ooo, (wire_sz - HDR_BYTES).astype(jnp.int64), 0
-        )
+            st["sv_state"] = jnp.where(
+                new_conn, 1, jnp.where(fin_acc, 0, st["sv_state"])
+            )
+            st["sv_peer"] = jnp.where(new_conn, src, st["sv_peer"])
+            st["sv_phase"] = jnp.where(new_conn, ph, st["sv_phase"])
+            st["rcv_nxt"] = jnp.where(
+                new_conn, 0, jnp.where(fin_acc, 0, rcv_nxt2)
+            )
+            st["sv_bm"] = jnp.where(new_conn | fin_acc, jnp.uint32(0), bm2)
+            st["segs_rcvd"] = st["segs_rcvd"] + inorder + ooo
+            st["dup_segs"] = st["dup_segs"] + dup_seg
+            # actual payload bytes from the wire size word (the SENDER's mss
+            # sets segment size; crediting the receiver's own mss would be
+            # wrong under heterogeneous mss args)
+            wire_sz = ctx.payload[:, 0]
+            st["bytes_rcvd"] = st["bytes_rcvd"] + jnp.where(
+                inorder | ooo, (wire_sz - HDR_BYTES).astype(jnp.int64), 0
+            )
 
-        # ================= client lane ==================================
-        for_me = seg & (src == st["c_peer"]) & (ph == my_phase)
-        synack_in = for_me & (ftype == FT_SYNACK) & (st["c_state"] == CST_SYN)
-        ack_in = for_me & (ftype == FT_ACK) & (st["c_state"] == CST_EST)
-        finack_in = for_me & (ftype == FT_FINACK) & (st["c_state"] == CST_FIN)
+        with jax.named_scope("shadow_tcp_client"):
+            # ================= client lane ==================================
+            for_me = seg & (src == st["c_peer"]) & (ph == my_phase)
+            synack_in = for_me & (ftype == FT_SYNACK) & (st["c_state"] == CST_SYN)
+            ack_in = for_me & (ftype == FT_ACK) & (st["c_state"] == CST_EST)
+            finack_in = for_me & (ftype == FT_FINACK) & (st["c_state"] == CST_FIN)
 
-        # ---- SYN-ACK: connection up, start the transmit chain
-        st["c_state"] = jnp.where(synack_in, CST_EST, st["c_state"])
+            # ---- SYN-ACK: connection up, start the transmit chain
+            st["c_state"] = jnp.where(synack_in, CST_EST, st["c_state"])
 
-        # ---- ACK processing (Reno + NewReno recovery)
-        ack = w_ack
-        una0 = st["snd_una"]
-        new_acked = ack_in & (ack > una0)
-        dup_ack = ack_in & (ack == una0) & (st["snd_nxt"] > una0)
+            # ---- ACK processing (Reno + NewReno recovery)
+            ack = w_ack
+            una0 = st["snd_una"]
+            new_acked = ack_in & (ack > una0)
+            dup_ack = ack_in & (ack == una0) & (st["snd_nxt"] > una0)
 
-        # RTT sample (Karn's: rtt_seq is cleared on any retransmission)
-        samp = new_acked & (st["rtt_seq"] >= 0) & (ack > st["rtt_seq"])
-        r = t - st["rtt_t0"]
-        first = samp & (st["srtt"] == 0)
-        later = samp & (st["srtt"] != 0)
-        rttvar1 = jnp.where(
-            first,
-            r // 2,
-            jnp.where(
-                later,
-                (3 * st["rttvar"] + jnp.abs(st["srtt"] - r)) // 4,
-                st["rttvar"],
-            ),
-        )
-        srtt1 = jnp.where(
-            first, r, jnp.where(later, (7 * st["srtt"] + r) // 8, st["srtt"])
-        )
-        rto1 = jnp.where(
-            samp,
-            jnp.clip(
-                srtt1 + jnp.maximum(1_000_000, 4 * rttvar1),
-                p["rto_min"],
-                p["rto_max"],
-            ),
-            st["rto"],
-        )
-        st["srtt"], st["rttvar"], st["rto"] = srtt1, rttvar1, rto1
-        st["rtt_seq"] = jnp.where(samp, -1, st["rtt_seq"])
-
-        in_rec = st["recover"] >= 0
-        exit_rec = new_acked & in_rec & (ack >= st["recover"])
-        partial = new_acked & in_rec & (ack < st["recover"])
-
-        # cwnd growth on forward ACKs outside recovery
-        grow = new_acked & ~in_rec
-        acked_segs = jnp.where(grow, ack - una0, 0)
-        ss = st["cwnd_x"] < st["ssth_x"]
-        ca_inc = (1 << 20) // jnp.maximum(st["cwnd_x"], 1)
-        cwnd1 = jnp.where(
-            grow,
-            jnp.where(
-                ss,
-                st["cwnd_x"] + (acked_segs << 10),
-                st["cwnd_x"] + ca_inc,
-            ),
-            st["cwnd_x"],
-        )
-        # dup-ACK counting / fast retransmit / inflation
-        dup1 = jnp.where(new_acked, 0, jnp.where(dup_ack, st["dup"] + 1, st["dup"]))
-        fast = dup_ack & (dup1 == 3) & ~in_rec
-        inflight = st["snd_nxt"] - una0
-        ssth_fast = jnp.maximum((inflight << 10) // 2, 2 << 10)
-        cwnd1 = jnp.where(
-            fast,
-            ssth_fast + (3 << 10),
-            jnp.where(dup_ack & in_rec, cwnd1 + _CWND_ONE, cwnd1),
-        )
-        st["ssth_x"] = jnp.where(fast, ssth_fast, st["ssth_x"])
-        st["recover"] = jnp.where(
-            fast, st["snd_nxt"], jnp.where(exit_rec, -1, st["recover"])
-        )
-        cwnd1 = jnp.where(exit_rec, st["ssth_x"], cwnd1)
-        cwnd_cap_x = p["cwnd_cap"] << 10
-        st["cwnd_x"] = jnp.clip(cwnd1, _CWND_ONE, cwnd_cap_x)
-        st["dup"] = dup1
-        st["snd_una"] = jnp.where(new_acked, ack, una0)
-        # Karn's rule on the retransmissions triggered below
-        st["rtt_seq"] = jnp.where(fast | partial, -1, st["rtt_seq"])
-
-        # all data acked -> send FIN
-        all_acked = new_acked & (st["snd_una"] >= L) & (st["c_state"] == CST_EST)
-        st["c_state"] = jnp.where(all_acked, CST_FIN, st["c_state"])
-
-        # ---- FIN-ACK: flow complete; next phase or done. The flow-ledger
-        # record is captured HERE, before the phase/flow_t0 lanes advance:
-        # the completed flow's identity is (this host, c_peer, my_phase),
-        # its span [flow_t0, t), its payload L segments x mss bytes, and
-        # its retransmits the d_rtx delta since the flow started. Pure
-        # observation — the engine reads it only when the ledger is on.
-        flow_done = FlowDone(
-            mask=finack_in,
-            dst=st["c_peer"],
-            flow=my_phase,
-            t_start=st["flow_t0"],
-            bytes=L.astype(jnp.int64) * p["mss"].astype(jnp.int64),
-            retransmits=st["d_rtx"] - st["flow_rtx0"],
-        )
-        phase1 = jnp.where(finack_in, my_phase + 1, my_phase)
-        more = finack_in & (phase1 < p["flows"])
-        st["c_phase"] = phase1
-        st["c_state"] = jnp.where(
-            finack_in, jnp.where(more, CST_IDLE, CST_DONE), st["c_state"]
-        )
-        st["flows_done"] = st["flows_done"] + finack_in
-        st["fct_sum"] = st["fct_sum"] + jnp.where(finack_in, t - st["flow_t0"], 0)
-        st["done_t"] = jnp.where(finack_in & ~more, t, st["done_t"])
-
-        # ---- TICK: start the next flow (SYN out)
-        start = tick & (st["c_state"] == CST_IDLE) & (my_phase < p["flows"])
-        nh = p["num_hosts"]
-        hid = ctx.host_id.astype(jnp.int32)
-        peer = (hid + 1 + my_phase % (nh - 1)) % nh
-        st["c_peer"] = jnp.where(start, peer, st["c_peer"])
-        st["c_state"] = jnp.where(start, CST_SYN, st["c_state"])
-        st["snd_una"] = jnp.where(start, 0, st["snd_una"])
-        st["snd_nxt"] = jnp.where(start, 0, st["snd_nxt"])
-        st["cwnd_x"] = jnp.where(start, p["cwnd_init"] << 10, st["cwnd_x"])
-        st["ssth_x"] = jnp.where(start, 0x7FFFFFFF, st["ssth_x"])
-        st["dup"] = jnp.where(start, 0, st["dup"])
-        st["recover"] = jnp.where(start, -1, st["recover"])
-        st["srtt"] = jnp.where(start, 0, st["srtt"])
-        st["rttvar"] = jnp.where(start, 0, st["rttvar"])
-        st["rto"] = jnp.where(start, p["rto_init"], st["rto"])
-        st["rtt_seq"] = jnp.where(start, -1, st["rtt_seq"])
-        st["flow_t0"] = jnp.where(start, t, st["flow_t0"])
-        st["flow_rtx0"] = jnp.where(start, st["d_rtx"], st["flow_rtx0"])
-
-        # ---- TX continuation: up to tx_batch DATA segments per microstep
-        # (one send port each; same-round departure makes the wire result
-        # identical to one-per-microstep, at a fraction of the event count)
-        txb = self.tx_batch
-        cwnd_segs = st["cwnd_x"] >> 10
-        lim_seq = jnp.minimum(st["snd_una"] + cwnd_segs, L)
-        n_can = jnp.where(
-            tx & (st["c_state"] == CST_EST),
-            jnp.clip(lim_seq - st["snd_nxt"], 0, txb),
-            0,
-        )
-        can_tx = n_can > 0
-        tx_seq = st["snd_nxt"]  # first segment of this batch
-        st["snd_nxt"] = st["snd_nxt"] + n_can
-        st["d_sent"] = st["d_sent"] + n_can
-        # time exactly one segment in flight (Karn-safe: first transmission)
-        time_it = can_tx & (st["rtt_seq"] < 0)
-        st["rtt_seq"] = jnp.where(time_it, tx_seq, st["rtt_seq"])
-        st["rtt_t0"] = jnp.where(time_it, t, st["rtt_t0"])
-        chain_more = can_tx & (st["snd_nxt"] < lim_seq)
-
-        # ---- RTO timer lane (single lazy timer event per host)
-        armed = st["deadline"] != TIME_MAX
-        expired = rto_ev & armed & (t >= st["deadline"])
-        resched = rto_ev & armed & (t < st["deadline"])
-        timer_dies = rto_ev & ~armed
-        st["timer_alive"] = jnp.where(timer_dies, False, st["timer_alive"])
-
-        syn_to = expired & (st["c_state"] == CST_SYN)
-        est_to = expired & (st["c_state"] == CST_EST) & (st["snd_nxt"] > st["snd_una"])
-        fin_to = expired & (st["c_state"] == CST_FIN)
-        timeout = syn_to | est_to | fin_to
-        st["timeouts"] = st["timeouts"] + timeout
-        # go-back-N on data timeout: collapse the window, retransmit una
-        st["ssth_x"] = jnp.where(
-            est_to,
-            jnp.maximum(((st["snd_nxt"] - st["snd_una"]) << 10) // 2, 2 << 10),
-            st["ssth_x"],
-        )
-        st["cwnd_x"] = jnp.where(est_to, _CWND_ONE, st["cwnd_x"])
-        st["snd_nxt"] = jnp.where(est_to, st["snd_una"] + 1, st["snd_nxt"])
-        st["dup"] = jnp.where(est_to, 0, st["dup"])
-        st["recover"] = jnp.where(est_to, -1, st["recover"])
-        st["rtt_seq"] = jnp.where(est_to, -1, st["rtt_seq"])
-        st["rto"] = jnp.where(
-            timeout, jnp.minimum(st["rto"] * 2, p["rto_max"]), st["rto"]
-        )
-
-        # ---- deadline maintenance (restart on forward progress; clear
-        # when nothing is outstanding)
-        idleish = (st["c_state"] == CST_IDLE) | (st["c_state"] == CST_DONE)
-        quiet = ack_in & ~idleish & (st["snd_nxt"] == st["snd_una"]) & (
-            st["c_state"] == CST_EST
-        )
-        rearm = (
-            start
-            | synack_in
-            | new_acked
-            | all_acked
-            | can_tx
-            | timeout
-        )
-        st["deadline"] = jnp.where(
-            finack_in | quiet | timer_dies,
-            TIME_MAX,
-            jnp.where(rearm, t + st["rto"], st["deadline"]),
-        )
-
-        # ================= emissions ====================================
-        # push port A: the TX chain (restart after SYN-ACK / forward ACK)
-        can_send_more = (
-            (st["c_state"] == CST_EST)
-            & (st["snd_nxt"] < st["snd_una"] + (st["cwnd_x"] >> 10))
-            & (st["snd_nxt"] < L)
-        )
-        # dup ACKs restart the chain too: cwnd inflation during fast
-        # recovery exists precisely to let NEW data flow while the
-        # retransmit is in flight (RFC 5681 §3.2 step 4)
-        restart = (
-            (synack_in | new_acked | dup_ack) & can_send_more & ~st["tx_alive"]
-        )
-        push_tx = chain_more | restart
-        st["tx_alive"] = jnp.where(
-            tx, chain_more, jnp.where(restart, True, st["tx_alive"])
-        )
-        port_a = LocalPush(
-            mask=push_tx,
-            t=t,
-            kind=jnp.full((h,), KIND_TX, jnp.int32),
-            payload=jnp.zeros((h, EVENT_PAYLOAD_WORDS), jnp.int32),
-        )
-
-        # push port B: timer chain + next-flow tick + delack timer — all
-        # mutually exclusive per host (timer pushes come from TICK/RTO
-        # events, tick pushes from FINACK, delack pushes from DATA/DELACK)
-        # (re)arm whenever THIS event left a live deadline and no chain is
-        # queued — not just at flow start: the chain legitimately dies
-        # whenever it fires during a quiet spell (deadline == TIME_MAX),
-        # and the next rearming event must resurrect it or the client
-        # never hears its RTO again (found as a wedged flow: deadline
-        # armed, timer_alive False, simulation idle forever).
-        arm_timer = (
-            (tick | seg | tx | rto_ev)
-            & (st["deadline"] != TIME_MAX)
-            & ~st["timer_alive"]
-        )
-        st["timer_alive"] = jnp.where(arm_timer, True, st["timer_alive"])
-        timer_push = arm_timer | resched | expired
-        timer_t = jnp.where(
-            arm_timer,
-            st["deadline"],
-            jnp.where(expired, t + st["rto"], st["deadline"]),
-        )
-        next_tick = finack_in & more
-        da_push = da_arm | da_repush
-        port_b = LocalPush(
-            mask=timer_push | next_tick | da_push,
-            t=jnp.where(
-                next_tick,
-                t + p["flow_gap"],
-                jnp.where(da_push, st["da_t"], timer_t),
-            ),
-            kind=jnp.where(
-                next_tick, KIND_TICK, jnp.where(da_push, KIND_DELACK, KIND_RTO)
-            ).astype(jnp.int32),
-            payload=jnp.zeros((h, EVENT_PAYLOAD_WORDS), jnp.int32),
-        )
-
-        # send port: at most one wire segment per host per microstep — the
-        # masks below are mutually exclusive by construction (each host
-        # handles one event, and each event type emits at most one packet).
-        rtx_data = fast | partial | est_to
-        rtx_seq = jnp.where(fast | est_to, st["snd_una"], ack)
-        st["d_rtx"] = st["d_rtx"] + rtx_data
-        st["fast_rtx"] = st["fast_rtx"] + fast
-        send_syn = start | syn_to
-        send_fin = all_acked | fin_to
-        send_data = can_tx | rtx_data
-
-        m = send_syn | send_fin | send_data | synack_out | ack_out | finack_out
-        # destinations: ACKs address via the stored connection (a delack
-        # timer firing is a LOCAL event whose payload src/phase are
-        # meaningless); SYNACK/FINACK echo the triggering packet (the
-        # stateless re-FIN answer must reach a peer no longer in sv_peer);
-        # client-side emissions go to c_peer.
-        dst = jnp.where(
-            ack_out,
-            st["sv_peer"],
-            jnp.where(synack_out | finack_out, src, st["c_peer"]),
-        ).astype(jnp.int64)
-        ft = jnp.where(
-            send_syn,
-            FT_SYN,
-            jnp.where(
-                send_fin,
-                FT_FIN,
+            # RTT sample (Karn's: rtt_seq is cleared on any retransmission)
+            samp = new_acked & (st["rtt_seq"] >= 0) & (ack > st["rtt_seq"])
+            r = t - st["rtt_t0"]
+            first = samp & (st["srtt"] == 0)
+            later = samp & (st["srtt"] != 0)
+            rttvar1 = jnp.where(
+                first,
+                r // 2,
                 jnp.where(
-                    send_data,
-                    FT_DATA,
+                    later,
+                    (3 * st["rttvar"] + jnp.abs(st["srtt"] - r)) // 4,
+                    st["rttvar"],
+                ),
+            )
+            srtt1 = jnp.where(
+                first, r, jnp.where(later, (7 * st["srtt"] + r) // 8, st["srtt"])
+            )
+            rto1 = jnp.where(
+                samp,
+                jnp.clip(
+                    srtt1 + jnp.maximum(1_000_000, 4 * rttvar1),
+                    p["rto_min"],
+                    p["rto_max"],
+                ),
+                st["rto"],
+            )
+            st["srtt"], st["rttvar"], st["rto"] = srtt1, rttvar1, rto1
+            st["rtt_seq"] = jnp.where(samp, -1, st["rtt_seq"])
+
+            in_rec = st["recover"] >= 0
+            exit_rec = new_acked & in_rec & (ack >= st["recover"])
+            partial = new_acked & in_rec & (ack < st["recover"])
+
+            # cwnd growth on forward ACKs outside recovery
+            grow = new_acked & ~in_rec
+            acked_segs = jnp.where(grow, ack - una0, 0)
+            ss = st["cwnd_x"] < st["ssth_x"]
+            ca_inc = (1 << 20) // jnp.maximum(st["cwnd_x"], 1)
+            cwnd1 = jnp.where(
+                grow,
+                jnp.where(
+                    ss,
+                    st["cwnd_x"] + (acked_segs << 10),
+                    st["cwnd_x"] + ca_inc,
+                ),
+                st["cwnd_x"],
+            )
+            # dup-ACK counting / fast retransmit / inflation
+            dup1 = jnp.where(new_acked, 0, jnp.where(dup_ack, st["dup"] + 1, st["dup"]))
+            fast = dup_ack & (dup1 == 3) & ~in_rec
+            inflight = st["snd_nxt"] - una0
+            ssth_fast = jnp.maximum((inflight << 10) // 2, 2 << 10)
+            cwnd1 = jnp.where(
+                fast,
+                ssth_fast + (3 << 10),
+                jnp.where(dup_ack & in_rec, cwnd1 + _CWND_ONE, cwnd1),
+            )
+            st["ssth_x"] = jnp.where(fast, ssth_fast, st["ssth_x"])
+            st["recover"] = jnp.where(
+                fast, st["snd_nxt"], jnp.where(exit_rec, -1, st["recover"])
+            )
+            cwnd1 = jnp.where(exit_rec, st["ssth_x"], cwnd1)
+            cwnd_cap_x = p["cwnd_cap"] << 10
+            st["cwnd_x"] = jnp.clip(cwnd1, _CWND_ONE, cwnd_cap_x)
+            st["dup"] = dup1
+            st["snd_una"] = jnp.where(new_acked, ack, una0)
+            # Karn's rule on the retransmissions triggered below
+            st["rtt_seq"] = jnp.where(fast | partial, -1, st["rtt_seq"])
+
+            # all data acked -> send FIN
+            all_acked = new_acked & (st["snd_una"] >= L) & (st["c_state"] == CST_EST)
+            st["c_state"] = jnp.where(all_acked, CST_FIN, st["c_state"])
+
+            # ---- FIN-ACK: flow complete; next phase or done. The flow-ledger
+            # record is captured HERE, before the phase/flow_t0 lanes advance:
+            # the completed flow's identity is (this host, c_peer, my_phase),
+            # its span [flow_t0, t), its payload L segments x mss bytes, and
+            # its retransmits the d_rtx delta since the flow started. Pure
+            # observation — the engine reads it only when the ledger is on.
+            flow_done = FlowDone(
+                mask=finack_in,
+                dst=st["c_peer"],
+                flow=my_phase,
+                t_start=st["flow_t0"],
+                bytes=L.astype(jnp.int64) * p["mss"].astype(jnp.int64),
+                retransmits=st["d_rtx"] - st["flow_rtx0"],
+            )
+            phase1 = jnp.where(finack_in, my_phase + 1, my_phase)
+            more = finack_in & (phase1 < p["flows"])
+            st["c_phase"] = phase1
+            st["c_state"] = jnp.where(
+                finack_in, jnp.where(more, CST_IDLE, CST_DONE), st["c_state"]
+            )
+            st["flows_done"] = st["flows_done"] + finack_in
+            st["fct_sum"] = st["fct_sum"] + jnp.where(finack_in, t - st["flow_t0"], 0)
+            st["done_t"] = jnp.where(finack_in & ~more, t, st["done_t"])
+
+            # ---- TICK: start the next flow (SYN out)
+            start = tick & (st["c_state"] == CST_IDLE) & (my_phase < p["flows"])
+            nh = p["num_hosts"]
+            hid = ctx.host_id.astype(jnp.int32)
+            peer = (hid + 1 + my_phase % (nh - 1)) % nh
+            st["c_peer"] = jnp.where(start, peer, st["c_peer"])
+            st["c_state"] = jnp.where(start, CST_SYN, st["c_state"])
+            st["snd_una"] = jnp.where(start, 0, st["snd_una"])
+            st["snd_nxt"] = jnp.where(start, 0, st["snd_nxt"])
+            st["cwnd_x"] = jnp.where(start, p["cwnd_init"] << 10, st["cwnd_x"])
+            st["ssth_x"] = jnp.where(start, 0x7FFFFFFF, st["ssth_x"])
+            st["dup"] = jnp.where(start, 0, st["dup"])
+            st["recover"] = jnp.where(start, -1, st["recover"])
+            st["srtt"] = jnp.where(start, 0, st["srtt"])
+            st["rttvar"] = jnp.where(start, 0, st["rttvar"])
+            st["rto"] = jnp.where(start, p["rto_init"], st["rto"])
+            st["rtt_seq"] = jnp.where(start, -1, st["rtt_seq"])
+            st["flow_t0"] = jnp.where(start, t, st["flow_t0"])
+            st["flow_rtx0"] = jnp.where(start, st["d_rtx"], st["flow_rtx0"])
+
+            # ---- TX continuation: up to tx_batch DATA segments per microstep
+            # (one send port each; same-round departure makes the wire result
+            # identical to one-per-microstep, at a fraction of the event count)
+            txb = self.tx_batch
+            cwnd_segs = st["cwnd_x"] >> 10
+            lim_seq = jnp.minimum(st["snd_una"] + cwnd_segs, L)
+            n_can = jnp.where(
+                tx & (st["c_state"] == CST_EST),
+                jnp.clip(lim_seq - st["snd_nxt"], 0, txb),
+                0,
+            )
+            can_tx = n_can > 0
+            tx_seq = st["snd_nxt"]  # first segment of this batch
+            st["snd_nxt"] = st["snd_nxt"] + n_can
+            st["d_sent"] = st["d_sent"] + n_can
+            # time exactly one segment in flight (Karn-safe: first transmission)
+            time_it = can_tx & (st["rtt_seq"] < 0)
+            st["rtt_seq"] = jnp.where(time_it, tx_seq, st["rtt_seq"])
+            st["rtt_t0"] = jnp.where(time_it, t, st["rtt_t0"])
+            chain_more = can_tx & (st["snd_nxt"] < lim_seq)
+
+            # ---- RTO timer lane (single lazy timer event per host)
+            armed = st["deadline"] != TIME_MAX
+            expired = rto_ev & armed & (t >= st["deadline"])
+            resched = rto_ev & armed & (t < st["deadline"])
+            timer_dies = rto_ev & ~armed
+            st["timer_alive"] = jnp.where(timer_dies, False, st["timer_alive"])
+
+            syn_to = expired & (st["c_state"] == CST_SYN)
+            est_to = expired & (st["c_state"] == CST_EST) & (st["snd_nxt"] > st["snd_una"])
+            fin_to = expired & (st["c_state"] == CST_FIN)
+            timeout = syn_to | est_to | fin_to
+            st["timeouts"] = st["timeouts"] + timeout
+            # go-back-N on data timeout: collapse the window, retransmit una
+            st["ssth_x"] = jnp.where(
+                est_to,
+                jnp.maximum(((st["snd_nxt"] - st["snd_una"]) << 10) // 2, 2 << 10),
+                st["ssth_x"],
+            )
+            st["cwnd_x"] = jnp.where(est_to, _CWND_ONE, st["cwnd_x"])
+            st["snd_nxt"] = jnp.where(est_to, st["snd_una"] + 1, st["snd_nxt"])
+            st["dup"] = jnp.where(est_to, 0, st["dup"])
+            st["recover"] = jnp.where(est_to, -1, st["recover"])
+            st["rtt_seq"] = jnp.where(est_to, -1, st["rtt_seq"])
+            st["rto"] = jnp.where(
+                timeout, jnp.minimum(st["rto"] * 2, p["rto_max"]), st["rto"]
+            )
+
+            # ---- deadline maintenance (restart on forward progress; clear
+            # when nothing is outstanding)
+            idleish = (st["c_state"] == CST_IDLE) | (st["c_state"] == CST_DONE)
+            quiet = ack_in & ~idleish & (st["snd_nxt"] == st["snd_una"]) & (
+                st["c_state"] == CST_EST
+            )
+            rearm = (
+                start
+                | synack_in
+                | new_acked
+                | all_acked
+                | can_tx
+                | timeout
+            )
+            st["deadline"] = jnp.where(
+                finack_in | quiet | timer_dies,
+                TIME_MAX,
+                jnp.where(rearm, t + st["rto"], st["deadline"]),
+            )
+
+        with jax.named_scope("shadow_tcp_emit"):
+            # ================= emissions ====================================
+            # push port A: the TX chain (restart after SYN-ACK / forward ACK)
+            can_send_more = (
+                (st["c_state"] == CST_EST)
+                & (st["snd_nxt"] < st["snd_una"] + (st["cwnd_x"] >> 10))
+                & (st["snd_nxt"] < L)
+            )
+            # dup ACKs restart the chain too: cwnd inflation during fast
+            # recovery exists precisely to let NEW data flow while the
+            # retransmit is in flight (RFC 5681 §3.2 step 4)
+            restart = (
+                (synack_in | new_acked | dup_ack) & can_send_more & ~st["tx_alive"]
+            )
+            push_tx = chain_more | restart
+            st["tx_alive"] = jnp.where(
+                tx, chain_more, jnp.where(restart, True, st["tx_alive"])
+            )
+            port_a = LocalPush(
+                mask=push_tx,
+                t=t,
+                kind=jnp.full((h,), KIND_TX, jnp.int32),
+                payload=jnp.zeros((h, EVENT_PAYLOAD_WORDS), jnp.int32),
+            )
+
+            # push port B: timer chain + next-flow tick + delack timer — all
+            # mutually exclusive per host (timer pushes come from TICK/RTO
+            # events, tick pushes from FINACK, delack pushes from DATA/DELACK)
+            # (re)arm whenever THIS event left a live deadline and no chain is
+            # queued — not just at flow start: the chain legitimately dies
+            # whenever it fires during a quiet spell (deadline == TIME_MAX),
+            # and the next rearming event must resurrect it or the client
+            # never hears its RTO again (found as a wedged flow: deadline
+            # armed, timer_alive False, simulation idle forever).
+            arm_timer = (
+                (tick | seg | tx | rto_ev)
+                & (st["deadline"] != TIME_MAX)
+                & ~st["timer_alive"]
+            )
+            st["timer_alive"] = jnp.where(arm_timer, True, st["timer_alive"])
+            timer_push = arm_timer | resched | expired
+            timer_t = jnp.where(
+                arm_timer,
+                st["deadline"],
+                jnp.where(expired, t + st["rto"], st["deadline"]),
+            )
+            next_tick = finack_in & more
+            da_push = da_arm | da_repush
+            port_b = LocalPush(
+                mask=timer_push | next_tick | da_push,
+                t=jnp.where(
+                    next_tick,
+                    t + p["flow_gap"],
+                    jnp.where(da_push, st["da_t"], timer_t),
+                ),
+                kind=jnp.where(
+                    next_tick, KIND_TICK, jnp.where(da_push, KIND_DELACK, KIND_RTO)
+                ).astype(jnp.int32),
+                payload=jnp.zeros((h, EVENT_PAYLOAD_WORDS), jnp.int32),
+            )
+
+            # send port: at most one wire segment per host per microstep — the
+            # masks below are mutually exclusive by construction (each host
+            # handles one event, and each event type emits at most one packet).
+            rtx_data = fast | partial | est_to
+            rtx_seq = jnp.where(fast | est_to, st["snd_una"], ack)
+            st["d_rtx"] = st["d_rtx"] + rtx_data
+            st["fast_rtx"] = st["fast_rtx"] + fast
+            send_syn = start | syn_to
+            send_fin = all_acked | fin_to
+            send_data = can_tx | rtx_data
+
+            m = send_syn | send_fin | send_data | synack_out | ack_out | finack_out
+            # destinations: ACKs address via the stored connection (a delack
+            # timer firing is a LOCAL event whose payload src/phase are
+            # meaningless); SYNACK/FINACK echo the triggering packet (the
+            # stateless re-FIN answer must reach a peer no longer in sv_peer);
+            # client-side emissions go to c_peer.
+            dst = jnp.where(
+                ack_out,
+                st["sv_peer"],
+                jnp.where(synack_out | finack_out, src, st["c_peer"]),
+            ).astype(jnp.int64)
+            ft = jnp.where(
+                send_syn,
+                FT_SYN,
+                jnp.where(
+                    send_fin,
+                    FT_FIN,
                     jnp.where(
-                        synack_out,
-                        FT_SYNACK,
-                        jnp.where(ack_out, FT_ACK, FT_FINACK),
+                        send_data,
+                        FT_DATA,
+                        jnp.where(
+                            synack_out,
+                            FT_SYNACK,
+                            jnp.where(ack_out, FT_ACK, FT_FINACK),
+                        ),
                     ),
                 ),
-            ),
-        ).astype(jnp.int32)
-        # phase stamp: ACKs carry the stored connection phase; SYNACK/
-        # FINACK echo the packet's phase; client emissions use their own
-        out_phase = jnp.where(
-            ack_out,
-            st["sv_phase"],
-            jnp.where(synack_out | finack_out, ph, my_phase),
-        )
-        seq_word = jnp.where(
-            send_data,
-            jnp.where(rtx_data, rtx_seq, tx_seq),
-            jnp.where(send_fin, L, jnp.where(ack_out, st["sv_bm"].astype(jnp.int32), 0)),
-        )
-        ack_word = jnp.where(ack_out, st["rcv_nxt"], 0)
-        payload = jnp.zeros((h, EVENT_PAYLOAD_WORDS), jnp.int32)
-        payload = payload.at[:, PW_SEQ].set(seq_word)
-        payload = payload.at[:, PW_ACK].set(ack_word)
-        payload = payload.at[:, PW_META].set(ft | (out_phase << 8))
-        size = jnp.where(
-            send_data, p["mss"] + HDR_BYTES, jnp.full((h,), HDR_BYTES, jnp.int32)
-        ).astype(jnp.int32)
-        # segments 2..tx_batch of a TX batch ride the SAME port as a burst
-        # (engine PacketSend.count): the per-segment payload differs only
-        # by +1 in the seq word, expressed via payload_inc. Non-TX
-        # emissions are count 1.
-        seq_inc = jnp.zeros((h, EVENT_PAYLOAD_WORDS), jnp.int32).at[
-            :, PW_SEQ
-        ].set(1)
-        send = PacketSend(
-            mask=m,
-            dst=dst,
-            size_bytes=size,
-            kind=jnp.full((h,), KIND_SEG, jnp.int32),
-            payload=payload,
-            count=jnp.where(can_tx, n_can, 1).astype(jnp.int32),
-            payload_inc=seq_inc,
-            count_max=txb,
-        )
+            ).astype(jnp.int32)
+            # phase stamp: ACKs carry the stored connection phase; SYNACK/
+            # FINACK echo the packet's phase; client emissions use their own
+            out_phase = jnp.where(
+                ack_out,
+                st["sv_phase"],
+                jnp.where(synack_out | finack_out, ph, my_phase),
+            )
+            seq_word = jnp.where(
+                send_data,
+                jnp.where(rtx_data, rtx_seq, tx_seq),
+                jnp.where(send_fin, L, jnp.where(ack_out, st["sv_bm"].astype(jnp.int32), 0)),
+            )
+            ack_word = jnp.where(ack_out, st["rcv_nxt"], 0)
+            payload = jnp.zeros((h, EVENT_PAYLOAD_WORDS), jnp.int32)
+            payload = payload.at[:, PW_SEQ].set(seq_word)
+            payload = payload.at[:, PW_ACK].set(ack_word)
+            payload = payload.at[:, PW_META].set(ft | (out_phase << 8))
+            size = jnp.where(
+                send_data, p["mss"] + HDR_BYTES, jnp.full((h,), HDR_BYTES, jnp.int32)
+            ).astype(jnp.int32)
+            # segments 2..tx_batch of a TX batch ride the SAME port as a burst
+            # (engine PacketSend.count): the per-segment payload differs only
+            # by +1 in the seq word, expressed via payload_inc. Non-TX
+            # emissions are count 1.
+            seq_inc = jnp.zeros((h, EVENT_PAYLOAD_WORDS), jnp.int32).at[
+                :, PW_SEQ
+            ].set(1)
+            send = PacketSend(
+                mask=m,
+                dst=dst,
+                size_bytes=size,
+                kind=jnp.full((h,), KIND_SEG, jnp.int32),
+                payload=payload,
+                count=jnp.where(can_tx, n_can, 1).astype(jnp.int32),
+                payload_inc=seq_inc,
+                count_max=txb,
+            )
 
         return HandlerOut(
             state=st, rng=ctx.rng, pushes=(port_a, port_b), sends=(send,),

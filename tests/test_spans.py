@@ -6,8 +6,10 @@ its driver loop (obs/runtime.py `span_or_null`, `chunk_span`), and
 running they land on the trace's host plane, on the device ops' clock.
 Device side: `jax.named_scope`s mark the barrier (`shadow_window`), the
 collectives (`shadow_collective`), the merge's two halves
-(`shadow_merge_order`, `shadow_merge_write`) and the model handler
-(`shadow_handler`) in the compiled HLO's `op_name` metadata. Neither
+(`shadow_merge_order`, `shadow_merge_write`), the model handler
+(`shadow_handler`) and, inside the tgen-TCP handler, its server lane,
+client lane and emissions (`shadow_tcp_server`, `shadow_tcp_client`,
+`shadow_tcp_emit`) in the compiled HLO's `op_name` metadata. Neither
 changes what the program computes."""
 
 from __future__ import annotations
@@ -34,6 +36,18 @@ def _phold(hosts=32, stop_s=1, **general):
     cfg = ge._sim_config(hosts, stop_s)
     cfg["general"].update(general)
     return cfg
+
+
+def _tgen(hosts=8, stop="100 ms"):
+    gml = ('graph [ directed 0 node [ id 0 host_bandwidth_down "1 Gbit" '
+           'host_bandwidth_up "1 Gbit" ] edge [ source 0 target 0 latency "1 ms" ] ]')
+    return {
+        "general": {"stop_time": stop, "seed": 5},
+        "network": {"graph": {"type": "gml", "inline": gml}},
+        "experimental": {"event_queue_capacity": 24, "sends_per_host_round": 24},
+        "hosts": {"peer": {"count": hosts, "network_node_id": 0, "processes": [
+            {"model": "tgen_tcp", "model_args": {"flow_segs": 40, "flows": 2}}]}},
+    }
 
 
 def _sim(cfg, world=1):
@@ -74,6 +88,20 @@ def test_chunk_hlo_carries_round_scopes(monkeypatch):
     assert nested("shadow_microsteps", "shadow_handler")
     # one chip: no collective
     assert not any("shadow_collective" in n for n in names)
+
+
+def test_tgen_chunk_hlo_splits_the_handler(monkeypatch):
+    """The TCP handler's three sections sit inside `shadow_handler`, and
+    together they hold most of its ops."""
+    names = [n.split("/") for n in _op_names(_tpu_chunk_hlo(monkeypatch, _sim(_tgen())))]
+    handler = [p for p in names if "shadow_handler" in p]
+    tcp = ("shadow_tcp_server", "shadow_tcp_client", "shadow_tcp_emit")
+    for scope in tcp:
+        inside = [p for p in handler if scope in p]
+        assert inside, scope
+        assert all(p.index("shadow_handler") < p.index(scope) for p in inside), scope
+    assert not any(scope in p for p in names if p not in handler for scope in tcp)
+    assert sum(any(sc in p for sc in tcp) for p in handler) > len(handler) // 2
 
 
 def test_sharded_chunk_hlo_scopes_every_collective():
@@ -143,7 +171,14 @@ def test_run_records_nested_host_spans(tmp_path):
 
 
 def test_results_identical_with_and_without_a_profiler_session(tmp_path):
-    cfg = _phold(stop_s=2)
+    _assert_profiler_session_changes_nothing(tmp_path, _phold(stop_s=2))
+
+
+def test_tgen_results_identical_with_and_without_a_profiler_session(tmp_path):
+    _assert_profiler_session_changes_nothing(tmp_path, _tgen(stop="300 ms"))
+
+
+def _assert_profiler_session_changes_nothing(tmp_path, cfg):
     plain = _sim(cfg)
     rep_plain = plain.run(progress=False)
     traced = _sim(cfg)
