@@ -310,6 +310,14 @@ class Stats(NamedTuple):
     # exchange kinds.
     ici_intra: Any = None  # i64[world] | None
     ici_inter: Any = None  # i64[world] | None
+    # Alltoall block-rung signal (None unless cfg.a2a_ladder — gather,
+    # hierarchical, world-1 and pinned-`a2a_block` programs carry none):
+    # the most rows any (source shard, destination shard) pair sent in
+    # one round, this shard's max over its destinations, sampled before
+    # the block truncation. The driver's rung controller
+    # (core/gears.BlockRungController) reads its max over shards between
+    # chunks and resets it per chunk, like outbox_hwm.
+    a2a_pair_hwm: Any = None  # i64[world] | None
 
 
 class SimState(NamedTuple):
@@ -474,6 +482,17 @@ class EngineConfig:
     #                so it stays 0 — every test asserts it).
     exchange: str = "gather"
     a2a_block: int = 0  # 0 -> auto: 2 * outbox_rows / world, >= 64
+    # Active alltoall block rung (auto `a2a_block` only): the per-pair
+    # block width a chunk program is compiled at, one rung of the
+    # driver's ladder (core/gears.BlockRungController). 0 or any width at
+    # or above the auto size is the top rung: today's auto block. Below
+    # it, a round in which some pair sends more than the rung counts the
+    # excess into stats.gear_shed (psum'd) and the chunk aborts; the
+    # driver restores its pre-chunk snapshot and replays at a rung that
+    # fits, so accepted chunks are bit-identical to the top rung. Like
+    # gear_cols, the driver's EngineConfig always carries 0: rung
+    # programs are built via Engine.run_chunk_resized.
+    a2a_rung: int = 0
     # Static cap on post-sort merge gather work (ops/merge.py): only the
     # first `merge_rows` sorted exchange rows are materialized. Exact while
     # (valid rows + num_hosts + 1) <= merge_rows; beyond it rows shed by
@@ -628,6 +647,10 @@ class EngineConfig:
             raise ValueError(
                 f"a2a_block must be >= 0 (0 = auto), got {self.a2a_block}"
             )
+        if self.a2a_rung < 0:
+            raise ValueError(
+                f"a2a_rung must be >= 0 (0 = top rung), got {self.a2a_rung}"
+            )
         if self.queue_block < 0 or (
             self.queue_block and self.queue_capacity % self.queue_block
         ):
@@ -727,11 +750,36 @@ class EngineConfig:
             )
 
     @property
+    def a2a_auto_block(self) -> int:
+        """The auto alltoall block: 2 x the outbox's rows / world, at
+        least 64, at most the outbox's rows. The top of the rung ladder."""
+        rows = self.hosts_per_shard * self.sends_per_host_round
+        return min(rows, max(64, 2 * rows // max(self.world, 1)))
+
+    @property
     def a2a_block_size(self) -> int:
         if self.a2a_block:
             return self.a2a_block
-        rows = self.hosts_per_shard * self.sends_per_host_round
-        return min(rows, max(64, 2 * rows // max(self.world, 1)))
+        if self.a2a_rung_active:
+            return self.a2a_rung
+        return self.a2a_auto_block
+
+    @property
+    def a2a_ladder(self) -> bool:
+        """True iff the alltoall block may run below its auto size: the
+        flat alltoall on a mesh with the block left auto. Only then does
+        the carry hold `stats.a2a_pair_hwm` (the rung controller's
+        signal); an explicit `a2a_block` pins the block and the ladder."""
+        return (
+            self.exchange == "alltoall" and self.world > 1
+            and not self.a2a_block
+        )
+
+    @property
+    def a2a_rung_active(self) -> bool:
+        """True iff this program's alltoall block is a rung below the
+        auto size (block-overflow abort traced in, like `gear_active`)."""
+        return self.a2a_ladder and 0 < self.a2a_rung < self.a2a_auto_block
 
     @property
     def hosts_per_shard(self) -> int:
@@ -937,6 +985,7 @@ def _init_stats(cfg: EngineConfig) -> Stats:
         # exchange is traced in — distinct buffers (donation rule above)
         ici_intra=zw() if cfg.hier_active else None,
         ici_inter=zw() if cfg.hier_active else None,
+        a2a_pair_hwm=zw() if cfg.a2a_ladder else None,
     )
 
 
@@ -1174,11 +1223,17 @@ class Engine:
         self.cfg = cfg
         self.model = model
         self.mesh = mesh
-        self.run_chunk = None  # built by init_state (needs model pytree shapes)
+        # the chunk program at the selected alltoall block rung
+        # (`select_a2a_rung`); the top-rung program until a driver selects
+        # a lower one. Built by init_state (needs model pytree shapes).
+        self.run_chunk = None
+        self._top_chunk = None  # the base-shape, full-width, top-rung program
+        self.a2a_rung = 0  # the rung `run_chunk` runs at (0 = top)
         self._gear_chunks: dict[int, Any] = {}  # gear_cols -> jitted chunk
-        # (gear_cols, queue_capacity, send_budget) -> jitted chunk: the
-        # pressure plane's escalated programs (core/pressure.py). Bounded
-        # by the escalation ladders (a handful of rungs per axis).
+        # (gear_cols, queue_capacity, send_budget, a2a_rung) -> jitted
+        # chunk: the pressure plane's escalated programs
+        # (core/pressure.py) and the alltoall block rungs. Bounded by the
+        # ladders (a handful of rungs per axis).
         self._resized_chunks: dict[tuple, Any] = {}
         # runtime observatory (obs/runtime.CompileLedger): when attached,
         # every cached chunk program is wrapped so its first (compiling)
@@ -1193,10 +1248,11 @@ class Engine:
         program still records on its first call); attach before the
         first dispatch or the base program's compile goes unrecorded."""
         self.compile_ledger = ledger
-        if self.run_chunk is not None and ledger is not None:
-            self.run_chunk = ledger.instrument(
-                "chunk", "base", "cold_start", self.run_chunk
+        if self._top_chunk is not None and ledger is not None:
+            self._top_chunk = ledger.instrument(
+                "chunk", "base", "cold_start", self._top_chunk
             )
+            self.select_a2a_rung(self.a2a_rung)
 
     def _jit_chunk(self, cfg: EngineConfig):
         """Build one jitted chunk program for `cfg` — shared by the
@@ -1217,7 +1273,20 @@ class Engine:
             fn = self.compile_ledger.instrument(
                 "chunk", "base", "cold_start", fn
             )
+        self._top_chunk = fn
         self.run_chunk = fn
+        self.a2a_rung = 0
+
+    def select_a2a_rung(self, a2a_rung: int):
+        """Point `run_chunk` at the base-shape, full-width program of
+        alltoall block rung `a2a_rung` (0 = top). The drivers call it with
+        the rung their next chunk runs at, so `run_chunk` (and its HLO) is
+        the program in force. Below the top rung a chunk stops at its
+        first overflowing round with `stats.gear_shed` grown, and only the
+        snapshot-replay seam (core/pressure.ResilienceController) may
+        accept its result."""
+        self.a2a_rung = int(a2a_rung)
+        self.run_chunk = self._chunk_program(0, 0, 0, self.a2a_rung)
 
     def run_chunk_gear(self, state: SimState, params: EngineParams, gear_cols: int):
         """Run one chunk at a merge gear (`gear_cols` outbox columns in the
@@ -1231,8 +1300,12 @@ class Engine:
         only the slice the exchange sorts changes), so the pre-chunk
         snapshot/replay loop in the drivers can hand the same pytree to
         any gear."""
+        return self._gear_program(gear_cols)(state, params)
+
+    def _gear_program(self, gear_cols: int):
+        """The cached top-rung, base-shape program at a merge gear."""
         if gear_cols <= 0 or gear_cols >= self.cfg.sends_per_host_round:
-            return self.run_chunk(state, params)
+            return self._top_chunk
         fn = self._gear_chunks.get(gear_cols)
         if fn is None:
             fn = self._jit_chunk(
@@ -1243,16 +1316,17 @@ class Engine:
                     "chunk", f"gear={gear_cols}", "gear_shift", fn
                 )
             self._gear_chunks[gear_cols] = fn
-        return fn(state, params)
+        return fn
 
     def run_chunk_resized(
         self, state: SimState, params: EngineParams, gear_cols: int,
-        queue_capacity: int, send_budget: int,
+        queue_capacity: int, send_budget: int, a2a_rung: int = 0,
     ):
         """Run one chunk at an escalated shape: `queue_capacity` slots per
         host and a `send_budget`-wide outbox (the pressure plane's
         regrown programs, core/pressure.py), at merge gear `gear_cols`
-        (0 = full width). Base shapes route to the gear/full-width cache.
+        (0 = full width) and alltoall block rung `a2a_rung` (0 = top).
+        Base shapes at the top rung route to the gear/full-width cache.
 
         The resized config pins the knobs that would otherwise drift
         with capacity, so the escalated trajectory stays bit-identical
@@ -1268,38 +1342,56 @@ class Engine:
         `queue_capacity`/`send_budget` of 0 means the base shape (the
         gears-only controller passes 0s — it never reads the state's
         shapes), exactly like `gear_cols` 0 means full width."""
+        fn = self._chunk_program(
+            gear_cols, queue_capacity, send_budget, a2a_rung
+        )
+        return fn(state, params)
+
+    def _chunk_program(
+        self, gear_cols: int, queue_capacity: int, send_budget: int,
+        a2a_rung: int,
+    ):
+        """The cached jitted chunk program `run_chunk_resized` runs."""
         base = self.cfg
-        if queue_capacity in (0, base.queue_capacity) and send_budget in (
-            0, base.sends_per_host_round
+        cap = int(queue_capacity) or base.queue_capacity
+        budget = int(send_budget) or base.sends_per_host_round
+        cfg = self.resized_cfg(gear_cols, cap, budget, a2a_rung)
+        if (cap, budget, cfg.a2a_rung) == (
+            base.queue_capacity, base.sends_per_host_round, 0
         ):
-            return self.run_chunk_gear(state, params, gear_cols)
-        key = (int(gear_cols), int(queue_capacity), int(send_budget))
+            return self._gear_program(cfg.gear_cols)
+        key = (cfg.gear_cols, cap, budget, cfg.a2a_rung)
         fn = self._resized_chunks.get(key)
         if fn is None:
-            fn = self._jit_chunk(self.resized_cfg(
-                gear_cols, queue_capacity, send_budget
-            ))
+            fn = self._jit_chunk(cfg)
             if self.compile_ledger is not None:
                 fn = self.compile_ledger.instrument(
                     "chunk",
-                    f"cap={queue_capacity}/box={send_budget}"
-                    f"/gear={gear_cols}",
-                    "pressure_regrow", fn,
+                    f"cap={cap}/box={budget}/gear={cfg.gear_cols}"
+                    + (f"/a2a={cfg.a2a_rung}" if cfg.a2a_rung else ""),
+                    "pressure_regrow"
+                    if (cap, budget) != (
+                        base.queue_capacity, base.sends_per_host_round
+                    ) else "a2a_rung",
+                    fn,
                 )
             self._resized_chunks[key] = fn
-        return fn(state, params)
+        return fn
 
     def resized_cfg(
-        self, gear_cols: int, queue_capacity: int, send_budget: int
+        self, gear_cols: int, queue_capacity: int, send_budget: int,
+        a2a_rung: int = 0,
     ) -> EngineConfig:
         """The escalated EngineConfig `run_chunk_resized` compiles (shared
-        so tests can assert the pinning rules)."""
+        so tests can assert the pinning rules). `a2a_rung` is kept only
+        where it is a rung below the auto block at this send budget."""
         base = self.cfg
-        return dataclasses.replace(
+        cfg = dataclasses.replace(
             base,
             queue_capacity=queue_capacity,
             sends_per_host_round=send_budget,
             gear_cols=gear_cols if 0 < gear_cols < send_budget else 0,
+            a2a_rung=int(a2a_rung),
             microstep_limit=base.effective_microstep_limit,
             max_round_inserts=(
                 queue_capacity
@@ -1307,6 +1399,9 @@ class Engine:
                 else base.max_round_inserts
             ),
         )
+        if cfg.a2a_rung and not cfg.a2a_rung_active:
+            cfg = dataclasses.replace(cfg, a2a_rung=0)
+        return cfg
 
     def build_capture_step(self):
         """Jitted single round returning (state, sent-outbox) for pcap
@@ -1405,6 +1500,7 @@ class Engine:
                 fl_bg_dropped=rep if self.cfg.fluid_active else None,
                 ici_intra=sh if self.cfg.hier_active else None,
                 ici_inter=sh if self.cfg.hier_active else None,
+                a2a_pair_hwm=sh if self.cfg.a2a_ladder else None,
             ),
             trace=(
                 TraceRing(rows=sh, cursor=sh) if self.cfg.trace_rounds
@@ -1630,15 +1726,19 @@ def _psum(x, axis):
 
 
 def _run_chunk(cfg: EngineConfig, model, axis, state: SimState, params: EngineParams):
-    # gear-abort: once a round's sliced exchange sheds, every further round
-    # of this chunk is wasted work (the driver will discard the result and
-    # replay from its snapshot one gear up), so the loop stops at the first
-    # shed. gear_shed carries the psum'd GLOBAL count, so the condition is
-    # uniform across shards and the mesh exits together. The pressure
+    # gear-abort: once a round's sliced exchange (or a below-top alltoall
+    # block rung) sheds, every further round of this chunk is wasted work
+    # (the driver will discard the result and replay from its snapshot one
+    # gear or rung up), so the loop stops at the first shed. gear_shed
+    # carries the psum'd GLOBAL count, so the condition is uniform across
+    # shards and the mesh exits together. The pressure
     # plane's first-drop abort (cfg.pressure_abort) is the same mechanism
     # on the psum'd capacity-drop total: the driver either regrows and
     # replays (escalate) or stops with honest artifacts (abort).
-    shed0 = state.stats.gear_shed[0] if cfg.gear_active else None
+    shed0 = (
+        state.stats.gear_shed[0]
+        if cfg.gear_active or cfg.a2a_rung_active else None
+    )
     press0 = state.stats.pressure[0] if cfg.pressure_abort else None
     # integrity sentinel: stop at the first violating round — every
     # further round of this chunk would run on state a guard already
@@ -1686,7 +1786,10 @@ def _run_guarded_chunk(
     first-shed abort as `_run_chunk` (the hybrid driver snapshots before
     the dispatch and replays one gear up on a shed), and the same
     first-drop pressure abort when `cfg.pressure_abort` is set."""
-    shed0 = st.stats.gear_shed[0] if cfg.gear_active else None
+    shed0 = (
+        st.stats.gear_shed[0]
+        if cfg.gear_active or cfg.a2a_rung_active else None
+    )
     press0 = st.stats.pressure[0] if cfg.pressure_abort else None
     iv0 = st.stats.integrity[0] if cfg.integrity else None
 
@@ -3396,10 +3499,18 @@ def _exchange_alltoall(cfg, axis, st: SimState):
     to the gather path whenever nothing sheds (`stats.a2a_shed` counts
     sheds; size `a2a_block` so it stays zero).
 
+    Block width: with the block left auto, k is the rung the chunk
+    program was compiled at (`EngineConfig.a2a_rung`, picked by the
+    driver's rung controller from `stats.a2a_pair_hwm`, the rows each
+    pair actually sent) and the top rung is the auto size. Below the top
+    a block overflow counts into the psum'd `stats.gear_shed`: the chunk
+    aborts and is replayed at a rung that fits, so accepted chunks match
+    the top rung bit for bit. At the top rung, and with an explicit
+    `a2a_block`, overflow sheds into `a2a_shed` as before.
+
     Merge gears trim the LOCAL dst-shard sort input (the [H, B] lanes
     sliced to gear_cols columns) exactly like the gather path; the
-    alltoall blocks themselves stay full width, so the wire format and
-    `a2a_shed` semantics are gear-invariant."""
+    block width does not follow the gear."""
     ob_full = st.outbox
     ob, gear_shed = _gear_sliced_outbox(cfg, axis, ob_full, st.sent_round)
     h_local = st.queue.t.shape[0]
@@ -3407,7 +3518,7 @@ def _exchange_alltoall(cfg, axis, st: SimState):
     k = cfg.a2a_block_size
     my = lax.axis_index(axis).astype(jnp.int32)
 
-    blocks, _seg_len, shed = _dshard_pack_blocks(ob, h_local, world, k)
+    blocks, seg_len, shed = _dshard_pack_blocks(ob, h_local, world, k)
 
     with jax.named_scope("shadow_collective"):
         recv = lax.all_to_all(blocks, axis, split_axis=0, concat_axis=0)
@@ -3424,10 +3535,20 @@ def _exchange_alltoall(cfg, axis, st: SimState):
     with jax.named_scope("shadow_merge"):
         queue = _merge_into_queue(cfg, st.queue, flat, has_sends)
     stats = st.stats._replace(
-        a2a_shed=st.stats.a2a_shed + shed[None],
         ici_bytes=st.stats.ici_bytes
         + jnp.int64(exchange_ici_bytes_per_round(cfg, "alltoall"))[None],
     )
+    if cfg.a2a_rung_active:
+        # below-top rung: overflow rides the abort-and-replay path
+        rung_shed = _psum(shed, axis)
+        gear_shed = rung_shed if gear_shed is None else gear_shed + rung_shed
+    else:
+        stats = stats._replace(a2a_shed=stats.a2a_shed + shed[None])
+    if cfg.a2a_ladder:
+        pair_hwm = jnp.max(seg_len).astype(jnp.int64)
+        stats = stats._replace(
+            a2a_pair_hwm=jnp.maximum(stats.a2a_pair_hwm, pair_hwm[None])
+        )
     if gear_shed is not None:
         stats = stats._replace(gear_shed=stats.gear_shed + gear_shed[None])
     if isinstance(st.queue, BucketQueue):

@@ -39,6 +39,10 @@ The controller is deliberately simple and deterministic:
     too-wide sort costs only its width).
 Determinism note: gear choices affect WHICH program runs, never what it
 computes — a controller bug can cost replays, not correctness.
+
+`BlockRungController` below applies the same controller, replay contract
+included, to the flat alltoall's per-pair block width (the rung ladder
+of the auto `a2a_block`, docs/architecture.md "Alltoall block rungs").
 """
 
 from __future__ import annotations
@@ -157,6 +161,96 @@ class GearController:
             "ladder": list(self.ladder),
             "chunks_per_gear": {str(g): n for g, n in sorted(self.chunks.items())},
             "replays": self.replays,
+        }
+
+
+RUNG_BASE = 512  # the alltoall block ladder's lowest rung (rows)
+
+
+def resolve_block_ladder(top: int) -> list[int]:
+    """The alltoall block-rung ladder: RUNG_BASE, 2 x RUNG_BASE, ...
+    while below `top` (the auto block size), then `top` itself, so the
+    replay loop keeps a rung that behaves exactly as the fixed block."""
+    top = int(top)
+    ladder = []
+    k = RUNG_BASE
+    while k < top:
+        ladder.append(k)
+        k *= 2
+    return ladder + [top]
+
+
+class BlockRungController(GearController):
+    """The alltoall block width for the next chunk, from the rows each
+    (source shard, destination shard) pair actually sent.
+
+    The gears' controller with another fit and another start:
+      - the fit is the smallest rung at or above TWICE the chunk's
+        per-pair high-water (`stats.a2a_pair_hwm`): a block holds one
+        round of one pair, and the headroom keeps a busier round from
+        shedding; upshift at once, downshift after `down_lag` chunks;
+      - a shed (a pair sent more than the rung in some round; the chunk
+        aborted there) replays at the fit of what the aborted chunk saw;
+      - `begin_run` seats the first chunk of every `Simulation.run` from
+        the state it starts on (its pending events), never below the
+        rung carried from the last run, so a run that restarts from an
+        initial state does not shed in its first round.
+    The controller lives on the Simulation; `report` counts the current
+    run's chunks and replays.
+    """
+
+    def __init__(self, top: int, down_lag: int = DOWN_LAG):
+        super().__init__(resolve_block_ladder(top), down_lag)
+        self.fresh = True  # no run has set the rung yet
+        self.pair_hwm = 0  # the current run's per-pair high-water
+
+    @property
+    def rung(self) -> int:
+        return self.gear
+
+    def _fit(self, hwm: int) -> int:
+        for k in self.ladder:
+            if 2 * hwm <= k:
+                return k
+        return self.top
+
+    def begin_run(self, pending_per_pair: int) -> int:
+        """Seat the run's first chunk: the fit of `pending_per_pair`
+        (pending events on the fullest shard / world), raised to the
+        carried rung after the first run. Resets the run's accounting."""
+        want = self._fit(pending_per_pair)
+        self.gear = want if self.fresh else max(self.gear, want)
+        self.fresh = False
+        self._low_streak = 0
+        self.chunks = {}
+        self.replays = 0
+        self.pair_hwm = 0
+        return self.gear
+
+    def note_shed(self, observed_hwm: int | None = None) -> int:
+        if observed_hwm is not None:
+            self.pair_hwm = max(self.pair_hwm, int(observed_hwm))
+        return super().note_shed(observed_hwm)
+
+    def note_chunk(self, gear: int, ob_hwm: int) -> int:
+        self.pair_hwm = max(self.pair_hwm, int(ob_hwm))
+        return super().note_chunk(gear, ob_hwm)
+
+    def retop(self, top: int):
+        """A regrown outbox moved the auto block: rebuild the ladder
+        under the new top, keeping the rung where it is still a rung."""
+        self.ladder = resolve_block_ladder(top)
+        if self.gear not in self.ladder:
+            self.gear = self.top
+
+    def report(self) -> dict:
+        return {
+            "ladder": list(self.ladder),
+            "chunks_per_rung": {
+                str(k): n for k, n in sorted(self.chunks.items())
+            },
+            "replays": self.replays,
+            "pair_hwm": self.pair_hwm,
         }
 
 

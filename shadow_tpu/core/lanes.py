@@ -244,6 +244,9 @@ _STATS_I64 = (
     # experimental.exchange: hierarchical on a multi-device mesh): byte
     # accumulators like ici_bytes, i64 for the same no-wrap reason
     "ici_intra", "ici_inter",
+    # alltoall block-rung signal (present only when the flat alltoall
+    # runs on a mesh with its block left auto): a row-count high-water
+    "a2a_pair_hwm",
 )
 
 STATE_LANES: dict[str, str] = {
@@ -385,7 +388,7 @@ _STATS_PER_SHARD = (
     "ici_bytes", "outbox_hwm", "gear_shed", "pressure",
     "ec_timer", "ec_pkt", "ec_app", "fl_done", "fl_bytes", "fl_rtx",
     "win_bound", "integrity", "iv_mask", "iv_round",
-    "ici_intra", "ici_inter",
+    "ici_intra", "ici_inter", "a2a_pair_hwm",
 )
 
 STATE_LANE_SHAPES: dict[str, tuple] = {
@@ -468,6 +471,12 @@ STATS_EXPORT_EXEMPT: dict[str, str] = {
         "discarded and replayed from its pre-chunk snapshot, so the "
         "counter is structurally zero in any accepted final state; the "
         "gears{} block in sim-stats carries the replay accounting"
+    ),
+    "a2a_pair_hwm": (
+        "per-chunk alltoall block-rung signal (core/gears."
+        "BlockRungController): the driver reads and resets it after "
+        "every accepted chunk, so it holds only the last chunk's rows; "
+        "the a2a_rungs{} block in sim-stats carries the run's high-water"
     ),
     "pressure": (
         "transient pressure-abort control signal (core/pressure.py): a "

@@ -76,6 +76,7 @@ from shadow_tpu.core.integrity import (
     violation_signature,
     violation_total,
 )
+from shadow_tpu.obs.runtime import span_or_null
 
 DEFAULT_MAX_CAPACITY_FACTOR = 8  # auto max_capacity = 8x the base slab
 DEFAULT_MAX_OUTBOX_FACTOR = 4  # auto max_outbox = 4x the base budget
@@ -120,6 +121,15 @@ class ResilienceController:
                   always). Gear decisions and accounting stay in the
                   gear controller; this class only arbitrates WHEN a
                   replay is a gear problem vs a capacity problem.
+      rungctl   — a `core.gears.BlockRungController` (or None: the
+                  alltoall block stays at its top rung). A shed is a rung
+                  problem when the aborted chunk's per-pair high-water
+                  passed the rung, a gear problem when its outbox
+                  high-water passed the gear; the replay raises each
+                  axis that shed. With it, `dispatch` takes the rung as a
+                  fifth argument.
+      rung_top  — budget -> the auto alltoall block at that send budget:
+                  the rung ladder's top after an outbox regrow.
       pressure  — a `config.options.PressureOptions` with an active
                   policy (escalate/abort), or None (gears only — the
                   exact `run_adaptive_chunk` behavior PR 4 shipped).
@@ -148,6 +158,8 @@ class ResilienceController:
         self,
         *,
         gearctl=None,
+        rungctl=None,
+        rung_top=None,
         pressure=None,
         integrity=None,
         queue_block: int = 0,
@@ -157,6 +169,8 @@ class ResilienceController:
         wall=None,
     ):
         self.gearctl = gearctl
+        self.rungctl = rungctl
+        self._rung_top = rung_top
         self.pressure = pressure
         # integrity sentinel (core/integrity.py): the third arbitration
         # branch — a chunk whose in-jit invariant guards tripped is
@@ -348,6 +362,8 @@ class ResilienceController:
                 g.ladder = sorted(set(g.ladder) | {int(new_budget)})
                 if g.gear not in g.ladder:
                     g.gear = g.top
+            if self.rungctl is not None:
+                self.rungctl.retop(self._rung_top(int(new_budget)))
         if self._reshard is not None:
             state = self._reshard(state)
         return state
@@ -370,6 +386,8 @@ class ResilienceController:
 
         gearctl = self.gearctl
         gear = gearctl.gear if gearctl is not None else 0
+        rungctl = self.rungctl
+        rung = rungctl.rung if rungctl is not None else 0
         pressured = self.pressure is not None
         if pressured:
             cap = state.queue.t.shape[1]
@@ -380,6 +398,7 @@ class ResilienceController:
             cap = budget = 0
         need_snap = (
             (gearctl is not None and gear < gearctl.top)
+            or (rungctl is not None and rung < rungctl.top)
             or self.escalate
             or self.integrity_on
         )
@@ -407,8 +426,13 @@ class ResilienceController:
                 if self.wall is not None else 0.0
             )
             try:
-                out = dispatch(state, gear, cap, budget)
-                jax.block_until_ready(out)
+                if rungctl is not None:
+                    out = dispatch(state, gear, cap, budget, rung)
+                else:
+                    out = dispatch(state, gear, cap, budget)
+                # the wait for the chip, traced as such (`shadow.sync`)
+                with span_or_null(None, "sync"):
+                    jax.block_until_ready(out)
             except (KeyboardInterrupt, SystemExit, PressureAbort,
                     IntegrityAbort):
                 raise
@@ -562,17 +586,30 @@ class ResilienceController:
                         "chunk"
                     )
                     iv_last_sig = None
-            shed = (
-                int(np.asarray(jax.device_get(out.stats.gear_shed)).max())
-                - shed0
-            )
-            if shed > 0:
-                # gear problem: the discarded attempt's high-water names
-                # the burst that shed it (read BEFORE the restore)
-                seen = int(
-                    np.asarray(jax.device_get(out.stats.outbox_hwm)).max()
-                )
-                gear = gearctl.note_shed(seen)
+            # one read for the abort and both fits
+            lanes = [out.stats.gear_shed, out.stats.outbox_hwm]
+            if rungctl is not None:
+                lanes.append(out.stats.a2a_pair_hwm)
+            with span_or_null(None, "sync"):
+                got = [int(np.asarray(a).max()) for a in jax.device_get(lanes)]
+            shed_now, ob_hwm = got[0], got[1]
+            pair_hwm = got[2] if rungctl is not None else 0
+            if shed_now > shed0:
+                # the discarded attempt's high-waters name the burst
+                # that shed it and the axis it shed on (read BEFORE the
+                # restore)
+                gear_shed = gearctl is not None and ob_hwm > gear
+                rung_shed = rungctl is not None and pair_hwm > rung
+                if not (gear_shed or rung_shed):
+                    raise RuntimeError(
+                        f"chunk shed at gear {gear}, rung {rung} with "
+                        f"outbox high-water {ob_hwm} and per-pair "
+                        f"high-water {pair_hwm} inside both"
+                    )
+                if gear_shed:
+                    gear = gearctl.note_shed(ob_hwm)
+                if rung_shed:
+                    rung = rungctl.note_shed(pair_hwm)
                 state = self._restore_timed(snap)
                 continue
             if pressured:
@@ -590,16 +627,17 @@ class ResilienceController:
                     continue
             break
         state = out
-        hwm = int(np.asarray(jax.device_get(state.stats.outbox_hwm)).max())
+        hwm = ob_hwm
         self.ob_hwm_run = max(self.ob_hwm_run, hwm)
         advanced = rounds0 is None or int(state.stats.rounds) > rounds0
         if gearctl is not None and advanced:
             gearctl.note_chunk(gear, hwm)
-        state = state._replace(
-            stats=state.stats._replace(
-                outbox_hwm=state.stats.outbox_hwm * 0
-            )
-        )
+        stats = state.stats._replace(outbox_hwm=state.stats.outbox_hwm * 0)
+        if rungctl is not None:
+            if advanced:
+                rungctl.note_chunk(rung, pair_hwm)
+            stats = stats._replace(a2a_pair_hwm=stats.a2a_pair_hwm * 0)
+        state = state._replace(stats=stats)
         if self.escalate:
             state = self._proactive(state, hwm)
         self._last_snap = None

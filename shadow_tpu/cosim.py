@@ -30,7 +30,11 @@ shard-mapped over the mesh exactly like `Engine.run_chunk` — staged sends
 arrive replicated, each shard merges only its own hosts' rows, and capture
 rings are gathered back (mesh-invariance: `tests/test_cosim.py`,
 `tests/test_mixed.py`). The CPU plane stays one Python process; its
-parallelism is `experimental.host_workers`.
+parallelism is `experimental.host_workers`. The alltoall exchange keeps
+its top rung here: a fixed block of the auto size (or the explicit
+`a2a_block`), as before the rung ladder. The ladder's abort-and-replay
+needs a chunk it can discard, and a window that the CPU plane has
+answered cannot be discarded.
 """
 
 from __future__ import annotations

@@ -86,6 +86,7 @@ def dims_of(
     fluid_classes: int = 0,
     fluid_links: int = 0,
     hier: bool = False,
+    a2a_ladder: bool = False,
     payload_words: int | None = None,
     trace_cols: int | None = None,
     flow_cols: int | None = None,
@@ -134,6 +135,8 @@ def dims_of(
         # hierarchical exchange (core/engine.py _exchange_hierarchical):
         # gates the two-tier byte counters (stats.ici_intra/ici_inter)
         "hier": 1 if hier else 0,
+        # alltoall block rungs: gates stats.a2a_pair_hwm
+        "a2a_ladder": 1 if a2a_ladder else 0,
         "integrity": 1 if integrity else 0,
         "integrity_dual": 1 if integrity_dual else 0,
     }
@@ -157,6 +160,7 @@ def dims_of_config(cfg) -> dict[str, int]:
         fluid_classes=cfg.fluid_classes,
         fluid_links=cfg.fluid_links,
         hier=cfg.hier_active,
+        a2a_ladder=cfg.a2a_ladder,
     )
 
 
@@ -199,6 +203,7 @@ def dims_of_state(cfg, state) -> dict[str, int]:
             if getattr(state, "fluid", None) is not None else 0
         ),
         hier=state.stats.ici_intra is not None,
+        a2a_ladder=state.stats.a2a_pair_hwm is not None,
     )
 
 
@@ -250,6 +255,9 @@ def lane_plane_bytes(path: str, dims: dict[str, int]) -> int | None:
     if path in ("stats.ici_intra", "stats.ici_inter") and not dims.get(
         "hier"
     ):
+        return None
+    # alltoall block-rung signal: absent unless the block may leave its top
+    if path == "stats.a2a_pair_hwm" and not dims.get("a2a_ladder"):
         return None
     n = 1
     for tok in shape:
@@ -410,6 +418,7 @@ def state_bytes_at(cfg, capacity: int, send_budget: int) -> int:
         wheel_slots=cfg.wheel_slots,
         wheel_block=cfg.wheel_block,
         hier=cfg.hier_active,
+        a2a_ladder=cfg.a2a_ladder,
     )
     return sum(component_totals(registered_component_bytes(dims)).values())
 
@@ -481,10 +490,9 @@ def ledger_entries(engine) -> dict[str, Any]:
     out = {"base": engine.cfg}
     for g in sorted(engine._gear_chunks):
         out[f"gear={g}"] = dataclasses.replace(engine.cfg, gear_cols=g)
-    for (g, c, b) in sorted(engine._resized_chunks):
-        out[f"cap={c or engine.cfg.queue_capacity}/"
-            f"box={b or engine.cfg.sends_per_host_round}/gear={g}"] = (
-            engine.resized_cfg(g, c, b)
+    for (g, c, b, k) in sorted(engine._resized_chunks):
+        out[f"cap={c}/box={b}/gear={g}" + (f"/a2a={k}" if k else "")] = (
+            engine.resized_cfg(g, c, b, k)
         )
     return out
 

@@ -645,6 +645,9 @@ class Simulation:
         except ValueError as e:
             raise ConfigError(f"experimental.merge_gears: {e}") from e
         self._gearctl = None  # built per run()
+        # alltoall block rungs (core/gears.BlockRungController): built by
+        # the first run() and kept, so later runs start at its rung
+        self._rungctl = None
         self._pressctl = None  # ResilienceController when pressure is active
         self._ob_hwm_run = 0  # run-wide outbox high-water (gear runs reset
         # the device counter per chunk, so the run max is tracked here)
@@ -817,9 +820,11 @@ class Simulation:
         resilience = None
         pressure_on = cfg.pressure.active
         integrity_on = cfg.integrity.enabled
-        if (self._gear_ladder or pressure_on or integrity_on) and (
-            capture is None
-        ):
+        rungctl = self._block_rungs() if capture is None else None
+        if (
+            self._gear_ladder or rungctl is not None or pressure_on
+            or integrity_on
+        ) and capture is None:
             # the shared snapshot-replay seam (core/pressure.py): adaptive
             # merge gears dispatch at the width the controller picked from
             # last chunk's outbox-send high-water — a shed (exact, in-jit)
@@ -827,7 +832,8 @@ class Simulation:
             # pressure plane's escalate policy regrows queue/outbox shapes
             # and replays at the first capacity drop, so accepted chunks
             # are bit-identical to full width at the final shape. The
-            # capture path stays full-width/fixed-shape: its single-round
+            # alltoall block rungs ride the same seam. The capture path
+            # stays full-width, top-rung and fixed-shape: its single-round
             # dispatches re-sync every round anyway (pressure policies are
             # rejected with capture at build time).
             from shadow_tpu.core.gears import GearController
@@ -858,6 +864,10 @@ class Simulation:
                 )
             resilience = ResilienceController(
                 gearctl=gearctl,
+                rungctl=rungctl,
+                rung_top=lambda b: dataclasses.replace(
+                    self.engine_cfg, sends_per_host_round=b
+                ).a2a_auto_block,
                 pressure=cfg.pressure if pressure_on else None,
                 integrity=cfg.integrity if integrity_on else None,
                 queue_block=self.engine_cfg.queue_block,
@@ -926,12 +936,14 @@ class Simulation:
             if resilience is not None:
                 st, lg, hwm = resilience.run_chunk(
                     st,
-                    lambda s, g, c, b: self.engine.run_chunk_resized(
-                        s, self.params, g, c, b
+                    lambda s, g, c, b, k=0: self.engine.run_chunk_resized(
+                        s, self.params, g, c, b, k
                     ),
                 )
                 last_gear = lg if gearctl is not None else None
                 self._ob_hwm_run = max(self._ob_hwm_run, hwm)
+                if rungctl is not None:
+                    self.engine.select_a2a_rung(rungctl.rung)
                 return st
             return self.engine.run_chunk(st, self.params)
 
@@ -1218,6 +1230,31 @@ class Simulation:
             simlog.close()
         return self.stats_report()
 
+    def _block_rungs(self):
+        """The alltoall block-rung controller for this run, seated on the
+        current state, or None where the block cannot leave its top rung
+        (no flat alltoall on a mesh, an explicit `a2a_block`, or a ladder
+        with no rung below the auto size)."""
+        ecfg = self.engine_cfg
+        if not ecfg.a2a_ladder:
+            return None
+        if self._rungctl is None:
+            from shadow_tpu.core.gears import BlockRungController
+
+            ctl = BlockRungController(ecfg.a2a_auto_block)
+            if len(ctl.ladder) < 2:
+                return None
+            self._rungctl = ctl
+        # pending events on the fullest shard, spread over the world: what
+        # one pair can be asked to carry in the run's first round
+        pending = jnp.sum(
+            (self.state.queue.t < TIME_MAX).reshape(ecfg.world, -1), axis=1
+        )
+        fullest = int(np.asarray(jax.device_get(pending)).max())
+        per_pair = -(-fullest // ecfg.world)
+        self.engine.select_a2a_rung(self._rungctl.begin_run(per_pair))
+        return self._rungctl
+
     def _pcap_capture_begin(self):
         """When any host has pcap_enabled, switch the run loop to captured
         single-round dispatches and open per-host eth0.pcap writers (the
@@ -1362,6 +1399,11 @@ class Simulation:
             }
         if self._gearctl is not None:
             report["gears"] = self._gearctl.report()
+        if self._rungctl is not None:
+            # the run's chunks per alltoall block rung, its replays, and
+            # the most rows one (source, destination) shard pair sent in a
+            # round
+            report["a2a_rungs"] = self._rungctl.report()
         if self._pressctl is not None:
             rc = self._pressctl
             report["pressure"] = {
