@@ -69,10 +69,6 @@ def bench_config(num_hosts: int, stop_s: int, rounds_per_chunk: int = 512) -> di
             # baseline leg picks its own setting — the knob tunes dispatch
             # amortization, not simulation semantics.
             "rounds_per_chunk": rounds_per_chunk,
-            # urgency-shed is the framework's default overflow contract;
-            # measured round-2: urgency and append are within noise on this
-            # workload (~46 ms/round both), so the bench runs the default
-            "overflow_shed": "urgency",
         },
         "hosts": {
             "node": {
@@ -354,8 +350,8 @@ def baseline_config(n: int, small: bool) -> tuple[dict, str, int]:
                 "microstep_events": microstep_events,
                 "sends_per_host_round": 24,
                 "rounds_per_chunk": 256,
-                # merge_rows deliberately unset: measured on this workload
-                # (66k sends/round avg, >121k peaks) a 196k truncation was
+                # no post-sort row cut: measured on this workload (66k
+                # sends/round avg, >121k peaks) a 196k truncation was
                 # behavior-clean but 2 ms/round SLOWER than the full 410k
                 # permute — an XLA scheduling artifact, A/B-verified twice.
                 # 128k and below shed (protocol-visible). See BASELINE.md.
@@ -489,9 +485,8 @@ def baseline_config(n: int, small: bool) -> tuple[dict, str, int]:
         cfg["integrity"] = {"enabled": True}
         return cfg, "tgen_tcp_integrity_sim_seconds_per_wall_second", stop_s
     if n == 11:
-        # timer-wheel + sort-free calendar merge bench (PR 12): the
-        # flagship tgen-TCP torus (config 6) with the device timer wheel
-        # and the scatter merge ON. What moves and why:
+        # timer-wheel bench: the flagship tgen-TCP torus (config 6)
+        # with the device timer wheel ON. What moves and why:
         #   - RTO/DELACK timers (10.9% of small-leg events, dominant at
         #     1M-flow scale per tools/net_report.py) leave the event
         #     queue for the [H, S] wheel, so every [H, C] slab pass
@@ -500,11 +495,7 @@ def baseline_config(n: int, small: bool) -> tuple[dict, str, int]:
         #     pending timers: capacity drops 28 -> 14 (the measured
         #     no-drop high-water 13 + 1 margin, same tuning rule as
         #     config 6's drop cliff; digests identical to the roomy
-        #     shapes);
-        #   - non-shedding exchange merges skip the (dst, t, order)
-        #     sort entirely (merge_scatter_free's scatter-add peeling;
-        #     the sort was ~70% of full-width merge cost per
-        #     tools/bench_merge_gears.py).
+        #     shapes).
         # Measured on this box (CPU small leg, 3 paired subprocess runs,
         # digests bit-identical to config 6's 28/7 trajectory, zero
         # drops): base 28/7 median 13.24 sim-s/wall-s vs wheel-4 +
@@ -514,10 +505,7 @@ def baseline_config(n: int, small: bool) -> tuple[dict, str, int]:
         # measured no-drop high-water + 1, and a drop would be loud:
         # counted counters + bench_compare FCT gates). Wheel slots 4 =
         # measured occupancy hwm (1-2) with margin; spills are exact
-        # and counted. merge_scatter stays OFF here: measured -5% on
-        # this leg (the XLA-CPU sort beats scatter-peeling at 240k-row
-        # full-width fan-in; the scatter's regime is low-occupancy/
-        # geared rounds — tests gate its exactness either way).
+        # and counted.
         # microstep_events pins 1 (the wheel's K-fold composition is
         # follow-up work; K=1 is also config 6's measured CPU winner).
         cfg, _, stop_s = baseline_config(6, small)

@@ -143,10 +143,6 @@ class ExperimentalOptions:
     # "deterministic" (StraceLoggingMode, configuration.rs:1162;
     # deterministic omits anything that could differ across machines)
     strace_logging_mode: str = "off"
-    # queue-overflow shed policy at the exchange merge: "urgency" keeps the
-    # most urgent events (tested contract); "append" is cheaper on TPU and
-    # identical whenever queues are sized to never overflow
-    overflow_shed: str = "urgency"
     # multi-device cross-shard exchange: "gather" replicates the outbox to
     # every shard; "alltoall" moves destination-sharded blocks so per-shard
     # ICI bytes and merge input are O(global sends / world) — identical
@@ -167,17 +163,6 @@ class ExperimentalOptions:
     # docs/architecture.md "Hierarchical exchange".
     exchange: str = "auto"
     a2a_block: int = 0  # entries per (src, dst-shard) block; 0 = auto
-    # static cap on post-sort merge gather rows (0 = unbounded): bounds the
-    # exchange-merge's per-round gather work at the real traffic level
-    # instead of the worst-case outbox (hosts x send budget). The exactness
-    # bound is PER SHARD — the merge runs shard-locally, so with world > 1
-    # it is: locally-destined rows + local host count (num_hosts / world)
-    # + 1 <= merge_rows, NOT the global packet/host counts (sizing from
-    # global counts over-provisions the permute on every shard; sizing from
-    # a naive global/world split can under-provision a shard that receives
-    # a traffic burst). Overflow sheds loudly into queue_overflow_dropped.
-    # See EngineConfig.merge_rows and docs/usage.md.
-    merge_rows: int = 0
     # Occupancy-adaptive merge gears (core/gears.py + docs/architecture.md
     # "Adaptive exchange"): compile the round body at a ladder of outbox
     # column widths and let the driver pick next chunk's gear from the
@@ -231,20 +216,12 @@ class ExperimentalOptions:
     # bit-identical to the wheel-off path (tests/test_wheel.py gates it).
     # A full wheel spills to the queue (stats wheel{} block counts it —
     # a sizing signal, never a loss). 0 = off. Sweep tools/bench_wheel.py
-    # to pick S; see docs/architecture.md "Timer wheel and calendar
-    # merge". Requires microstep_events = 1 this round.
+    # to pick S; see docs/architecture.md "Timer wheel". Requires
+    # microstep_events = 1 this round.
     timer_wheel: int = 0
     # wheel block-cache block size; 0 = auto (divisor of timer_wheel
     # near sqrt — the bucketed-queue balance rule)
     timer_wheel_block: int = 0
-    # Sort-free calendar-queue exchange merge (ops/merge.py
-    # merge_scatter_free): non-shedding rounds bucket incoming rows by
-    # destination via scatter-add instead of the full (dst, t, order)
-    # sort; overflow rounds fall back to the sort in-jit, so results are
-    # bit-identical on every workload. Measured CPU win (the CPU merge
-    # is sort-dominated); off by default.
-    merge_scatter: bool = False
-
     def resolve_shapes(self, num_hosts: int) -> tuple[int, int, int]:
         """(queue_capacity, send_budget, rounds_per_chunk) with 0-valued
         knobs sized from the host count (r4, VERDICT r3 weak #7):
@@ -314,8 +291,6 @@ class ExperimentalOptions:
         ):
             if f in d:
                 setattr(e, f, str(d.pop(f)))
-        if "overflow_shed" in d:
-            e.overflow_shed = str(d.pop("overflow_shed"))
         if "exchange" in d:
             e.exchange = str(d.pop("exchange"))
         if "a2a_block" in d:
@@ -361,11 +336,6 @@ class ExperimentalOptions:
                 f"experimental.strace_logging_mode must be off|standard|"
                 f"deterministic, got {e.strace_logging_mode!r}"
             )
-        if e.overflow_shed not in ("urgency", "append"):
-            raise ConfigError(
-                f"experimental.overflow_shed must be urgency|append, "
-                f"got {e.overflow_shed!r}"
-            )
         if e.scheduler not in ("tpu", "cpu-reference"):
             raise ConfigError(
                 f"experimental.scheduler must be tpu|cpu-reference, "
@@ -383,7 +353,6 @@ class ExperimentalOptions:
             "use_codel",
             "packet_breadcrumbs",
             "use_cpu_pinning",
-            "merge_scatter",
         ):
             if f in d:
                 setattr(e, f, bool(d.pop(f)))
@@ -396,7 +365,6 @@ class ExperimentalOptions:
             "microstep_limit",
             "microstep_events",
             "host_workers",
-            "merge_rows",
             "timer_wheel",
             "timer_wheel_block",
         ):

@@ -81,6 +81,7 @@ from shadow_tpu.ops import (
     q_pop_min,
     q_push_many,
 )
+from shadow_tpu.ops.merge import merge_apply, merge_empty_plan, merge_plan
 from shadow_tpu.ops.wheel import (
     wheel_free,
     wheel_next_time,
@@ -421,11 +422,6 @@ class EngineConfig:
     # is an exact no-op — eliding it at trace time removes ~40% of the
     # microstep's ops with bit-identical results (digests unchanged).
     shaping: bool = True
-    # Cheap overflow-shed: the exchange merge groups by destination with a
-    # 2xi32 sort (append-order shed) instead of the 3-key urgency sort —
-    # identical results whenever queues never overflow (see
-    # ops/merge.py merge_flat_events). Opt-in for sized workloads.
-    cheap_shed: bool = False
     # Per-packet latency jitter (graph edges carry a `jitter` amplitude):
     # statically elided when no edge has jitter so jitter-free sims draw no
     # extra RNG (digest stability).
@@ -493,12 +489,6 @@ class EngineConfig:
     # gear_cols, the driver's EngineConfig always carries 0: rung
     # programs are built via Engine.run_chunk_resized.
     a2a_rung: int = 0
-    # Static cap on post-sort merge gather work (ops/merge.py): only the
-    # first `merge_rows` sorted exchange rows are materialized. Exact while
-    # (valid rows + num_hosts + 1) <= merge_rows; beyond it rows shed by
-    # sorted position and count in queue.dropped. 0 = unbounded (the full
-    # worst-case outbox, num_hosts * sends_per_host_round rows).
-    merge_rows: int = 0
     # Active merge gear (experimental.merge_gears): the number of outbox
     # LANE COLUMNS the exchange flattens, sorts, and merges. The outbox is
     # [H, B] with host h's k-th send of the round in column k, so when no
@@ -602,14 +592,6 @@ class EngineConfig:
     # 0 = auto (a divisor of wheel_slots near sqrt — ops/wheel.py
     # resolve_wheel_block). Must divide wheel_slots.
     wheel_block: int = 0
-    # Sort-free calendar-queue exchange merge (ops/merge.py
-    # merge_scatter_free): bucket incoming exchange rows by destination
-    # via scatter-add + scatter-max peeling instead of the full
-    # (dst, t, order) sort on the non-shedding fast path; any round where
-    # a destination would overflow falls back to the sort path in-jit, so
-    # digests/events/drops are bit-identical on every workload. False
-    # (default) keeps the sort merge and traces no scatter code.
-    merge_scatter: bool = False
     # Fluid traffic plane statics (net/fluid.py; config `fluid:`). The
     # ARRAYS live in EngineParams.fluid; these are the trace-time
     # shape/coupling knobs the round body specializes on. fluid_classes
@@ -2014,7 +1996,7 @@ def _window_step(
         )
     if cfg.pressure_abort:
         # pressure signal: the shard-local capacity-drop total (queue-push
-        # overflow + merge/merge_rows sheds in queue.dropped, alltoall
+        # overflow + merge sheds in queue.dropped, alltoall
         # block sheds, outbox overflow, per-host send-budget drops),
         # psum'd so every shard carries the GLOBAL cumulative count and
         # the chunk loop's first-drop abort stays mesh-uniform. Two [H]
@@ -3400,67 +3382,36 @@ def _merge_into_queue(cfg, queue0, flat, has_sends):
     The merge's sort dominates round cost; rounds where NO shard sent
     anything (timer-heavy workloads, drained phases) skip it entirely —
     `has_sends` is identical on all shards, so the branch is uniform
-    across the mesh. The cond wraps only the PLAN (sorts + SoA sorted
-    vectors): branches returning the whole queue forced XLA to copy every
-    slab at the branch boundary each round — traced at ~55% of the
-    PHOLD-torus round cost — while the plan is one [H, C] index map plus
-    [K]-vector sorted fields, cheap to copy at every capacity. The apply
-    runs unconditionally as a single where-pass."""
+    across the mesh. At capacity >= 48 the cond wraps only the PLAN
+    (`merge_plan`): branches returning the whole queue forced XLA to copy
+    every slab at the branch boundary each round — traced at ~55% of the
+    PHOLD-torus round cost — while the plan is one [H, C, W] packed block.
+    The apply then runs unconditionally as a single where-pass."""
     q_flat = as_flat(queue0)
-    if cfg.merge_scatter:
-        # sort-free calendar scatter (ops/merge.py merge_scatter_free):
-        # non-shedding rounds bucket rows by destination via scatter-add
-        # peeling — no (dst, t, order) sort at all; a round where any
-        # destination would overflow falls back to the sort path IN-JIT,
-        # so shed order (hence digests/drops) is identical on every
-        # workload. Runs in the fused-cond form: the fast path reads the
-        # whole queue for its free ranking, so the plan split's
-        # time-plane-only cond has nothing to buy here.
-        from shadow_tpu.ops.merge import merge_scatter_free
-
-        merged = lax.cond(
-            has_sends,
-            lambda queue: merge_scatter_free(
-                queue, *flat, cfg.max_round_inserts,
-                shed_urgency=not cfg.cheap_shed,
-                merge_rows=cfg.merge_rows,
-            ),
-            lambda queue: queue,
-            q_flat,
-        )
-    elif jax.default_backend() == "cpu" or cfg.queue_capacity < 48:
-        # Fused merge inside the cond. On CPU the scatter path is faster
-        # and branch copies are cheap. On TPU this wins at SMALL slab
-        # capacities (measured: PHOLD-torus cap 16 ran 40% slower with the
-        # plan split — the [H, C, W] plan materialization costs more than
-        # the small branch-boundary copies it avoids; at cap >= ~48 the
-        # copy volume dominates and the split below wins).
+    if cfg.queue_capacity < 48:
+        # Fused merge inside the cond: it wins at SMALL slab capacities
+        # (measured in round 5, on the old stack: PHOLD-torus cap 16 ran 40%
+        # slower on the v5e with the plan split — the [H, C, W] plan
+        # materialization costs more than the small branch-boundary copies
+        # it avoids; at cap >= ~48 the copy volume dominates and the split
+        # below won). The choice depends on the capacity alone, so every
+        # backend runs the program the chip runs.
         merged = lax.cond(
             has_sends,
             lambda queue: merge_flat_events(
-                queue, *flat, cfg.max_round_inserts,
-                shed_urgency=not cfg.cheap_shed,
-                merge_rows=cfg.merge_rows,
+                queue, *flat, cfg.max_round_inserts
             ),
             lambda queue: queue,
             q_flat,
         )
     else:
-        from shadow_tpu.ops.merge import (
-            merge_apply, merge_empty_plan, merge_plan,
-        )
-
         p_words = flat[4].shape[-1]
         # the cond consumes ONLY the time plane (free-slot source): feeding
         # the whole queue through would add a second consumer per slab and
         # reintroduce the branch-boundary copies this split removes
         take, gw, dropped_add = lax.cond(
             has_sends,
-            lambda q_t: merge_plan(
-                q_t, *flat, cfg.max_round_inserts,
-                shed_urgency=not cfg.cheap_shed,
-                merge_rows=cfg.merge_rows,
-            ),
+            lambda q_t: merge_plan(q_t, *flat, cfg.max_round_inserts),
             lambda q_t: merge_empty_plan(q_t, p_words),
             q_flat.t,
         )

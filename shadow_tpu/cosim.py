@@ -258,9 +258,6 @@ class HybridSimulation:
             # microstep loop / the cross-shard merge), so the CPU plane
             # sees identical deliveries either way
             microstep_events=ex.microstep_events,
-            # the sort-free calendar merge acts below the bridge (the
-            # cross-shard merge), so it rides along like the K-way fold
-            merge_scatter=ex.merge_scatter,
             exchange=ex.resolve_exchange(world),
             a2a_block=ex.a2a_block,
             world=world,

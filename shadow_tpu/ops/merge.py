@@ -7,8 +7,9 @@ emitted during a round are staged in a flat outbox, exchanged at the barrier,
 and inserted here in an order fully determined by the packed event order key —
 so the result is bit-identical for any shard count or arrival interleaving.
 
-TPU algorithm (`_merge_gather_plan`; static shapes, no scatters — measured
-on v5e the original scatter formulation was ~60% of total round cost):
+The algorithm (`merge_plan`, the same on every backend; static shapes, no
+scatters — measured on v5e the original scatter formulation was ~60% of
+total round cost):
   1. ONE stable single-key sort groups entries by destination (invalid
      entries sort to the end). The TPU compiler's time for a sort grows
      steeply with its key words: the earlier (dst, time, order) key — five
@@ -33,25 +34,15 @@ on v5e the original scatter formulation was ~60% of total round cost):
      beyond the free count or `max_inserts` land in `dropped` (counted,
      never silent).
 
-The gather inversion is exact because the CPU scatter path maps urgency rank
-r to the r-th free slot — the same bijection read from the other side
-(tests/test_events.py pins the two paths bit-equal, overflow included).
-
-`merge_rows` (round 5) statically bounds the merge to the first k positions
-of the (dst, time, order)-sorted layout with one token leading each host's
-segment: every row a non-shedding round needs lives in the first
-(valid + H + 1) positions. Rows past the bound shed by that position and
-are counted, never silent.
-
-Merge gears (round 7) shrink the SORT itself the same way `merge_rows`
-shrank the gather: every entry point here is width-parameterized (N is just
-the length of the flat arrays handed in), so the engine compiles the round
-body at a ladder of outbox column widths and feeds the sort H x gear_cols
-rows instead of H x B. The truncation is positional on the [H, B] lane
-layout (host h's k-th send sits in column k), so it is exact whenever no
-host staged more than gear_cols sends that round — `gear_shed_count` is the
-exact detector, and the driver replays a shedding chunk one gear up from a
-pre-chunk snapshot (core/engine.py `_gear_sliced_outbox`, core/gears.py).
+Merge gears (round 7) shrink the sort: every entry point here is
+width-parameterized (N is just the length of the flat arrays handed in), so
+the engine compiles the round body at a ladder of outbox column widths and
+feeds the sort H x gear_cols rows instead of H x B. The truncation is
+positional on the [H, B] lane layout (host h's k-th send sits in column k),
+so it is exact whenever no host staged more than gear_cols sends that
+round — `gear_shed_count` is the exact detector, and the driver replays a
+shedding chunk one gear up from a pre-chunk snapshot (core/engine.py
+`_gear_sliced_outbox`, core/gears.py).
 
 Formulations tried and rejected in round 5 (measured on the v5e, kept for
 the record — all three looked faster in isolated microbenches and were not):
@@ -90,58 +81,6 @@ from shadow_tpu.ops.events import (
     bucket_rebuild,
 )
 from shadow_tpu.simtime import TIME_MAX
-
-
-def _merge_scatter(q, s_dst, s_idx, t, order, kind, payload, r_cap,
-                   merge_rows=0):
-    """CPU insertion path: rank entries within their dst segment and scatter
-    each into its dst's rank-th free slot (the round-1 formulation)."""
-    num_hosts, cap = q.t.shape
-    n = s_dst.shape[0]
-    s_t = t[s_idx]
-    s_order = order[s_idx]
-    s_kind = kind[s_idx].astype(jnp.int32)
-    s_payload = payload[s_idx]
-    s_valid = s_dst < num_hosts
-
-    seg_start = jnp.searchsorted(s_dst, s_dst, side="left")
-    rank = jnp.arange(n, dtype=jnp.int64) - seg_start
-
-    free = q.t == TIME_MAX  # [H, C]
-    free_rank = jnp.cumsum(free.astype(jnp.int32), axis=1) - 1
-    scatter_r = jnp.where(free & (free_rank < r_cap), free_rank, r_cap)
-    slot_of_rank = jnp.full((num_hosts, r_cap), -1, jnp.int32)
-    hh = jnp.broadcast_to(jnp.arange(num_hosts)[:, None], free.shape)
-    cc = jnp.broadcast_to(jnp.arange(cap, dtype=jnp.int32)[None, :], free.shape)
-    slot_of_rank = slot_of_rank.at[hh, scatter_r].set(cc, mode="drop")
-
-    in_rank = s_valid & (rank < r_cap)
-    if merge_rows > 0:
-        # mirror the gather path's positional truncation bit-exactly: its
-        # sorted array interleaves one token per host, so this path's
-        # position p sits at gather position p + s_dst[p] + 1 (tokens for
-        # hosts 0..s_dst[p] precede it). Rows landing at or past the bound
-        # shed there and must shed identically here.
-        gather_pos = jnp.arange(n, dtype=jnp.int64) + s_dst + 1
-        in_rank = in_rank & (gather_pos < merge_rows)
-    h_safe = jnp.where(s_valid, s_dst, 0).astype(jnp.int32)
-    r_safe = jnp.where(in_rank, rank, 0).astype(jnp.int32)
-    slot = slot_of_rank[h_safe, r_safe]
-    ok = in_rank & (slot >= 0)
-    h_scatter = jnp.where(ok, h_safe, num_hosts)
-    s_scatter = jnp.where(ok, slot, 0)
-
-    lost = s_valid & ~ok
-    dropped = q.dropped.at[jnp.where(lost, h_safe, num_hosts)].add(
-        jnp.where(lost, 1, 0).astype(jnp.int64), mode="drop"
-    )
-    return EventQueue(
-        t=q.t.at[h_scatter, s_scatter].set(s_t, mode="drop"),
-        order=q.order.at[h_scatter, s_scatter].set(s_order, mode="drop"),
-        kind=q.kind.at[h_scatter, s_scatter].set(s_kind, mode="drop"),
-        payload=q.payload.at[h_scatter, s_scatter].set(s_payload, mode="drop"),
-        dropped=dropped,
-    )
 
 
 def gear_shed_count(sent_round, gear_cols: int):
@@ -276,11 +215,9 @@ def merge_plan(
     payload,
     valid,
     max_inserts: int,
-    shed_urgency: bool = True,
-    merge_rows: int = 0,
 ):
-    """The sort/gather half of the gather-path merge, WITHOUT writing the
-    queue: returns (take bool[H, C], g i32[H, C, W], dropped_add i64[H]).
+    """Steps 1-4 of the merge, WITHOUT writing the queue: returns
+    (take bool[H, C], g i32[H, C, W], dropped_add i64[H]).
 
     Split out so the engine can wrap only THIS half in the empty-round
     `lax.cond`: a cond whose branches return the whole queue copies every
@@ -291,10 +228,64 @@ def merge_plan(
     through the cond made every plane a second consumer and forced XLA to
     copy the slabs around the branch anyway (measured as a 40% round-cost
     regression on PHOLD-torus before the narrowing)."""
-    return _merge_gather_plan(
-        q_t, dst, t, order, kind, payload, valid, max_inserts, shed_urgency,
-        merge_rows,
-    )
+    num_hosts, cap = q_t.shape
+    n = dst.shape[0]
+    r_cap = min(max_inserts, cap)
+    # steps 1-3 and step 4 carry scopes of their own, so that a device
+    # trace splits the merge between ordering rows and writing slabs
+    with jax.named_scope("shadow_merge_order"):
+        dst_key = jnp.where(valid, dst.astype(jnp.int32), jnp.int32(num_hosts))
+        iota = jnp.arange(n, dtype=jnp.int32)
+
+        # -- 1. group rows by destination: ONE single-key stable sort (rows of
+        # a destination keep their source order; invalid rows sort last). The
+        # urgency order inside a destination is a per-host row sort below, not
+        # a (dst, t, order) key of this sort: the TPU compiler's cost for a
+        # sort grows steeply with its key words, and that 5-word key at the
+        # flagship's 250k rows did not compile in 10 minutes. The source
+        # index and the words of t and order are sorted with it, so that a
+        # host's window is one row gather of the grouped [N, 5] matrix: as
+        # ONE [5, N] operand under the key repeated per row (a stable sort
+        # gives every row the same permutation): the compiler's time grows
+        # with every extra [N] operand, not with the rows of one.
+        carried = jnp.concatenate(
+            [iota[None], _split64(t).T, _split64(order).T], axis=0
+        )
+        s_key, s_carried = lax.sort(
+            (jnp.broadcast_to(dst_key, carried.shape), carried),
+            dimension=1, num_keys=1, is_stable=True,
+        )
+        s_dst, rows = s_key[0], s_carried.T  # i32[N], i32[N, 5]
+
+        # -- 2. segment bounds: host h's rows are rows[start[h]:start[h + 1]]
+        with jax.named_scope("shadow_merge_bounds"):
+            start = _segment_starts(s_dst, num_hosts + 1)
+            seg_len = start[1:] - start[:-1]  # i32[H]
+
+        # -- 3. host h takes its n_ins most urgent rows
+        with jax.named_scope("shadow_merge_window"):
+            n_ins = jnp.minimum(seg_len, r_cap)  # i32[H]
+            sel = _segment_urgency_rows(rows, start[:-1], seg_len, r_cap)
+
+    with jax.named_scope("shadow_merge_write"):
+        # -- 4. the r-th free slot of host h takes row sel[h, r]: a one-hot
+        # select over r (per-row dynamic gathers lower to slow custom kernels
+        # on TPU), then ONE row gather of all event fields bit-packed into an
+        # [N, W] i32 matrix
+        free = q_t == TIME_MAX  # [H, C]
+        free_rank = jnp.cumsum(free.astype(jnp.int32), axis=1) - 1  # [H, C]
+        take = free & (free_rank < n_ins[:, None])
+        oh = free_rank[:, :, None] == jnp.arange(r_cap, dtype=jnp.int32)
+        row = jnp.sum(jnp.where(oh, sel[:, None, :], 0), axis=2)
+        row = jnp.where(take, row, 0)
+        words = _pack_words(t, order, kind.astype(jnp.int32), payload)
+        g = words[row]  # [H, C, W]
+
+        # -- overflow accounting (elementwise: order-independent,
+        # deterministic)
+        inserted = jnp.sum(take.astype(jnp.int32), axis=1)
+        dropped_add = (seg_len - inserted).astype(jnp.int64)
+    return take, g, dropped_add
 
 
 def merge_empty_plan(q_t, p_words: int):
@@ -330,16 +321,10 @@ def merge_flat_events(
     payload,  # i32[N, P]
     valid,  # bool[N]
     max_inserts: int,
-    shed_urgency: bool = True,
-    force_path: str | None = None,  # tests: 'gather' | 'scatter'
-    merge_rows: int = 0,
 ) -> EventQueue:
-    """`shed_urgency=True` (default): overflow sheds by (time, order) so the
-    most urgent events always win slots — the tested contract. False: a
-    single-key sort grouped by dst with buffer-order ranks; identical
-    simulation results whenever nothing overflows (pop_min re-derives the
-    total order from slot contents), at a fraction of the sort cost — the
-    engine's `cheap_shed` knob for workloads sized to never overflow.
+    """Insert the valid rows into their hosts' free slots: `merge_plan`
+    then `merge_apply`. Overflow sheds by (time, order), so the most
+    urgent events always win slots.
 
     Accepts either queue type: a `BucketQueue` merges through its flat slab
     view and comes back with freshly rebuilt block caches — merges are
@@ -349,179 +334,12 @@ def merge_flat_events(
     caches itself so empty rounds can skip the rebuild."""
     if isinstance(q, BucketQueue):
         merged = merge_flat_events(
-            as_flat(q), dst, t, order, kind, payload, valid, max_inserts,
-            shed_urgency=shed_urgency, force_path=force_path,
-            merge_rows=merge_rows,
+            as_flat(q), dst, t, order, kind, payload, valid, max_inserts
         )
         return bucket_rebuild(merged, q.block)
-    num_hosts, cap = q.t.shape
-    n = dst.shape[0]
-    r_cap = min(max_inserts, cap)
-
-    dst_key = jnp.where(valid, dst.astype(jnp.int32), jnp.int32(num_hosts))
-    iota = jnp.arange(n, dtype=jnp.int32)
-
-    path = force_path or (
-        "scatter" if jax.default_backend() == "cpu" else "gather"
-    )
-    if path == "scatter":
-        # scatter formulation: faster on CPU (TPU scatters are the slow path
-        # the gather variant below exists to avoid; CPU scatters are cheap).
-        # Identical insertion set and order -> identical queues and digests.
-        if shed_urgency:
-            s_dst, _, _, s_idx = lax.sort(
-                (dst_key, t, order, iota), num_keys=3
-            )
-        else:
-            idx_bits = max(1, (n - 1).bit_length())
-            packed = (dst_key.astype(jnp.int64) << idx_bits) | iota.astype(
-                jnp.int64
-            )
-            s_packed = lax.sort(packed)
-            s_dst = (s_packed >> idx_bits).astype(jnp.int32)
-            s_idx = (s_packed & ((1 << idx_bits) - 1)).astype(jnp.int32)
-        return _merge_scatter(
-            q, s_dst, s_idx, t, order, kind, payload, r_cap, merge_rows
-        )
-
     return merge_apply(
-        q,
-        *_merge_gather_plan(
-            q.t, dst, t, order, kind, payload, valid, max_inserts,
-            shed_urgency, merge_rows
-        ),
+        q, *merge_plan(q.t, dst, t, order, kind, payload, valid, max_inserts)
     )
-
-
-def merge_scatter_free(
-    q: EventQueue,
-    dst,  # i32[N] local host index of each entry
-    t,  # i64[N]
-    order,  # i64[N]
-    kind,  # i32[N]
-    payload,  # i32[N, P]
-    valid,  # bool[N]
-    max_inserts: int,
-    shed_urgency: bool = True,
-    merge_rows: int = 0,
-) -> EventQueue:
-    """Sort-free calendar-queue merge: bucket incoming exchange rows by
-    destination via scatter-add instead of the full (dst, t, order) sort
-    — the non-shedding FAST PATH; the sort path stays as the shed/
-    overflow fallback (`merge_flat_events`).
-
-    Why no sort is needed when nothing sheds: the sort serves two
-    purposes — grouping rows by destination, and ordering them by
-    urgency WITHIN a destination so overflow sheds the latest. Slot
-    positions are unobservable (`migrate_queue`'s invariant: pops
-    re-derive the (time, order) total order from slot contents, drops
-    depend only on the free-slot COUNT), so when every row fits, ANY
-    deterministic row -> free-slot bijection yields a bit-identical
-    simulation. The within-destination order is then irrelevant and the
-    sort is pure overhead.
-
-    Fast-path admission is exact and cheap: a scatter-add histogram
-    counts arrivals per destination; the fast path runs iff every
-    destination's count fits both its free slots and the insert cap
-    (and, under a `merge_rows` bound, the sorted-prefix bound provably
-    cannot bind). Otherwise the call falls through to the sort path,
-    whose shed order is the tested urgency/append contract — so enabling
-    the scatter merge NEVER changes digests, events, or drop counters
-    on any workload (tests/test_wheel.py gates equality on forced
-    overflow too).
-
-    Slot assignment without a sort: iterative scatter-max peeling. Each
-    pass scatters row indices with `max` onto a per-destination cell;
-    the winner (one per contended destination, fully deterministic)
-    takes the destination's next free rank and drops out. Passes needed
-    = the max arrivals to any ONE destination that round — 1-2 for
-    balanced traffic, bounded by the insert cap in the worst case —
-    each pass a handful of O(N) scatters/gathers versus the
-    O(M log M) 4-operand sort (M = N + H + 1) it replaces.
-    `shed_urgency` is accepted for signature parity and only shapes the
-    FALLBACK's shed order (the fast path never sheds)."""
-    num_hosts, cap = q.t.shape
-    n = dst.shape[0]
-    r_cap = min(max_inserts, cap)
-    dst_safe = jnp.where(valid, dst.astype(jnp.int32), jnp.int32(num_hosts))
-
-    cnt = jnp.zeros((num_hosts + 1,), jnp.int32).at[dst_safe].add(
-        jnp.ones((n,), jnp.int32)
-    )
-    free_cnt = jnp.sum((q.t == TIME_MAX).astype(jnp.int32), axis=1)
-    fits = jnp.all(
-        cnt[:num_hosts] <= jnp.minimum(free_cnt, jnp.int32(r_cap))
-    )
-    if merge_rows > 0:
-        # conservative: with every valid row + one token per host + the
-        # sentinel inside the bound, no sorted position can shed
-        n_valid = jnp.sum(valid.astype(jnp.int32))
-        fits = fits & (n_valid + num_hosts + 1 <= merge_rows)
-
-    def fast(queue: EventQueue) -> EventQueue:
-        # per-destination free-slot ranking (the same rank -> slot
-        # bijection the scatter path uses)
-        free = queue.t == TIME_MAX
-        free_rank = jnp.cumsum(free.astype(jnp.int32), axis=1) - 1
-        hh = jnp.broadcast_to(
-            jnp.arange(num_hosts)[:, None], free.shape
-        )
-        cc = jnp.broadcast_to(
-            jnp.arange(cap, dtype=jnp.int32)[None, :], free.shape
-        )
-        scatter_r = jnp.where(free & (free_rank < r_cap), free_rank, r_cap)
-        slot_of_rank = jnp.full((num_hosts, r_cap), -1, jnp.int32)
-        slot_of_rank = slot_of_rank.at[hh, scatter_r].set(cc, mode="drop")
-
-        iota = jnp.arange(n, dtype=jnp.int32)
-
-        def cond(carry):
-            _, _, unassigned = carry
-            return jnp.any(unassigned)
-
-        def body(carry):
-            rank, fill, unassigned = carry
-            dst_u = jnp.where(unassigned, dst_safe, jnp.int32(num_hosts))
-            win = jnp.full((num_hosts + 1,), -1, jnp.int32).at[dst_u].max(
-                jnp.where(unassigned, iota, -1)
-            )
-            iswin = unassigned & (win[dst_safe] == iota)
-            rank = jnp.where(iswin, fill[dst_safe], rank)
-            fill = fill.at[dst_safe].add(iswin.astype(jnp.int32))
-            return rank, fill, unassigned & ~iswin
-
-        rank, _, _ = lax.while_loop(
-            cond,
-            body,
-            (
-                jnp.zeros((n,), jnp.int32),
-                jnp.zeros((num_hosts + 1,), jnp.int32),
-                valid,
-            ),
-        )
-        # every valid row has a distinct (dst, rank) with rank < its
-        # destination's free count <= r_cap, so the slot lookup never
-        # misses; invalid rows scatter to host index H and drop
-        slot = slot_of_rank[jnp.where(valid, dst_safe, 0), rank]
-        h_sc = jnp.where(valid, dst_safe, jnp.int32(num_hosts))
-        s_sc = jnp.where(valid, slot, 0)
-        return EventQueue(
-            t=queue.t.at[h_sc, s_sc].set(t, mode="drop"),
-            order=queue.order.at[h_sc, s_sc].set(order, mode="drop"),
-            kind=queue.kind.at[h_sc, s_sc].set(
-                kind.astype(jnp.int32), mode="drop"
-            ),
-            payload=queue.payload.at[h_sc, s_sc].set(payload, mode="drop"),
-            dropped=queue.dropped,  # fast path never sheds
-        )
-
-    def fallback(queue: EventQueue) -> EventQueue:
-        return merge_flat_events(
-            queue, dst, t, order, kind, payload, valid, max_inserts,
-            shed_urgency=shed_urgency, merge_rows=merge_rows,
-        )
-
-    return lax.cond(fits, fast, fallback, q)
 
 
 _BLOCK = 128  # lanes of a TPU vector register
@@ -591,80 +409,3 @@ def _segment_urgency_rows(rows, start, seg_len, r: int):
     )
     n_chunks = jnp.maximum((jnp.max(seg_len) + r - 1) // r, 1)
     return lax.fori_loop(0, n_chunks, body, pads)[2]
-
-
-def _merge_gather_plan(
-    q_t, dst, t, order, kind, payload, valid, max_inserts, shed_urgency,
-    merge_rows=0,
-):
-    num_hosts, cap = q_t.shape
-    n = dst.shape[0]
-    r_cap = min(max_inserts, cap)
-    # steps 1-3 and step 4 carry scopes of their own, so that a device
-    # trace splits the merge between ordering rows and writing slabs
-    with jax.named_scope("shadow_merge_order"):
-        dst_key = jnp.where(valid, dst.astype(jnp.int32), jnp.int32(num_hosts))
-        iota = jnp.arange(n, dtype=jnp.int32)
-
-        # -- 1. group rows by destination: ONE single-key stable sort (rows of
-        # a destination keep their source order; invalid rows sort last). The
-        # urgency order inside a destination is a per-host row sort below, not
-        # a (dst, t, order) key of this sort: the TPU compiler's cost for a
-        # sort grows steeply with its key words, and that 5-word key at the
-        # flagship's 250k rows did not compile in 10 minutes. The source
-        # index and the words of t and order are sorted with it, so that a
-        # host's window is one row gather of the grouped [N, 5] matrix: as
-        # ONE [5, N] operand under the key repeated per row (a stable sort
-        # gives every row the same permutation): the compiler's time grows
-        # with every extra [N] operand, not with the rows of one.
-        carried = jnp.concatenate(
-            [iota[None], _split64(t).T, _split64(order).T], axis=0
-        )
-        s_key, s_carried = lax.sort(
-            (jnp.broadcast_to(dst_key, carried.shape), carried),
-            dimension=1, num_keys=1, is_stable=True,
-        )
-        s_dst, rows = s_key[0], s_carried.T  # i32[N], i32[N, 5]
-
-        # -- 2. segment bounds: host h's rows are rows[start[h]:start[h + 1]]
-        with jax.named_scope("shadow_merge_bounds"):
-            start = _segment_starts(s_dst, num_hosts + 1)
-            seg_len = start[1:] - start[:-1]  # i32[H]
-
-        # -- 3. host h takes its n_ins most urgent rows. `merge_rows` truncates
-        # the (dst, t, order)-sorted layout in which one token per host leads
-        # its segment, so host h's first row sits at position start[h] + h + 1
-        # there (the scatter path mirrors the same cut).
-        with jax.named_scope("shadow_merge_window"):
-            m = n + num_hosts + 1
-            k = m if merge_rows <= 0 else min(merge_rows, m)
-            first = start[:-1] + jnp.arange(num_hosts, dtype=jnp.int32)
-            n_ins = jnp.minimum(
-                jnp.minimum(seg_len, r_cap), jnp.maximum(k - 1 - first, 0)
-            )  # i32[H]
-            if shed_urgency:
-                sel = _segment_urgency_rows(rows, start[:-1], seg_len, r_cap)
-            else:
-                rr = start[:-1, None] + jnp.arange(r_cap, dtype=jnp.int32)
-                # [H, r]; rows past n_ins unused
-                sel = rows[jnp.minimum(rr, n - 1), 0]
-
-    with jax.named_scope("shadow_merge_write"):
-        # -- 4. the r-th free slot of host h takes row sel[h, r]: a one-hot
-        # select over r (per-row dynamic gathers lower to slow custom kernels
-        # on TPU), then ONE row gather of all event fields bit-packed into an
-        # [N, W] i32 matrix
-        free = q_t == TIME_MAX  # [H, C]
-        free_rank = jnp.cumsum(free.astype(jnp.int32), axis=1) - 1  # [H, C]
-        take = free & (free_rank < n_ins[:, None])
-        oh = free_rank[:, :, None] == jnp.arange(r_cap, dtype=jnp.int32)
-        row = jnp.sum(jnp.where(oh, sel[:, None, :], 0), axis=2)
-        row = jnp.where(take, row, 0)
-        words = _pack_words(t, order, kind.astype(jnp.int32), payload)
-        g = words[row]  # [H, C, W]
-
-        # -- overflow accounting (elementwise: order-independent,
-        # deterministic)
-        inserted = jnp.sum(take.astype(jnp.int32), axis=1)
-        dropped_add = (seg_len - inserted).astype(jnp.int64)
-    return take, g, dropped_add

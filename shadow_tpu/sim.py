@@ -533,12 +533,6 @@ class Simulation:
                     "disable pcap or the integrity block"
                 )
         if press.policy == "escalate":
-            if ex.merge_rows > 0:
-                raise ConfigError(
-                    "pressure: escalate cannot cure a merge_rows bound "
-                    "(its shed is positional, not capacity-sized) — drop "
-                    "merge_rows or keep policy: drop/abort"
-                )
             if ex.a2a_block > 0:
                 raise ConfigError(
                     "pressure: escalate cannot cure an explicit "
@@ -589,11 +583,9 @@ class Simulation:
             shaping=any(
                 h.bw_up_bits > 0 or h.bw_down_bits > 0 for h in self.hosts
             ),
-            cheap_shed=ex.overflow_shed == "append",
             cpu_delay_ns=ex.cpu_delay,
             exchange=ex.resolve_exchange(world),
             a2a_block=ex.a2a_block,
-            merge_rows=ex.merge_rows,
             # round tracer ring sized to the chunk length: the run loop
             # drains at every chunk boundary, so the ring can never wrap
             trace_rounds=rpc if cfg.observability.trace else 0,
@@ -619,12 +611,10 @@ class Simulation:
             # code (the default program stays byte-identical)
             integrity=cfg.integrity.enabled,
             integrity_dual=cfg.integrity.enabled and cfg.integrity.dual_digest,
-            # timer wheel + sort-free calendar merge (ops/wheel.py,
-            # ops/merge.py): both off by default — the default program
-            # stays byte-identical (jaxpr fingerprints are the gate)
+            # timer wheel (ops/wheel.py): off by default — the default
+            # program stays byte-identical (jaxpr fingerprints are the gate)
             wheel_slots=ex.timer_wheel,
             wheel_block=ex.timer_wheel_block,
-            merge_scatter=ex.merge_scatter,
             # fluid traffic plane (net/fluid.py): zero classes (the
             # default) traces no fluid code — the program stays
             # byte-identical to the fluid-free engine

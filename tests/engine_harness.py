@@ -22,6 +22,7 @@ def build_sim(
     bw_bits: int = 0,
     qcap: int = 32,
     sends_budget: int = 8,
+    max_round_inserts: int = 0,
     seed: int = 1,
     runahead_floor: int = 1_000_000,
     use_codel: bool = True,
@@ -35,14 +36,12 @@ def build_sim(
     flow_records: int = 0,
     integrity: bool = False,
     integrity_dual: bool | None = None,
-    merge_rows: int = 0,
     faults: dict | None = None,
     bootstrap_end: int = 0,
     rounds_per_chunk: int = 64,
     microstep_limit: int = 0,
     wheel_slots: int = 0,
     wheel_block: int = 0,
-    merge_scatter: bool = False,
     fluid: dict | None = None,
 ):
     """(cfg, model, params, model_state, initial_events) — shared between the
@@ -100,7 +99,7 @@ def build_sim(
         queue_capacity=qcap,
         queue_block=queue_block,
         sends_per_host_round=sends_budget,
-        max_round_inserts=qcap,
+        max_round_inserts=max_round_inserts or qcap,
         rounds_per_chunk=rounds_per_chunk,
         microstep_limit=microstep_limit,
         world=world,
@@ -118,10 +117,8 @@ def build_sim(
         integrity_dual=(
             integrity if integrity_dual is None else integrity_dual
         ),
-        merge_rows=merge_rows,
         wheel_slots=wheel_slots,
         wheel_block=wheel_block,
-        merge_scatter=merge_scatter,
         **fault_kw,
         **fluid_kw,
     )

@@ -86,6 +86,34 @@ def test_overflow_counts_dropped():
     assert int(queue_len(q)[0]) == 2
 
 
+def _merge_reference(q, dst, t, order, kind, payload, valid, max_inserts):
+    """The insertion contract in plain numpy: per host, its valid rows by
+    (t, order); the r-th takes the r-th free slot, up to min(free, r_cap);
+    the rest count in `dropped`. Returns the merged queue's fields."""
+    out = {f: np.array(getattr(q, f)) for f in q._fields}
+    dst, t, order, kind, payload, valid = (
+        np.asarray(a) for a in (dst, t, order, kind, payload, valid)
+    )
+    r_cap = min(max_inserts, out["t"].shape[1])
+    for h in range(out["t"].shape[0]):
+        rows = np.flatnonzero(valid & (dst == h))
+        rows = rows[np.lexsort((order[rows], t[rows]))]
+        free = np.flatnonzero(out["t"][h] == TIME_MAX)[:r_cap]
+        for slot, r in zip(free, rows):
+            out["t"][h, slot], out["order"][h, slot] = t[r], order[r]
+            out["kind"][h, slot], out["payload"][h, slot] = kind[r], payload[r]
+        out["dropped"][h] += rows.size - min(rows.size, free.size)
+    return out
+
+
+def _assert_merge_is_reference(q, merged, *rows, max_inserts, what=""):
+    want = _merge_reference(q, *rows, max_inserts)
+    for f in merged._fields:
+        assert np.array_equal(np.asarray(getattr(merged, f)), want[f]), (
+            f"{what} field {f}"
+        )
+
+
 def test_merge_flat_events_sorted_and_counted():
     q = make_queue(H, C)
     q = _push(q, 1, 5, 1)  # pre-existing event occupies slot 0 of host 1
@@ -112,7 +140,7 @@ def test_merge_flat_events_sorted_and_counted():
     assert times == [5, 10, 20, 30]
 
 
-def test_merge_overflow_sheds_latest_not_earliest():
+def test_merge_sheds_latest_not_earliest():
     """Under overflow pressure the merge must keep the most urgent events:
     drop priority is (time, order), not the raw order key."""
     q = make_queue(1, 1)
@@ -146,10 +174,9 @@ def test_merge_overflow_drops_counted():
 
 
 def test_merge_gather_and_scatter_paths_agree():
-    """The TPU (destination sort + gather) and CPU (scatter) insertion paths must
-    produce bit-identical queues for any input, including overflow — the
-    bench's vs_baseline comparison and cross-platform digest stability both
-    rest on this."""
+    """The merge (destination sort + gather, the one formulation on every
+    backend) leaves exactly the numpy reference's queue for any input,
+    overflow included: slot for slot, and every shed counted."""
     rng = np.random.default_rng(7)
     for trial in range(8):
         hh, cc = int(rng.integers(1, 12)), int(rng.integers(1, 6))
@@ -175,118 +202,11 @@ def test_merge_gather_and_scatter_paths_agree():
             rng.integers(0, 100, (n, EVENT_PAYLOAD_WORDS)), jnp.int32
         )
         valid = jnp.asarray(rng.random(n) < 0.8)
-        for shed in (True, False):
-            a = merge_flat_events(
-                q, dst, t, order, kind, payload, valid, max_inserts=cc,
-                shed_urgency=shed, force_path="gather",
-            )
-            b = merge_flat_events(
-                q, dst, t, order, kind, payload, valid, max_inserts=cc,
-                shed_urgency=shed, force_path="scatter",
-            )
-            for fa, fb, name in zip(a, b, a._fields):
-                assert np.array_equal(np.asarray(fa), np.asarray(fb)), (
-                    f"trial {trial} shed={shed} field {name}"
-                )
-
-
-def test_merge_rows_truncation_exact_when_sized():
-    """merge_rows >= valid + H + 1 must change nothing (gather path)."""
-    rng = np.random.default_rng(11)
-    hh, cc, n = 6, 4, 30
-    q = make_queue(hh, cc)
-    dst = jnp.asarray(rng.integers(0, hh, n), jnp.int32)
-    t = jnp.asarray(rng.integers(1, 1000, n), jnp.int64)
-    order = jnp.asarray(
-        [int(pack_order(0, int(rng.integers(0, hh)), i)) for i in range(n)],
-        jnp.int64,
-    )
-    kind = jnp.asarray(rng.integers(0, 5, n), jnp.int32)
-    payload = jnp.asarray(
-        rng.integers(0, 100, (n, EVENT_PAYLOAD_WORDS)), jnp.int32
-    )
-    valid = jnp.asarray(rng.random(n) < 0.7)
-    a = merge_flat_events(
-        q, dst, t, order, kind, payload, valid, max_inserts=cc,
-        force_path="gather",
-    )
-    b = merge_flat_events(
-        q, dst, t, order, kind, payload, valid, max_inserts=cc,
-        force_path="gather", merge_rows=n + hh + 1,
-    )
-    for fa, fb, name in zip(a, b, a._fields):
-        assert np.array_equal(np.asarray(fa), np.asarray(fb)), name
-
-
-def test_merge_rows_truncation_sheds_counted():
-    """An undersized merge_rows sheds by sorted position — and every shed
-    event lands in `dropped`, never silently."""
-    hh, cc = 3, 4
-    q = make_queue(hh, cc)
-    n = 9
-    dst = jnp.asarray([0, 0, 0, 1, 1, 1, 2, 2, 2], jnp.int32)
-    t = jnp.arange(1, n + 1, dtype=jnp.int64)
-    order = jnp.asarray([int(pack_order(0, 0, i)) for i in range(n)], jnp.int64)
-    kind = jnp.zeros((n,), jnp.int32)
-    payload = jnp.zeros((n, EVENT_PAYLOAD_WORDS), jnp.int32)
-    valid = jnp.ones((n,), bool)
-    # full run inserts all 9
-    full = merge_flat_events(
-        q, dst, t, order, kind, payload, valid, max_inserts=cc,
-        force_path="gather",
-    )
-    assert int(np.asarray(queue_len(full)).sum()) == 9
-    assert int(np.asarray(full.dropped).sum()) == 0
-    # sorted layout: [tok0, e0, e1, e2, tok1, e3, e4, e5, tok2, e6, e7, e8,
-    # tok3]; merge_rows=7 keeps positions < 7 -> host 0 whole, host 1 only
-    # its first entry (position 5, 6 -> e3 at 5... entries at 5,6 = e3, e4)
-    cut = merge_flat_events(
-        q, dst, t, order, kind, payload, valid, max_inserts=cc,
-        force_path="gather", merge_rows=7,
-    )
-    kept = int(np.asarray(queue_len(cut)).sum())
-    shed = int(np.asarray(cut.dropped).sum())
-    assert kept + shed == 9
-    assert kept == 5  # host0: 3, host1: 2 (positions 5, 6), host2: 0
-    # host 0 intact, host 2 fully shed
-    assert int(np.asarray(queue_len(cut))[0]) == 3
-    assert int(np.asarray(queue_len(cut))[2]) == 0
-
-
-def test_merge_rows_truncation_paths_agree():
-    """merge_rows sheds must be bit-identical between the gather path and
-    the scatter path (the scatter side mirrors the token-interleaved
-    positional cut) — cross-backend digest stability in the shed regime."""
-    rng = np.random.default_rng(23)
-    for trial in range(6):
-        hh, cc = int(rng.integers(2, 10)), int(rng.integers(2, 6))
-        n = int(rng.integers(4, 40))
-        mr = int(rng.integers(2, n + hh + 2))
-        q = make_queue(hh, cc)
-        dst = jnp.asarray(rng.integers(0, hh, n), jnp.int32)
-        t = jnp.asarray(rng.integers(1, 1000, n), jnp.int64)
-        order = jnp.asarray(
-            [int(pack_order(0, int(rng.integers(0, hh)), 50 + i)) for i in range(n)],
-            jnp.int64,
+        rows = (dst, t, order, kind, payload, valid)
+        merged = merge_flat_events(q, *rows, max_inserts=cc)
+        _assert_merge_is_reference(
+            q, merged, *rows, max_inserts=cc, what=f"trial {trial}"
         )
-        kind = jnp.asarray(rng.integers(0, 5, n), jnp.int32)
-        payload = jnp.asarray(
-            rng.integers(0, 100, (n, EVENT_PAYLOAD_WORDS)), jnp.int32
-        )
-        valid = jnp.asarray(rng.random(n) < 0.8)
-        for shed in (True, False):
-            a = merge_flat_events(
-                q, dst, t, order, kind, payload, valid, max_inserts=cc,
-                shed_urgency=shed, force_path="gather", merge_rows=mr,
-            )
-            b = merge_flat_events(
-                q, dst, t, order, kind, payload, valid, max_inserts=cc,
-                shed_urgency=shed, force_path="scatter", merge_rows=mr,
-            )
-            for fa, fb, name in zip(a, b, a._fields):
-                assert np.array_equal(np.asarray(fa), np.asarray(fb)), (
-                    f"trial {trial} shed={shed} mr={mr} field {name}"
-                )
 
 
 def test_dshard_segments_block_takes_most_urgent_rows():
@@ -348,11 +268,12 @@ def test_segment_starts_equal_searchsorted(case):
     assert np.array_equal(got, want), case
 
 
-@pytest.mark.parametrize("shed", [True, False])
-@pytest.mark.parametrize("merge_rows", [0, 40])
-def test_merge_gather_sheds_long_segment_like_scatter(shed, merge_rows):
+@pytest.mark.parametrize("hot", [0, 2, 4], ids=["first", "middle", "last"])
+def test_merge_gather_sheds_long_segment_like_scatter(hot):
     """A host whose segment spans several r_cap chunks (the urgency loop
-    runs more than once) sheds exactly as the scatter path does."""
+    runs more than once) sheds exactly as the numpy reference does. The
+    hot host is the first, a middle and the last one: the edges of
+    `_segment_starts`."""
     rng = np.random.default_rng(41)
     hh, cc, n = 5, 4, 60
     q = make_queue(hh, cc)
@@ -361,9 +282,9 @@ def test_merge_gather_sheds_long_segment_like_scatter(shed, merge_rows):
         t=jnp.asarray(np.where(occ, rng.integers(1, 900, (hh, cc)), np.asarray(q.t))),
         order=jnp.asarray(np.where(occ, rng.integers(0, 1 << 40, (hh, cc)), np.asarray(q.order))),
     )
-    # host 2 receives every second row, over 7 x r_cap; the rest spread
-    dst = np.where(np.arange(n) % 2 == 0, 2, rng.integers(0, hh, n))
-    assert (dst == 2).sum() > 2 * cc
+    # the hot host receives every second row, over 7 x r_cap; the rest spread
+    dst = np.where(np.arange(n) % 2 == 0, hot, rng.integers(0, hh, n))
+    assert (dst == hot).sum() > 2 * cc
     # three distinct times: the shed cut falls among equal times, where
     # the order key decides
     t = jnp.asarray(rng.integers(1, 4, n), jnp.int64)
@@ -374,14 +295,73 @@ def test_merge_gather_sheds_long_segment_like_scatter(shed, merge_rows):
     kind = jnp.asarray(rng.integers(0, 5, n), jnp.int32)
     payload = jnp.asarray(rng.integers(0, 100, (n, EVENT_PAYLOAD_WORDS)), jnp.int32)
     valid = jnp.asarray(rng.random(n) < 0.9)
-    a, b = (
-        merge_flat_events(
-            q, jnp.asarray(dst, jnp.int32), t, order, kind, payload, valid,
-            max_inserts=cc, shed_urgency=shed, force_path=path,
-            merge_rows=merge_rows,
+    rows = (jnp.asarray(dst, jnp.int32), t, order, kind, payload, valid)
+    merged = merge_flat_events(q, *rows, max_inserts=cc)
+    assert int(np.asarray(merged.dropped)[hot]) > 0
+    _assert_merge_is_reference(q, merged, *rows, max_inserts=cc)
+
+
+def _drain_queue(q):
+    out = []
+    for _ in range(q.t.shape[0] * q.t.shape[1] + 1):
+        q, ev, active = pop_min(q, jnp.int64(TIME_MAX))
+        if not bool(np.asarray(active).any()):
+            break
+        out.append((
+            np.asarray(ev.t).tolist(),
+            np.asarray(ev.order).tolist(),
+            np.asarray(ev.kind).tolist(),
+        ))
+    return out
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2, 3])
+@pytest.mark.parametrize("load", ["light", "overflow"])
+def test_merge_flat_events_matches_reference(seed, load):
+    """Random row sets into a random pre-filled queue: the merged queue's
+    OBSERVABLE behavior (drain order via pop_min, drop counts) is the
+    numpy reference's. `overflow` sends one destination past its free
+    slots, so equality covers the shed order too."""
+    rng = np.random.default_rng(seed)
+    h, cap = 6, 8
+    q = make_queue(h, cap)
+    # pre-fill some slots so free ranks are nontrivial
+    seq = 0
+    for _ in range(int(rng.integers(0, 3))):
+        t0 = rng.integers(1, 500, size=h).astype(np.int64)
+        order = np.asarray(pack_order(1, np.arange(h), np.full(h, seq)))
+        seq += 1
+        mask = rng.random(h) < 0.7
+        q = push_one(
+            q, jnp.asarray(mask), jnp.asarray(t0), jnp.asarray(order),
+            jnp.full((h,), 2, jnp.int32),
+            jnp.zeros((h, EVENT_PAYLOAD_WORDS), jnp.int32),
         )
-        for path in ("gather", "scatter")
+    n = 24 if load == "overflow" else 10
+    hot = int(rng.integers(0, h))
+    dst = rng.integers(0, h, size=n).astype(np.int32)
+    if load == "overflow":
+        dst[: n // 2] = hot  # slam one destination past its free slots
+    t = rng.integers(600, 1000, size=n).astype(np.int64)
+    order = np.array(
+        [int(pack_order(0, int(rng.integers(0, h)), 1000 + j))
+         for j in range(n)], np.int64,
     )
-    assert int(np.asarray(a.dropped).sum()) > 0
-    for fa, fb, name in zip(a, b, a._fields):
-        assert np.array_equal(np.asarray(fa), np.asarray(fb)), name
+    kind = rng.integers(0, 5, size=n).astype(np.int32)
+    payload = rng.integers(0, 100, size=(n, EVENT_PAYLOAD_WORDS)).astype(np.int32)
+    valid = rng.random(n) < 0.9
+    rows = (
+        jnp.asarray(dst), jnp.asarray(t), jnp.asarray(order),
+        jnp.asarray(kind), jnp.asarray(payload), jnp.asarray(valid),
+    )
+    merged = merge_flat_events(q, *rows, max_inserts=cap)
+    want = q._replace(**{
+        f: jnp.asarray(v)
+        for f, v in _merge_reference(q, *rows, cap).items()
+    })
+    np.testing.assert_array_equal(
+        np.asarray(merged.dropped), np.asarray(want.dropped)
+    )
+    assert _drain_queue(merged) == _drain_queue(want)
+    if load == "overflow":
+        assert int(np.asarray(merged.dropped).sum()) > 0

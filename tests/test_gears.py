@@ -135,29 +135,6 @@ def test_gear_ladder_mesh_invariant(exchange):
     assert ctl.replays > 0
 
 
-@pytest.mark.parametrize("qb", [0, 8], ids=["flat", "bucketed"])
-def test_merge_rows_and_gears_compose(qb):
-    """merge_rows (post-sort POSITIONAL shedding into queue.dropped) and
-    gears (pre-sort width truncation with abort-replay) must compose: the
-    sorted sequence of valid entries + tokens is identical at any
-    non-shedding gear (the slice drops only trailing invalid rows), so a
-    merge_rows bound sheds the SAME rows at every gear — digests, events,
-    and the merge_rows drop counts all bit-identical to the full-width
-    run under the same bound, with the bound genuinely firing."""
-    model, hosts, stop, kw = _CASES["phold"]
-    # tight enough that overflow rounds shed by sorted position: 8 hosts
-    # x up to 8 sends + 9 tokens can exceed 24 sorted positions
-    mr = 24
-    full = _run_full(model, hosts, stop, queue_block=qb, merge_rows=mr, **kw)
-    geared, ctl = _run_geared(model, hosts, stop, queue_block=qb,
-                              merge_rows=mr, **kw)
-    _assert_identical(full, geared)
-    assert ctl.replays > 0  # the gear-1 start still forced replays
-    # the merge_rows bound itself fired (otherwise this tests nothing) —
-    # identical drops on both sides already asserted above
-    assert int(np.asarray(jax.device_get(full.queue.dropped)).sum()) > 0
-
-
 def test_snapshot_survives_donation_and_repeated_restores():
     """The replay loop's memory contract: the snapshot is an independent
     device copy (the jitted chunk donates its input), and each restore

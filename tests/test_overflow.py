@@ -120,16 +120,13 @@ def test_capture_ring_overflow_is_counted():
 
 
 def test_event_queue_shed_policies_run_clean():
-    """Tiny per-host event queues under flood: overflow is counted in
-    queue_overflow_dropped for BOTH shed policies and the run terminates
-    without monotonic violations."""
-    for policy in ("urgency", "append"):
-        sim = HybridSimulation(
-            _flood_cfg(16, {"event_queue_capacity": 256,
-                            "overflow_shed": policy}),
-            world=1,
-        )
-        r = sim.run()
-        assert r["packets_sent"] > 0
-        # no wedge: the run reached stop_time and reported
-        assert r["simulated_seconds"] == 4.0
+    """Tiny per-host event queues under flood: overflow sheds by urgency
+    (the one shed policy) and the run terminates without monotonic
+    violations."""
+    sim = HybridSimulation(
+        _flood_cfg(16, {"event_queue_capacity": 256}), world=1
+    )
+    r = sim.run()
+    assert r["packets_sent"] > 0
+    # no wedge: the run reached stop_time and reported
+    assert r["simulated_seconds"] == 4.0

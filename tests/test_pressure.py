@@ -668,11 +668,6 @@ def test_simulation_build_wiring_and_rejections():
     d["experimental"] = {"scheduler": "cpu-reference"}
     with pytest.raises(ConfigError, match="cpu-reference"):
         Simulation(ConfigOptions.from_dict(d), world=1)
-    # merge_rows' positional shed is not capacity-curable
-    d = cfg_dict(policy="escalate")
-    d["experimental"] = {"merge_rows": 64}
-    with pytest.raises(ConfigError, match="merge_rows"):
-        Simulation(ConfigOptions.from_dict(d), world=1)
     # explicit a2a_block sheds are not capacity-curable either
     d = cfg_dict(policy="escalate")
     d["experimental"] = {"a2a_block": 64}

@@ -60,7 +60,8 @@ def _op_names(hlo_text: str) -> list[str]:
 
 def _tpu_chunk_hlo(monkeypatch, sim) -> str:
     """The one-chip chunk program compiled on the CPU in its TPU form (the
-    engine picks the gather merge from `jax.default_backend()`)."""
+    engine picks its pops from `jax.default_backend()`; the merge is the
+    same on every backend)."""
     fn = functools.partial(E._run_chunk, sim.engine_cfg, sim.engine.model, None)
     with monkeypatch.context() as m:
         m.setattr(jax, "default_backend", lambda: "tpu")

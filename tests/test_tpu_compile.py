@@ -7,9 +7,10 @@ compiler crash, a program that takes minutes to compile — before any chip
 time is spent. Nothing runs: results and times need the chip
 (chip_smoke.py).
 
-The engine picks its TPU branches (one-hot pops, gather merge, K=4 for
-the flagship) from `jax.default_backend()`, which is "cpu" here; each case
-steers it to "tpu" while the program is traced. The topology is described
+The engine picks its pops (one-hot on the TPU, gather on the CPU) from
+`jax.default_backend()`, which is "cpu" here; each case steers it to "tpu"
+while the program is traced. The exchange merge is the same program on
+every backend. The topology is described
 inside a fixture so that only the xdist worker that runs this file loads
 the TPU library.
 """

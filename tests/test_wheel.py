@@ -1,15 +1,15 @@
-"""Timer wheel + sort-free calendar merge (ISSUE 12).
+"""Timer wheel, and the exchange merge's two cond forms.
 
 Three layers of gates, mirroring the bucketed-queue/popk precedent:
 
   1. per-op property sweeps (hypothesis-style seeded randomized
      sequences, no hypothesis dep): wheel push/cancel/pop-due against a
-     sorted-list reference model, and the scatter merge against the sort
-     merge on random row sets including forced overflow;
+     sorted-list reference model;
   2. engine digest matrix: wheel ON is event-for-event identical
      (digests, events, every drop counter) to wheel OFF across
      echo/phold/tgen x flat/bucketed queue layouts, including a
-     spill-forcing tiny wheel and the merge_scatter knob;
+     spill-forcing tiny wheel; and the merge fused inside the empty-round
+     cond (capacity < 48) identical to the split plan/apply form;
   3. checkpoint round-trip + cross-slot migration restore (subprocess-
      isolated: compiled Simulation sequences are this box's documented
      corruption magnet — tests/subproc.py classifies and retries).
@@ -27,8 +27,6 @@ from shadow_tpu.ops.events import (
     pack_order,
     q_len,
 )
-from shadow_tpu.ops.merge import merge_flat_events, merge_scatter_free
-from shadow_tpu.ops.events import make_queue, pop_min, push_one
 from shadow_tpu.ops.wheel import (
     make_wheel,
     migrate_wheel,
@@ -187,76 +185,7 @@ def test_wheel_migrate_roundtrip():
 
 
 # --------------------------------------------------------------------------
-# 1b. scatter merge vs sort merge property sweep
-# --------------------------------------------------------------------------
-
-
-def _drain_queue(q):
-    out = []
-    for _ in range(q.t.shape[0] * q.t.shape[1] + 1):
-        q, ev, active = pop_min(q, jnp.int64(TIME_MAX))
-        if not bool(np.asarray(active).any()):
-            break
-        out.append((
-            np.asarray(ev.t).tolist(),
-            np.asarray(ev.order).tolist(),
-            np.asarray(ev.kind).tolist(),
-        ))
-    return out
-
-
-@pytest.mark.parametrize("seed", [0, 1, 2, 3])
-@pytest.mark.parametrize("load", ["light", "overflow"])
-def test_merge_scatter_free_matches_sort(seed, load):
-    """Random row sets into a random pre-filled queue: the sort-free
-    scatter merge must leave a queue whose OBSERVABLE behavior (drain
-    order via pop_min, drop counts) is identical to the sort merge's.
-    `overflow` forces per-destination counts past the free slots so the
-    fallback path (which IS the sort path) must engage — equality then
-    covers shed behavior too."""
-    rng = np.random.default_rng(seed)
-    h, cap = 6, 8
-    q = make_queue(h, cap)
-    # pre-fill some slots so free ranks are nontrivial
-    seq = 0
-    for _ in range(int(rng.integers(0, 3))):
-        t0 = rng.integers(1, 500, size=h).astype(np.int64)
-        order = np.asarray(pack_order(1, np.arange(h), np.full(h, seq)))
-        seq += 1
-        mask = rng.random(h) < 0.7
-        q = push_one(
-            q, jnp.asarray(mask), jnp.asarray(t0), jnp.asarray(order),
-            jnp.full((h,), 2, jnp.int32), jnp.zeros((h, P), jnp.int32),
-        )
-    n = 24 if load == "overflow" else 10
-    hot = int(rng.integers(0, h))
-    dst = rng.integers(0, h, size=n).astype(np.int32)
-    if load == "overflow":
-        dst[: n // 2] = hot  # slam one destination past its free slots
-    t = rng.integers(600, 1000, size=n).astype(np.int64)
-    order = np.array(
-        [int(pack_order(0, int(rng.integers(0, h)), 1000 + j))
-         for j in range(n)], np.int64,
-    )
-    kind = rng.integers(0, 5, size=n).astype(np.int32)
-    payload = rng.integers(0, 100, size=(n, P)).astype(np.int32)
-    valid = rng.random(n) < 0.9
-    args = (
-        jnp.asarray(dst), jnp.asarray(t), jnp.asarray(order),
-        jnp.asarray(kind), jnp.asarray(payload), jnp.asarray(valid),
-    )
-    q_sort = merge_flat_events(q, *args, max_inserts=cap)
-    q_scat = merge_scatter_free(q, *args, max_inserts=cap)
-    np.testing.assert_array_equal(
-        np.asarray(q_sort.dropped), np.asarray(q_scat.dropped)
-    )
-    assert _drain_queue(q_sort) == _drain_queue(q_scat)
-    if load == "overflow":
-        assert int(np.asarray(q_sort.dropped).sum()) > 0  # fallback engaged
-
-
-# --------------------------------------------------------------------------
-# 2. engine digest matrix: wheel/merge_scatter ON == OFF
+# 2. engine digest matrix: wheel ON == OFF, fused merge == split merge
 # --------------------------------------------------------------------------
 
 _CASES = {
@@ -353,36 +282,58 @@ def test_wheel_spill_path_bit_identical(case):
     assert int(np.asarray(jax.device_get(st1.wheel.dropped)).sum()) == 0
 
 
+# echo's 2 Mbit/s links back the server's queue past 64 slots, so its
+# drops (and then its digests) depend on the capacity; at 20 Mbit/s the
+# server holds its load in 32 slots and the capacity changes nothing else
+_NO_QUEUE_DROPS = {"echo": dict(bw_bits=20_000_000)}
+
+
+def _cond_forms(case, **kw):
+    """Run `case` at capacity 32 (the merge fused inside the empty-round
+    cond) and at 64 (plan inside, apply outside), with the insert cap and
+    the microstep limit pinned so that capacity is the only difference."""
+    model, hosts, stop, base = _CASES[case]
+    kw = dict(
+        base, max_round_inserts=32, microstep_limit=64,
+        **_NO_QUEUE_DROPS.get(case, {}), **kw,
+    )
+    fused = run_sim(model, hosts, stop, qcap=32, **kw)
+    split = run_sim(model, hosts, stop, qcap=64, **kw)
+    for st, _, _ in (fused, split):
+        assert int(np.asarray(jax.device_get(st.queue.dropped)).sum()) == 0
+    _assert_identical(*fused[:2], *split[:2])
+
+
 @pytest.mark.parametrize("case", sorted(_CASES))
-def test_merge_scatter_bit_identical(case):
-    model, hosts, stop, kw = _CASES[case]
-    st0, s0, _ = run_sim(model, hosts, stop, **kw)
-    st1, s1, _ = run_sim(model, hosts, stop, merge_scatter=True, **kw)
-    _assert_identical(st0, s0, st1, s1)
+def test_merge_cond_forms_bit_identical(case):
+    """The fused and the split merge leave the same digests, events and
+    drops (every backend picks the form from the capacity alone)."""
+    _cond_forms(case)
 
 
-@pytest.mark.slow
-def test_merge_scatter_overflow_fallback_bit_identical():
-    """A queue sized to actually overflow under tgen exercises the
-    in-jit sort fallback: drops (and everything else) must match the
-    sort path exactly."""
-    model, hosts, stop, kw = _CASES["tgen"]
-    kw = dict(kw, qcap=4, microstep_limit=16)
-    st0, s0, _ = run_sim(model, hosts, stop, **kw)
-    st1, s1, _ = run_sim(model, hosts, stop, merge_scatter=True, **kw)
-    _assert_identical(st0, s0, st1, s1)
+@pytest.mark.parametrize("case", sorted(_CASES))
+def test_merge_cond_forms_bit_identical_bucketed(case):
+    """The same with the bucketed queue (blocks of 8), whose block caches
+    are rebuilt under the cond after either form."""
+    _cond_forms(case, queue_block=8)
 
 
-def test_wheel_plus_scatter_plus_netobs_reconciles():
-    """The flagship combination (bench config 11): wheel + scatter merge
-    + network observatory. Digests identical to the plain run AND the
+def test_merge_cond_forms_bit_identical_alltoall():
+    """PHOLD at world 4 over the alltoall exchange: each shard merges its
+    received blocks in either form."""
+    _cond_forms("phold", world=4, exchange="alltoall")
+
+
+def test_wheel_plus_netobs_reconciles():
+    """The flagship combination (bench config 11): wheel + network
+    observatory. Digests identical to the plain run AND the
     event-class accounting still reconciles (ec_timer + ec_pkt + ec_app
     == events) — the ec_timer count is exactly the wheel's traffic."""
     model, hosts, stop, kw = _CASES["tgen"]
     st0, s0, _ = run_sim(model, hosts, stop, **kw)
     st1, s1, _ = run_sim(
-        model, hosts, stop, wheel_slots=8, merge_scatter=True, netobs=True,
-        flow_records=32, **kw
+        model, hosts, stop, wheel_slots=8, netobs=True, flow_records=32,
+        **kw
     )
     _assert_identical(st0, s0, st1, s1)
     ec = (
@@ -434,11 +385,9 @@ def test_config_knobs_parse_and_validate():
     from shadow_tpu.config.options import ConfigError, ExperimentalOptions
 
     e = ExperimentalOptions.from_dict(
-        {"timer_wheel": 16, "timer_wheel_block": 4, "merge_scatter": True}
+        {"timer_wheel": 16, "timer_wheel_block": 4}
     )
-    assert (e.timer_wheel, e.timer_wheel_block, e.merge_scatter) == (
-        16, 4, True
-    )
+    assert (e.timer_wheel, e.timer_wheel_block) == (16, 4)
     with pytest.raises(ConfigError, match="timer_wheel_block"):
         ExperimentalOptions.from_dict(
             {"timer_wheel": 16, "timer_wheel_block": 5}

@@ -8,12 +8,12 @@ a synthetic [H, B] outbox filled to a per-host occupancy level:
 
   - sort:   the destination sort + urgency selection half (`merge_plan`)
   - gather: the apply half (`merge_apply` slab write)
-  - total:  the fused `merge_flat_events` path (what the engine runs on
-            this backend)
+  - total:  the fused `merge_flat_events` path (what the engine runs at
+            queue capacities below 48, on every backend)
 
 at each gear width (the flattened input is H x gear columns — exactly the
 slice `core/engine._gear_sliced_outbox` feeds the merge). CPU-runnable by
-design; on TPU the same sweep maps the gather-path economics.
+design; on TPU the same sweep maps the chip's economics.
 
 Usage: python tools/bench_merge_gears.py [--hosts 4096] [--budget 8]
            [--cap 32] [--iters 30] [--occupancy 1,2,4,8] [--json]

@@ -104,17 +104,15 @@ AUDIT_CONFIGS = {
                          "rate": "80 Mbit", "start": 0}],
         }),
     ),
-    # timer wheel + sort-free calendar merge ON (ISSUE 12): the wheel
-    # carry lanes, merged queue∪wheel pops, spill routing, and the
-    # scatter-merge fast/fallback cond traced in — pins the GATED
-    # program's compile surface (and audits the wheel.* lane dtypes)
-    # while `echo`/`phold` above pin that the default (wheel-off)
-    # programs stay byte-unchanged.
+    # timer wheel ON: the wheel carry lanes, merged queue∪wheel pops and
+    # spill routing traced in — pins the GATED program's compile surface
+    # (and audits the wheel.* lane dtypes) while `echo`/`phold` above pin
+    # that the default (wheel-off) programs stay byte-unchanged.
     "phold_wheel": dict(
         model="phold",
         hosts=None,  # mk_hosts(4) below
         stop=200_000_000,
-        kw=dict(qcap=16, wheel_slots=8, merge_scatter=True),
+        kw=dict(qcap=16, wheel_slots=8),
     ),
 }
 

@@ -2,9 +2,9 @@
 
 Times the full chunk, then variants with the exchange merge stubbed out and
 with shaping off, to attribute round cost. The round-1 claim 'sort = 85%'
-came from operand-slimming experiments, not a measured knockout — the
-microbenchmarks (tools/bench_merge_ops.py) time the 60k 3-key sort at ~40 us,
-which cannot be 85% of a 46 ms round.
+came from operand-slimming experiments, not a measured knockout — a
+round-1 microbenchmark timed the 60k 3-key sort at ~40 us, which cannot be
+85% of a 46 ms round.
 """
 
 import sys, pathlib
@@ -49,15 +49,18 @@ def main():
     which = sys.argv[1] if len(sys.argv) > 1 else "base"
 
     if which == "nomerge":
-        import shadow_tpu.ops.merge as m
         import shadow_tpu.core.engine as e
+        from shadow_tpu.ops.merge import merge_empty_plan
 
-        def fake_merge(q, dst, t, order, kind, payload, valid, max_inserts,
-                       shed_urgency=True):
+        def fake_merge(q, dst, t, order, kind, payload, valid, max_inserts):
             return q
 
-        m.merge_flat_events = fake_merge
+        def fake_plan(q_t, dst, t, order, kind, payload, valid, max_inserts):
+            return merge_empty_plan(q_t, payload.shape[-1])
+
+        # the engine fuses the merge below capacity 48 and splits it above
         e.merge_flat_events = fake_merge
+        e.merge_plan = fake_plan
         sim = build()
     elif which == "noshaping":
         # strip host bandwidths from the GML -> Simulation auto-elides the
@@ -73,8 +76,6 @@ def main():
         sim = build(lambda d: d["experimental"].update({"use_codel": False}))
     elif which == "micro1":
         sim = build(lambda d: d["experimental"].update({"microstep_limit": 1}))
-    elif which == "urgency":
-        sim = build(lambda d: d["experimental"].update({"overflow_shed": "urgency"}))
     elif which == "cap8":
         sim = build(lambda d: d["experimental"].update({"event_queue_capacity": 8}))
     elif which == "chunk1":
