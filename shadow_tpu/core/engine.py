@@ -1217,6 +1217,7 @@ class Engine:
         # (core/pressure.py) and the alltoall block rungs. Bounded by the
         # ladders (a handful of rungs per axis).
         self._resized_chunks: dict[tuple, Any] = {}
+        self._summary_fn = None  # jitted `chunk_summary` (any shape)
         # runtime observatory (obs/runtime.CompileLedger): when attached,
         # every cached chunk program is wrapped so its first (compiling)
         # call is recorded with its trigger. HOST-SIDE only — wrapping a
@@ -1269,6 +1270,24 @@ class Engine:
         accept its result."""
         self.a2a_rung = int(a2a_rung)
         self.run_chunk = self._chunk_program(0, 0, 0, self.a2a_rung)
+
+    def chunk_summary(self, state):
+        """Dispatch the summary of a chunk's output (core/pipeline.py): a
+        row of int64 boundary numbers per shard, reduced on the shard, so
+        the host reads one small array a chunk and the device runs no
+        collective for it. Does not donate `state`: the drivers dispatch
+        it before the next chunk consumes that state. Returns a
+        `PendingSummary`; its `read()` blocks."""
+        from shadow_tpu.core.pipeline import (
+            PendingSummary, summary_layout, summary_row,
+        )
+
+        if self._summary_fn is None:
+            row = summary_row
+            if self.mesh is not None:
+                row = _shard_map(row, self.mesh, (self.state_specs(),), P(AXIS))
+            self._summary_fn = jax.jit(row)
+        return PendingSummary(summary_layout(state), self._summary_fn(state))
 
     def run_chunk_gear(self, state: SimState, params: EngineParams, gear_cols: int):
         """Run one chunk at a merge gear (`gear_cols` outbox columns in the

@@ -70,13 +70,12 @@ from typing import Any
 
 import numpy as np
 
-from shadow_tpu.core.integrity import (
-    IntegrityAbort,
-    describe_signature,
-    violation_signature,
-    violation_total,
+from shadow_tpu.core.integrity import IntegrityAbort, describe_signature
+from shadow_tpu.core.pipeline import (
+    ChunkPipeline,
+    Flight,
+    host_summary,
 )
-from shadow_tpu.obs.runtime import span_or_null
 
 DEFAULT_MAX_CAPACITY_FACTOR = 8  # auto max_capacity = 8x the base slab
 DEFAULT_MAX_OUTBOX_FACTOR = 4  # auto max_outbox = 4x the base budget
@@ -230,57 +229,32 @@ class ResilienceController:
     # ---- host-side counter reads ------------------------------------------
 
     @staticmethod
-    def _pressure_total(state) -> int:
-        """Cumulative global capacity-drop total, read host-side. Uses
-        the psum'd device signal when present (policies escalate/abort)
-        and falls back to summing the category counters."""
-        import jax
-
-        s = state.stats
-        if getattr(s, "pressure", None) is not None:
-            return int(np.asarray(jax.device_get(s.pressure)).max())
-        return sum(ResilienceController._pressure_categories(state).values())
-
-    @staticmethod
-    def _pressure_categories(state) -> dict[str, int]:
-        """Per-category cumulative drop totals — the growth decision's
-        input (queue-side pressure grows the slab, outbox-side pressure
-        grows the send budget)."""
-        import jax
-
-        s = state.stats
-        return {
-            "queue": int(
-                np.asarray(jax.device_get(state.queue.dropped)).sum()
-            ),
-            "budget": int(
-                np.asarray(jax.device_get(s.pkts_budget_dropped)).sum()
-            ),
-            "a2a": int(np.asarray(jax.device_get(s.a2a_shed)).sum()),
-            "outbox": int(np.asarray(jax.device_get(s.ob_dropped)).sum()),
-        }
-
-    @classmethod
-    def raise_if_dropped(cls, state, baseline: dict | None = None):
-        """Raise PressureAbort naming the per-category drop deltas when
-        `state` carries capacity drops past `baseline` (None = zero) —
+    def _drop_abort(s, baseline: dict | None):
+        """PressureAbort naming the per-category drop deltas of summary
+        `s` past `baseline` (None = zero), or None when nothing dropped —
         the one formatter the abort policy's two drivers share (the
         modeled controller's in-loop check and the hybrid driver's
         post-window check must report identically)."""
-        if cls._pressure_total(state) <= (
-            sum(baseline.values()) if baseline else 0
-        ):
-            return
-        cats = cls._pressure_categories(state)
+        if s.pressure_total <= (sum(baseline.values()) if baseline else 0):
+            return None
+        cats = s.cats
         base = baseline or {k: 0 for k in cats}
         detail = ", ".join(
             f"{k}+{v - base[k]}"
             for k, v in sorted(cats.items())
             if v > base[k]
         )
-        raise PressureAbort(
+        return PressureAbort(
             f"pressure: abort policy hit its first capacity drop ({detail})"
         )
+
+    @classmethod
+    def raise_if_dropped(cls, state, baseline: dict | None = None):
+        """Raise PressureAbort when `state` carries capacity drops past
+        `baseline` (None = zero)."""
+        err = cls._drop_abort(host_summary(state), baseline)
+        if err is not None:
+            raise err
 
     # ---- ladders -----------------------------------------------------------
 
@@ -319,7 +293,7 @@ class ResilienceController:
         self._wall_move("snapshot", time.perf_counter() - t0)
         return snap
 
-    def _restore_timed(self, snap):
+    def restore(self, snap):
         from shadow_tpu.core.checkpoint import restore_snapshot
 
         t0 = time.perf_counter()
@@ -372,30 +346,38 @@ class ResilienceController:
 
     def run_chunk(self, state, dispatch, rounds0=None):
         """One ACCEPTED chunk, with shed-exact gear replay and drop-exact
-        capacity escalation from a single pre-chunk snapshot.
+        capacity escalation from a single pre-chunk snapshot: the lag-0
+        form of `core.pipeline.ChunkPipeline` on this seam, reading the
+        boundary numbers on the host.
 
         `dispatch(state, gear, capacity, budget)` runs one chunk program
         at that shape (donation-safe). `rounds0` keeps the hybrid
         drivers' zero-round guarded windows out of the gear controller,
         exactly as `run_adaptive_chunk` documents.
 
+        Returns (state, accepted_gear, chunk_outbox_hwm)."""
+        pipe = ChunkPipeline(dispatch, seam=self, lag=0)
+        pipe.start(state)
+        pipe.advance(rounds0)
+        return pipe.state, pipe.gear, pipe.hwm
+
+    def begin(self, state, dispatch, base) -> Flight:
+        """Snapshot `state` where a replay may need it and dispatch one
+        chunk on it at the controllers' current gear and rung. `base` is
+        the summary of `state`, pending or read.
+
         Shapes are derived from the STATE, not from controller memory: a
         supervisor rewind can hand back a pre-migration state, and the
         state's own shapes are the only truth about which program runs."""
-        import jax
-
-        gearctl = self.gearctl
+        gearctl, rungctl = self.gearctl, self.rungctl
         gear = gearctl.gear if gearctl is not None else 0
-        rungctl = self.rungctl
         rung = rungctl.rung if rungctl is not None else 0
-        pressured = self.pressure is not None
-        if pressured:
+        cap = budget = 0
+        if self.pressure is not None:
             cap = state.queue.t.shape[1]
             budget = state.outbox.t.shape[1]
             if self.escalate:
                 self._ensure_ladders(cap, budget)
-        else:
-            cap = budget = 0
         need_snap = (
             (gearctl is not None and gear < gearctl.top)
             or (rungctl is not None and rung < rungctl.top)
@@ -403,255 +385,244 @@ class ResilienceController:
             or self.integrity_on
         )
         snap = self._snap_timed(state) if need_snap else None
-        self._last_snap = snap
-        # integrity classifier state, chunk-scoped: the last violating
-        # attempt's (shard, round, mask) signature and how many
-        # sentinel-forced replays this chunk has eaten
-        iv_last_sig = None
-        iv_attempts = 0
-        attempt_i = 0
-        while True:
-            shed0 = int(
-                np.asarray(jax.device_get(state.stats.gear_shed)).max()
-            )
-            press0 = self._pressure_total(state) if pressured else 0
-            cats0 = self._pressure_categories(state) if pressured else None
-            iv0 = violation_total(state) if self.integrity_on else 0
-            if self.test_scribble is not None:
-                state = self.test_scribble(state, attempt_i)
-            attempt_i += 1
-            t_disp = time.perf_counter()
-            comp0 = (
-                self.wall.pending_to("compile")
-                if self.wall is not None else 0.0
-            )
-            try:
-                if rungctl is not None:
-                    out = dispatch(state, gear, cap, budget, rung)
-                else:
-                    out = dispatch(state, gear, cap, budget)
-                # the wait for the chip, traced as such (`shadow.sync`)
-                with span_or_null(None, "sync"):
-                    jax.block_until_ready(out)
-            except (KeyboardInterrupt, SystemExit, PressureAbort,
-                    IntegrityAbort):
-                raise
-            except Exception as e:
-                grown_cap = (
-                    self.escalate and cap > self._cap_ladder[0]
-                )
-                grown_box = (
-                    self.escalate and budget > self._box_ladder[0]
-                )
-                if (grown_cap or grown_box) and _is_oom(e):
-                    # a GROWN program could not compile/dispatch: which
-                    # axis blew the budget is unknowable from here, so
-                    # every axis currently above base falls back one
-                    # rung and its abandoned rungs (and everything
-                    # above — bigger only) are poisoned. The shrink is
-                    # fits-checked against the restored snapshot (the
-                    # state we actually rewind to): a lower rung the
-                    # live events no longer fit would silently truncate
-                    # them — the exact loss this plane exists to prevent
-                    # — so an unfitting fallback corners into a loud
-                    # PressureAbort instead (migrate_queue's shrink
-                    # contract, ops/events.py).
-                    self.oom_fallbacks += 1
-                    self.last_error = f"{type(e).__name__}: {e}"
-                    restored = self._restore_timed(snap)
-                    lower_cap, lower_box = cap, budget
-                    if grown_cap:
-                        import jax.numpy as jnp
+        f = Flight(base, snap, gear, rung, cap, budget)
+        self._dispatch(f, state, dispatch)
+        return f
 
-                        from shadow_tpu.ops.events import migration_fits
+    def _dispatch(self, f: Flight, state, dispatch):
+        """One attempt of chunk `f` on `state`. A dispatch error is kept
+        on the flight for `settle` (an OOM of a grown program falls back
+        a rung there)."""
+        if self.test_scribble is not None:
+            state = self.test_scribble(state, f.attempts)
+        f.attempts += 1
+        f.t_disp = time.perf_counter()
+        f.comp0 = (
+            self.wall.pending_to("compile") if self.wall is not None else 0.0
+        )
+        f.out = f.error = None
+        try:
+            if self.rungctl is not None:
+                f.out = dispatch(state, f.gear, f.cap, f.budget, f.rung)
+            else:
+                f.out = dispatch(state, f.gear, f.cap, f.budget)
+        except (KeyboardInterrupt, SystemExit, PressureAbort, IntegrityAbort):
+            raise
+        except Exception as e:
+            f.error = e
 
-                        for rung in self._cap_ladder:
-                            if rung >= cap:
-                                self._cap_poisoned.add(rung)
-                        lower_cap = next(
-                            (
-                                r
-                                for r in sorted(self._cap_ladder, reverse=True)
-                                if r < cap
-                                and r not in self._cap_poisoned
-                                and bool(jnp.all(
-                                    migration_fits(restored.queue, r)
-                                ))
-                            ),
-                            None,
-                        )
-                        if lower_cap is None:
-                            self.aborted = True
-                            raise PressureAbort(
-                                f"pressure: cornered — grown program "
-                                f"failed at capacity {cap} "
-                                f"({self.last_error}) and the live events "
-                                f"no longer fit any usable lower rung "
-                                f"(shrinking would silently truncate them)"
-                            ) from e
-                    if grown_box:
-                        for rung in self._box_ladder:
-                            if rung >= budget:
-                                self._box_poisoned.add(rung)
-                        lower_box = max(
-                            r for r in self._box_ladder
-                            if r < budget and r not in self._box_poisoned
-                        )
-                    self._say(
-                        f"grown program failed at (cap={cap}, "
-                        f"outbox={budget}) ({self.last_error}); falling "
-                        f"back to (cap={lower_cap}, outbox={lower_box})"
-                    )
-                    state = self.migrate(restored, lower_cap, lower_box)
-                    cap, budget = lower_cap, lower_box
-                    snap = self._snap_timed(state)
-                    self._last_snap = snap
-                    continue
-                raise
-            if self.wall is not None and attempt_i > 1:
-                # a replay attempt's wall, minus whatever compile
-                # pipeline the regrown program just paid (that part is
-                # already bound for the compile span — moving it twice
-                # would double-count)
-                sec = time.perf_counter() - t_disp
-                sec -= self.wall.pending_to("compile") - comp0
-                self._wall_move("replay", sec)
-            if self.integrity_on:
-                # integrity arbitration FIRST: a violating attempt's
-                # other counters (shed/pressure) may themselves be
-                # scribbled — the attempt is discarded wholesale either
-                # way, so nothing below may act on it
-                ivd = violation_total(out) - iv0
-                if ivd > 0:
-                    sig = violation_signature(out)
-                    detail = describe_signature(sig)
-                    if iv_last_sig is not None and sig == iv_last_sig:
-                        # reproduced at the same round with the same
-                        # bitmask across a snapshot replay: the engine
-                        # deterministically violates its own invariant —
-                        # a real bug, never survivable
-                        self.aborted = True
-                        self.iv_deterministic = {
-                            "signature": [list(s) for s in sig],
-                            "detail": detail,
-                        }
-                        self.last_error = (
-                            f"deterministic integrity violation: {detail}"
-                        )
-                        raise IntegrityAbort(
-                            f"integrity: violation REPRODUCED across a "
-                            f"snapshot replay (deterministic engine bug, "
-                            f"not SDC) — {detail}"
-                        )
-                    if iv_last_sig is not None:
-                        # the previous violation did not reproduce at
-                        # its signature: transient SDC, counted
-                        self.iv_transients += 1
-                    iv_last_sig = sig
-                    iv_attempts += 1
-                    if iv_attempts > self.integrity.max_replays:
-                        # cornered WITHOUT dispatching another replay:
-                        # iv_replays counts replays that actually ran
-                        # (iv_attempts - 1 here), not this refusal
-                        self.aborted = True
-                        self.iv_deterministic = {
-                            "signature": [list(s) for s in sig],
-                            "detail": detail,
-                            "nonreproducing": True,
-                        }
-                        self.last_error = (
-                            f"integrity violations persist without "
-                            f"reproducing after {iv_attempts - 1} "
-                            f"replays; last: {detail}"
-                        )
-                        raise IntegrityAbort(
-                            f"integrity: cornered — {self.last_error}"
-                        )
-                    self.iv_replays += 1
-                    self._say(
-                        f"invariant violation ({detail}); restoring "
-                        f"pre-chunk snapshot and replaying to classify "
-                        f"(attempt {iv_attempts}/"
-                        f"{self.integrity.max_replays})"
-                    )
-                    state = self._restore_timed(snap)
-                    continue
-                if iv_last_sig is not None:
-                    # the replay came back clean: the violation was
-                    # transient SDC — counted, logged, survived
-                    self.iv_transients += 1
-                    self._say(
-                        "transient SDC survived: the violation did not "
-                        "reproduce on replay; continuing with the clean "
-                        "chunk"
-                    )
-                    iv_last_sig = None
-            # one read for the abort and both fits
-            lanes = [out.stats.gear_shed, out.stats.outbox_hwm]
-            if rungctl is not None:
-                lanes.append(out.stats.a2a_pair_hwm)
-            with span_or_null(None, "sync"):
-                got = [int(np.asarray(a).max()) for a in jax.device_get(lanes)]
-            shed_now, ob_hwm = got[0], got[1]
-            pair_hwm = got[2] if rungctl is not None else 0
-            if shed_now > shed0:
-                # the discarded attempt's high-waters name the burst
-                # that shed it and the axis it shed on (read BEFORE the
-                # restore)
-                gear_shed = gearctl is not None and ob_hwm > gear
-                rung_shed = rungctl is not None and pair_hwm > rung
-                if not (gear_shed or rung_shed):
-                    raise RuntimeError(
-                        f"chunk shed at gear {gear}, rung {rung} with "
-                        f"outbox high-water {ob_hwm} and per-pair "
-                        f"high-water {pair_hwm} inside both"
-                    )
-                if gear_shed:
-                    gear = gearctl.note_shed(ob_hwm)
-                if rung_shed:
-                    rung = rungctl.note_shed(pair_hwm)
-                state = self._restore_timed(snap)
-                continue
-            if pressured:
-                delta = self._pressure_total(out) - press0
-                if delta > 0:
-                    if self.abort_on_drop:
-                        # honest stop AT the drop: the exported state
-                        # includes the dropping round, counters and all
-                        self.aborted = True
-                        self._abort_state = out
-                        self.raise_if_dropped(out, cats0)
-                    state, gear, cap, budget, snap = self._escalate_replay(
-                        out, cats0, snap, gear, cap, budget
-                    )
-                    continue
-            break
-        state = out
-        hwm = ob_hwm
-        self.ob_hwm_run = max(self.ob_hwm_run, hwm)
-        advanced = rounds0 is None or int(state.stats.rounds) > rounds0
-        if gearctl is not None and advanced:
-            gearctl.note_chunk(gear, hwm)
+    def reset_hwm(self, state):
+        """Zero the per-chunk high-waters the controllers read (a running
+        max could never signal a downshift); runs on every chunk output
+        before the next chunk takes it."""
         stats = state.stats._replace(outbox_hwm=state.stats.outbox_hwm * 0)
-        if rungctl is not None:
-            if advanced:
-                rungctl.note_chunk(rung, pair_hwm)
+        if self.rungctl is not None:
             stats = stats._replace(a2a_pair_hwm=stats.a2a_pair_hwm * 0)
-        state = state._replace(stats=stats)
-        if self.escalate:
-            state = self._proactive(state, hwm)
-        self._last_snap = None
-        # the gear this chunk was ACCEPTED at — note_chunk above may have
-        # already moved the controller for the NEXT chunk (heartbeats and
-        # gear histograms pair against what actually ran)
-        return state, gear, hwm
+        return state._replace(stats=stats)
 
-    def _escalate_replay(self, aborted, cats0, snap, gear, cap, budget):
+    def settle(self, f: Flight, s, dispatch) -> bool:
+        """Arbitrate chunk `f`'s current attempt from its summary `s` (None
+        after a dispatch error): True when the attempt was discarded and
+        the chunk re-dispatched from its pre-chunk snapshot (the caller
+        reads the new attempt), False when it is accepted. Raises
+        PressureAbort / IntegrityAbort where a policy stops the run."""
+        self._last_snap = f.snap
+        if f.error is not None:
+            if not self._oom_fallback(f, dispatch):
+                raise f.error
+            return True
+        base = f.base.read()
+        if self.wall is not None and f.attempts > 1:
+            # a replay attempt's wall, minus whatever compile pipeline
+            # the regrown program just paid (that part is already bound
+            # for the compile span — moving it twice would double-count)
+            sec = time.perf_counter() - f.t_disp
+            sec -= self.wall.pending_to("compile") - f.comp0
+            self._wall_move("replay", sec)
+        if self.integrity_on:
+            # integrity arbitration FIRST: a violating attempt's other
+            # counters (shed/pressure) may themselves be scribbled — the
+            # attempt is discarded wholesale either way, so nothing below
+            # may act on it
+            if s.iv_total > base.iv_total:
+                self._violation_replay(f, s.iv_signature, dispatch)
+                return True
+            if f.iv_sig is not None:
+                # the replay came back clean: the violation was
+                # transient SDC — counted, logged, survived
+                self.iv_transients += 1
+                self._say(
+                    "transient SDC survived: the violation did not "
+                    "reproduce on replay; continuing with the clean chunk"
+                )
+                f.iv_sig = None
+        if s.gear_shed > base.gear_shed:
+            # the discarded attempt's high-waters name the burst that
+            # shed it and the axis it shed on
+            gearctl, rungctl = self.gearctl, self.rungctl
+            gear_shed = gearctl is not None and s.outbox_hwm > f.gear
+            rung_shed = rungctl is not None and s.a2a_pair_hwm > f.rung
+            if not (gear_shed or rung_shed):
+                raise RuntimeError(
+                    f"chunk shed at gear {f.gear}, rung {f.rung} with "
+                    f"outbox high-water {s.outbox_hwm} and per-pair "
+                    f"high-water {s.a2a_pair_hwm} inside both"
+                )
+            if gear_shed:
+                f.gear = gearctl.note_shed(s.outbox_hwm)
+            if rung_shed:
+                f.rung = rungctl.note_shed(s.a2a_pair_hwm)
+            self._dispatch(f, self.restore(f.snap), dispatch)
+            return True
+        if self.pressure is not None and s.pressure_total > base.pressure_total:
+            if self.abort_on_drop:
+                # honest stop AT the drop: the exported state includes
+                # the dropping round, counters and all (the abort policy
+                # runs at lag 0, so the attempt's output is intact)
+                self.aborted = True
+                self._abort_state = f.out
+                raise self._drop_abort(s, base.cats)
+            self._escalate_replay(f, s.cats, base.cats, dispatch)
+            return True
+        return False
+
+    def accept(self, f: Flight, s, rounds0=None):
+        """Chunk `f` is accepted with summary `s`: fold its high-waters
+        into the controllers (which may move them for the next chunk)."""
+        hwm = s.outbox_hwm
+        self.ob_hwm_run = max(self.ob_hwm_run, hwm)
+        advanced = rounds0 is None or s.rounds > rounds0
+        if advanced:
+            if self.gearctl is not None:
+                self.gearctl.note_chunk(f.gear, hwm)
+            if self.rungctl is not None:
+                self.rungctl.note_chunk(f.rung, s.a2a_pair_hwm)
+        self._last_snap = None
+
+    def _violation_replay(self, f: Flight, sig: tuple, dispatch):
+        """An attempt tripped the integrity guards: a violation that
+        reproduces at the same (shard, round, bitmask) signature across a
+        snapshot replay is a deterministic engine bug (IntegrityAbort);
+        one that does not is transient SDC. Replays at the same shape."""
+        detail = describe_signature(sig)
+        if f.iv_sig is not None and sig == f.iv_sig:
+            self.aborted = True
+            self.iv_deterministic = {
+                "signature": [list(x) for x in sig],
+                "detail": detail,
+            }
+            self.last_error = f"deterministic integrity violation: {detail}"
+            raise IntegrityAbort(
+                f"integrity: violation REPRODUCED across a snapshot replay "
+                f"(deterministic engine bug, not SDC) — {detail}"
+            )
+        if f.iv_sig is not None:
+            # the previous violation did not reproduce at its signature:
+            # transient SDC, counted
+            self.iv_transients += 1
+        f.iv_sig = sig
+        f.iv_attempts += 1
+        if f.iv_attempts > self.integrity.max_replays:
+            # cornered WITHOUT dispatching another replay: iv_replays
+            # counts replays that actually ran (iv_attempts - 1 here),
+            # not this refusal
+            self.aborted = True
+            self.iv_deterministic = {
+                "signature": [list(x) for x in sig],
+                "detail": detail,
+                "nonreproducing": True,
+            }
+            self.last_error = (
+                f"integrity violations persist without reproducing after "
+                f"{f.iv_attempts - 1} replays; last: {detail}"
+            )
+            raise IntegrityAbort(f"integrity: cornered — {self.last_error}")
+        self.iv_replays += 1
+        self._say(
+            f"invariant violation ({detail}); restoring pre-chunk snapshot "
+            f"and replaying to classify (attempt {f.iv_attempts}/"
+            f"{self.integrity.max_replays})"
+        )
+        self._dispatch(f, self.restore(f.snap), dispatch)
+
+    def _oom_fallback(self, f: Flight, dispatch) -> bool:
+        """A GROWN program could not compile/dispatch: which axis blew
+        the budget is unknowable from here, so every axis currently above
+        base falls back one rung and its abandoned rungs (and everything
+        above — bigger only) are poisoned, and the chunk is re-dispatched
+        from its snapshot. False where the error is not such an OOM. The
+        shrink is fits-checked against the restored snapshot (the state
+        we actually rewind to): a lower rung the live events no longer
+        fit would silently truncate them — the exact loss this plane
+        exists to prevent — so an unfitting fallback corners into a loud
+        PressureAbort instead (migrate_queue's shrink contract,
+        ops/events.py)."""
+        e, cap, budget = f.error, f.cap, f.budget
+        grown_cap = self.escalate and cap > self._cap_ladder[0]
+        grown_box = self.escalate and budget > self._box_ladder[0]
+        if not ((grown_cap or grown_box) and _is_oom(e)):
+            return False
+        self.oom_fallbacks += 1
+        self.last_error = f"{type(e).__name__}: {e}"
+        restored = self.restore(f.snap)
+        lower_cap, lower_box = cap, budget
+        if grown_cap:
+            import jax.numpy as jnp
+
+            from shadow_tpu.ops.events import migration_fits
+
+            for rung in self._cap_ladder:
+                if rung >= cap:
+                    self._cap_poisoned.add(rung)
+            lower_cap = next(
+                (
+                    r
+                    for r in sorted(self._cap_ladder, reverse=True)
+                    if r < cap
+                    and r not in self._cap_poisoned
+                    and bool(jnp.all(migration_fits(restored.queue, r)))
+                ),
+                None,
+            )
+            if lower_cap is None:
+                self.aborted = True
+                raise PressureAbort(
+                    f"pressure: cornered — grown program failed at "
+                    f"capacity {cap} ({self.last_error}) and the live "
+                    f"events no longer fit any usable lower rung "
+                    f"(shrinking would silently truncate them)"
+                ) from e
+        if grown_box:
+            for rung in self._box_ladder:
+                if rung >= budget:
+                    self._box_poisoned.add(rung)
+            lower_box = max(
+                r for r in self._box_ladder
+                if r < budget and r not in self._box_poisoned
+            )
+        self._say(
+            f"grown program failed at (cap={cap}, outbox={budget}) "
+            f"({self.last_error}); falling back to (cap={lower_cap}, "
+            f"outbox={lower_box})"
+        )
+        self._reseat(f, self.migrate(restored, lower_cap, lower_box), dispatch)
+        return True
+
+    def _reseat(self, f: Flight, state, dispatch):
+        """Re-dispatch chunk `f` on a migrated `state`, with a fresh
+        snapshot at the new shape."""
+        f.cap = state.queue.t.shape[1]
+        f.budget = state.outbox.t.shape[1]
+        f.snap = self._snap_timed(state)
+        self._last_snap = f.snap
+        self._dispatch(f, state, dispatch)
+
+    def _escalate_replay(self, f: Flight, cats, cats0, dispatch):
         """A chunk attempt dropped: pick the grown shape from the aborted
         attempt's per-category deltas, restore the pre-chunk snapshot,
-        migrate, and hand the loop the new shape. Raises PressureAbort
-        when cornered (a dropping axis cannot grow)."""
-        cats = self._pressure_categories(aborted)
+        migrate, and replay the chunk at the new shape. Raises
+        PressureAbort when cornered (a dropping axis cannot grow)."""
+        cap, budget = f.cap, f.budget
         queue_side = cats["queue"] > cats0["queue"]
         box_side = (
             cats["budget"] > cats0["budget"]
@@ -696,10 +667,11 @@ class ResilienceController:
             f"capacity drop at (cap={cap}, outbox={budget}); replaying "
             f"chunk at (cap={new_cap}, outbox={new_budget})"
         )
-        state = self.migrate(self._restore_timed(snap), new_cap, new_budget)
-        snap = self._snap_timed(state)
-        self._last_snap = snap
-        return state, gear, new_cap, new_budget, snap
+        self._reseat(
+            f,
+            self.migrate(self.restore(f.snap), new_cap, new_budget),
+            dispatch,
+        )
 
     def _admit_or_corner(self, cap, budget, new_cap, new_budget):
         """Memory-informed pre-dispatch admission (obs/memory.MemoryGuard):
@@ -780,23 +752,24 @@ class ResilienceController:
             )
         return cap, budget
 
-    def _proactive(self, state, chunk_hwm: int):
-        """Boundary regrow BEFORE anything drops: the always-on
-        occupancy high-water crossing the headroom threshold grows the
-        queue; a chunk whose outbox high-water FILLED the budget grows
-        the outbox (hwm == budget means one more send next chunk would
-        be a budget drop — the gear controller's exactly-filled rule,
-        applied to the shape)."""
-        import jax
+    def proactive_shape(self, state, s):
+        """Boundary regrow BEFORE anything drops, decided from the
+        accepted chunk's summary `s`: the always-on occupancy high-water
+        crossing the headroom threshold grows the queue; a chunk whose
+        outbox high-water FILLED the budget grows the outbox (hwm ==
+        budget means one more send next chunk would be a budget drop —
+        the gear controller's exactly-filled rule, applied to the shape).
+        Returns the (capacity, budget) the next chunk's input migrates
+        to, or None. `state` is that input (its shapes only are read)."""
         import math
 
         p = self.pressure
-        if not p.headroom:
-            return state
+        if not self.escalate or not p.headroom:
+            return None
         cap = state.queue.t.shape[1]
         budget = state.outbox.t.shape[1]
         new_cap, new_budget = cap, budget
-        occ = int(np.asarray(jax.device_get(state.stats.q_occ_hwm)).max())
+        occ, chunk_hwm = s.q_occ_hwm, s.outbox_hwm
         if occ >= math.ceil(p.headroom * cap):
             up = self._next_rung(self._cap_ladder, cap, self._cap_poisoned)
             if up is not None:
@@ -808,15 +781,14 @@ class ResilienceController:
         new_cap, new_budget = self._admitted_proactive(
             cap, budget, new_cap, new_budget
         )
-        if (new_cap, new_budget) != (cap, budget):
-            self.proactive_regrows += 1
-            self._say(
-                f"proactive regrow: occupancy hwm {occ}/{cap}, outbox hwm "
-                f"{chunk_hwm}/{budget} -> (cap={new_cap}, "
-                f"outbox={new_budget})"
-            )
-            state = self.migrate(state, new_cap, new_budget)
-        return state
+        if (new_cap, new_budget) == (cap, budget):
+            return None
+        self.proactive_regrows += 1
+        self._say(
+            f"proactive regrow: occupancy hwm {occ}/{cap}, outbox hwm "
+            f"{chunk_hwm}/{budget} -> (cap={new_cap}, outbox={new_budget})"
+        )
+        return new_cap, new_budget
 
     # ---- abort/export ------------------------------------------------------
 

@@ -639,6 +639,7 @@ class Simulation:
         # the first run() and kept, so later runs start at its rung
         self._rungctl = None
         self._pressctl = None  # ResilienceController when pressure is active
+        self._pipeline = None  # the last run()'s core/pipeline.ChunkPipeline
         self._ob_hwm_run = 0  # run-wide outbox high-water (gear runs reset
         # the device counter per chunk, so the run max is tracked here)
         mesh = None
@@ -918,24 +919,29 @@ class Simulation:
             )
             self._supervisor = sup
             sup.note_state(self.state)
-        last_gear = None
         chunks = 0
+        from shadow_tpu.core.pipeline import ChunkPipeline
 
-        def _chunk_step(st):
-            nonlocal last_gear
-            if resilience is not None:
-                st, lg, hwm = resilience.run_chunk(
-                    st,
-                    lambda s, g, c, b, k=0: self.engine.run_chunk_resized(
-                        s, self.params, g, c, b, k
-                    ),
-                )
-                last_gear = lg if gearctl is not None else None
-                self._ob_hwm_run = max(self._ob_hwm_run, hwm)
-                if rungctl is not None:
-                    self.engine.select_a2a_rung(rungctl.rung)
-                return st
-            return self.engine.run_chunk(st, self.params)
+        if resilience is not None:
+            def dispatch(s, g, c, b, k=0):
+                return self.engine.run_chunk_resized(s, self.params, g, c, b, k)
+        else:
+            def dispatch(s):
+                return self.engine.run_chunk(s, self.params)
+        # lag 1 (the next chunk queued before this one's summary is
+        # read) unless something between chunks reads the whole state:
+        # then lag 0, the same loop with the read before the dispatch
+        whole_state = (
+            capture is not None or sup is not None or tracer is not None
+            or flowcol is not None or monitor is not None
+            or wallled is not None
+            or (pressure_on and cfg.pressure.policy == "abort")
+        )
+        pipe = ChunkPipeline(
+            dispatch, self.engine.chunk_summary, seam=resilience,
+            lag=0 if whole_state else 1,
+        )
+        self._pipeline = pipe
 
         def _policy_abort(e, t_chunk, kind="pressure"):
             # a policy stopped the run. Pressure-abort exports the
@@ -981,40 +987,23 @@ class Simulation:
         from shadow_tpu.core.integrity import IntegrityAbort
         from shadow_tpu.core.pressure import PressureAbort
 
-        def _heartbeat(now_ns, wall):
-            # the stat reads are device-to-host copies made between two
-            # dispatches (traced as `shadow.heartbeat`)
-            ev = int(np.asarray(self.state.stats.events).sum())
-            # event-density telemetry (the K-way microstep's target
-            # quantities): microsteps per round is how serialized the
-            # round loop is, events per microstep is how well the
-            # K-fold amortizes — the same two numbers bench.py tracks
-            msteps = int(np.asarray(self.state.stats.microsteps).sum())
-            rounds = int(self.state.stats.rounds)
-            ici = int(np.asarray(self.state.stats.ici_bytes).sum())
-            qhwm = int(np.asarray(self.state.stats.q_occ_hwm).max())
+        def _heartbeat(s, wall):
+            # every number but the host's own comes from the accepted
+            # chunk's summary, read once for the whole boundary
+            ecfg = self.engine_cfg
             # faults= rides along only when the fault plane is
             # active, gear= only on adaptive runs (old-format
             # lines stay byte-identical; parse_shadow reads both)
             # xw= rides along only on hierarchical-exchange runs:
             # cumulative (intra compaction, inter wire) tier bytes
-            xw = None
-            if self.engine_cfg.hier_active:
-                xw = (
-                    int(np.asarray(self.state.stats.ici_intra).sum()),
-                    int(np.asarray(self.state.stats.ici_inter).sum()),
-                )
-            fault = None
-            if self.engine_cfg.faults_active:
-                fd = int(np.asarray(self.state.stats.faults_dropped).sum())
-                fy = int(np.asarray(self.state.stats.faults_delayed).sum())
-                fault = (fd, fy)
+            xw = (s.ici_intra, s.ici_inter) if ecfg.hier_active else None
+            fault = (
+                (s.faults_dropped, s.faults_delayed)
+                if ecfg.faults_active else None
+            )
             # cap= rides along only on pressure-plane runs (the
             # ACTIVE capacity — escalation regrows it mid-run)
-            cap = (
-                self.state.queue.t.shape[1]
-                if pressure_on else None
-            )
+            cap = self.state.queue.t.shape[1] if pressure_on else None
             # hbm= rides along only on memory-observatory runs:
             # the per-shard HBM high-water so far (bytes)
             hbm = (
@@ -1023,27 +1012,14 @@ class Simulation:
             # ek= (timer/packet event counts) and fct= (flows
             # completed) ride along only on network-observatory
             # runs (fct only when a flow ledger is active)
-            ek = fct = None
-            if self.engine_cfg.netobs:
-                ek = (
-                    int(np.asarray(self.state.stats.ec_timer).sum()),
-                    int(np.asarray(self.state.stats.ec_pkt).sum()),
-                )
-                if self.engine_cfg.flow_ledger_active:
-                    fct = int(
-                        np.asarray(self.state.stats.fl_done).sum()
-                    )
+            ek = (s.ec_timer, s.ec_pkt) if ecfg.netobs else None
+            fct = s.fl_done if ecfg.flow_ledger_active else None
             # bg= rides along only on fluid-traffic-plane runs:
             # cumulative background bytes delivered/dropped
-            # (replicated scalars — read, never summed)
-            bg = None
-            if self.engine_cfg.fluid_active:
-                bg = (
-                    int(np.asarray(self.state.stats.fl_bg_bytes)),
-                    int(np.asarray(
-                        self.state.stats.fl_bg_dropped
-                    )),
-                )
+            bg = (
+                (s.fl_bg_bytes, s.fl_bg_dropped)
+                if ecfg.fluid_active else None
+            )
             # iv= rides along only on integrity-sentinel runs:
             # (transient SDC survived, sentinel replays) so far
             iv = (
@@ -1055,28 +1031,30 @@ class Simulation:
             rt = (
                 wallled.rt_last if wallled is not None else None
             )
+            gear = pipe.gear if gearctl is not None else None
             print(
                 heartbeat_line(
-                    now_ns, wall, ev, msteps, rounds, ici, qhwm,
-                    xw=xw, fault=fault, gear=last_gear, cap=cap,
+                    s.now, wall, s.events, s.microsteps, s.rounds,
+                    s.ici_bytes, s.q_occ_hwm,
+                    xw=xw, fault=fault, gear=gear, cap=cap,
                     hbm=hbm, ek=ek, fct=fct, bg=bg, iv=iv, rt=rt,
                 ),
                 file=log,
             )
             if simlog is not None:
                 simlog.info(
-                    now_ns, "manager",
-                    f"heartbeat events={ev} "
-                    f"rounds={int(self.state.stats.rounds)}",
+                    s.now, "manager",
+                    f"heartbeat events={s.events} rounds={s.rounds}",
                 )
 
+        s = None  # the summary of the last accepted chunk
         try:
             with span_or_null(wallled, "run"):
                 while True:
                     with chunk_span(chunks):
-                        with span_or_null(wallled, "sync"):
-                            done = bool(self.state.done)
-                        if done:
+                        if s is None:
+                            s = pipe.start(self.state)
+                        if s.done:
                             break
                         t_chunk = time.monotonic()
                         if wallled is not None:
@@ -1090,6 +1068,7 @@ class Simulation:
                                     jax.block_until_ready(self.state)
                             with span_or_null(wallled, "export"):
                                 capture.write_round(sent)
+                            s = pipe.start(self.state)
                         elif sup is not None:
                             from shadow_tpu.core.supervisor import (
                                 SupervisorAbort,
@@ -1098,8 +1077,9 @@ class Simulation:
                             try:
                                 with span_or_null(wallled, "dispatch"):
                                     self.state = sup.run_chunk(
-                                        self.state, _chunk_step
+                                        self.state, pipe.run_one
                                     )
+                                s = pipe.last
                             except IntegrityAbort as e:
                                 _policy_abort(e, t_chunk, kind="integrity")
                                 break
@@ -1149,18 +1129,28 @@ class Simulation:
                         else:
                             try:
                                 with span_or_null(wallled, "dispatch"):
-                                    self.state = _chunk_step(self.state)
+                                    pipe.launch()
                                     if wallled is not None:
-                                        # async dispatch: without the
-                                        # block the device time would leak
-                                        # into whichever span syncs first
-                                        jax.block_until_ready(self.state)
+                                        # the observatory's dispatch span
+                                        # holds the chunk's device time
+                                        # and the seam's replays (lag 0)
+                                        s = pipe.settle()
+                                        jax.block_until_ready(pipe.state)
+                                if wallled is None:
+                                    s = pipe.settle()
+                                self.state = pipe.state
                             except IntegrityAbort as e:
                                 _policy_abort(e, t_chunk, kind="integrity")
                                 break
                             except PressureAbort as e:
                                 _policy_abort(e, t_chunk)
                                 break
+                        if resilience is not None:
+                            self._ob_hwm_run = max(
+                                self._ob_hwm_run, resilience.ob_hwm_run
+                            )
+                            if rungctl is not None:
+                                self.engine.select_a2a_rung(rungctl.rung)
                         with span_or_null(wallled, "export"):
                             if tracer is not None:
                                 # pair the drained rounds with the true
@@ -1186,8 +1176,7 @@ class Simulation:
                                 if tracer is not None:
                                     tracer.note_memory(t_s, shard_bytes)
                         chunks += 1
-                        with span_or_null(wallled, "sync"):
-                            now_ns = int(self.state.now)
+                        now_ns = s.now
                         if wallled is not None:
                             # close the chunk (heartbeat/progress
                             # printing below lands in the NEXT chunk's
@@ -1196,7 +1185,7 @@ class Simulation:
                         wall = time.monotonic() - t0
                         if hb_ns and now_ns >= next_hb:
                             with span_or_null(wallled, "heartbeat"):
-                                _heartbeat(now_ns, wall)
+                                _heartbeat(s, wall)
                             next_hb = (now_ns // hb_ns + 1) * hb_ns
                         if show_progress:
                             pct = min(100.0, 100.0 * now_ns / max(
@@ -1387,6 +1376,11 @@ class Simulation:
                     )[:n].sum()
                 ),
             }
+        if self._pipeline is not None:
+            # the driver loop's chunks, how many of them had their
+            # successor queued before they were read (lag 1), and the
+            # chunks in flight thrown away for a replay
+            report["chunk_pipeline"] = self._pipeline.report()
         if self._gearctl is not None:
             report["gears"] = self._gearctl.report()
         if self._rungctl is not None:

@@ -191,6 +191,52 @@ def test_too_small_start_rung_sheds_replays_and_matches(case, low_rungs):
     assert int(np.asarray(g.gear_shed).max()) == 0
 
 
+@pytest.mark.parametrize("case", sorted(_ENGINE_CASES))
+def test_too_small_start_rung_discards_in_flight_chunk_and_matches(
+    case, low_rungs
+):
+    """The same seating through the driver loop's pipeline at lag 1: the
+    successor is queued before a chunk's summary is read, so each shed
+    also discards the chunk in flight; the replays stay exact and the run
+    ends bit-identical to the top-rung run."""
+    _needs_mesh()
+    from shadow_tpu.core.pipeline import ChunkPipeline
+
+    model, hosts, stop, kw = _ENGINE_CASES[case]
+    _, eng, full, params = _build(model, hosts, stop, **kw)
+    while not bool(full.done):
+        full = eng.run_chunk(full, params)
+
+    cfg, eng, state, params = _build(model, hosts, stop, **kw)
+    ctl = BlockRungController(cfg.a2a_auto_block)
+    ctl.gear = ctl.ladder[0]
+    pipe = ChunkPipeline(
+        lambda s, g, c, b, k: eng.run_chunk_resized(s, params, g, c, b, k),
+        eng.chunk_summary,
+        seam=ResilienceController(rungctl=ctl),
+        lag=1,
+    )
+    s = pipe.start(state)
+    while not s.done:
+        s = pipe.advance()
+    state = pipe.state
+    rep = pipe.report()
+    assert ctl.replays >= 1 and rep["discarded"] >= 1
+    assert rep["ahead"] == rep["chunks"]
+    f, g = jax.device_get(full.stats), jax.device_get(state.stats)
+    for field in ("digest", "events", "pkts_sent", "pkts_lost",
+                  "pkts_delivered", "pkts_budget_dropped", "rounds"):
+        np.testing.assert_array_equal(
+            np.asarray(getattr(f, field)), np.asarray(getattr(g, field)),
+            err_msg=field,
+        )
+    np.testing.assert_array_equal(
+        np.asarray(jax.device_get(full.queue.dropped)),
+        np.asarray(jax.device_get(state.queue.dropped)),
+    )
+    assert int(np.asarray(g.gear_shed).max()) == 0
+
+
 def test_second_run_starts_at_carried_rung_and_compiles_nothing(low_rungs):
     """The controller lives on the Simulation: a second run() from the
     same initial state (the benchmark's replayed segment) starts where

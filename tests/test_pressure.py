@@ -331,6 +331,44 @@ def test_escalate_drop_free_and_bit_identical(case, k, qb):
     _assert_drop_free_and_identical(state, ref)
 
 
+@pytest.mark.parametrize("case", ["phold", "tgen"])
+def test_escalate_with_a_chunk_in_flight_matches_lag_zero(case):
+    """Escalation through the driver loop's pipeline at lag 1: a drop is
+    seen one chunk late, in the summary, with the successor already
+    queued; the successor is discarded, the dropping chunk replayed at
+    the grown shape, and the run equals the lag-0 seam's, shapes and
+    regrows included."""
+    from shadow_tpu.core.pipeline import ChunkPipeline
+
+    model, hosts, stop, kw = _CASES[case]
+    qcap0 = 4
+    cfg0, ref, rc0 = _run_escalated(model, hosts, stop, qcap=qcap0, **kw)
+    cfg, eng, state, params = _build(
+        model, hosts, stop, pressure_abort=True, qcap=qcap0, **kw
+    )
+    rc = ResilienceController(
+        pressure=PressureOptions(policy="escalate", max_capacity=256,
+                                 max_outbox=64),
+        queue_block=cfg.queue_block,
+    )
+    pipe = ChunkPipeline(
+        lambda s, g, c, b: eng.run_chunk_resized(s, params, g, c, b),
+        eng.chunk_summary, seam=rc, lag=1,
+    )
+    s = pipe.start(state)
+    while not s.done:
+        s = pipe.advance()
+        assert pipe.chunks < 500
+    state = pipe.state
+    assert rc.replays >= 1 and pipe.discarded >= 1
+    assert (rc.regrows, rc.proactive_regrows, rc.replays) == (
+        rc0.regrows, rc0.proactive_regrows, rc0.replays
+    )
+    assert state.queue.t.shape == ref.queue.t.shape
+    assert state.outbox.t.shape == ref.outbox.t.shape
+    _assert_drop_free_and_identical(state, ref)
+
+
 def test_escalate_mesh_invariant():
     """world=8 dryrun: the pressure signal is psum'd, so the first-drop
     abort is mesh-uniform, migration re-shards onto the mesh specs, and

@@ -131,3 +131,35 @@ def test_sharded_chunk_compiles_for_four_v5e(topo, no_cache, monkeypatch,
     cfg["experimental"]["exchange"] = exchange
     compiled = _compile(monkeypatch, cfg, 4, topo.devices)
     assert "all-to-all" in compiled.as_text()
+
+
+@pytest.mark.parametrize("world", [1, 4])
+def test_chunk_summary_compiles_for_v5e_without_collectives(topo, no_cache,
+                                                            world):
+    """The driver loop's per-chunk summary (`Engine.chunk_summary`) for
+    the described chips: a row of int64 numbers per shard, reduced on the
+    shard, so no collective (the TPU lowers no 64-bit max all-reduce) and
+    nothing crosses chips for it."""
+    import re
+
+    from jax.sharding import PartitionSpec as P
+
+    from shadow_tpu.core.pipeline import summary_row
+
+    cfg = _torus_config(2, side=2, per_node=8)
+    sim = Simulation(ConfigOptions.from_dict(cfg), world=world)
+    if world == 1:
+        fn = summary_row
+        one = SingleDeviceSharding(topo.devices[0])
+        args = jax.tree.map(lambda x: _shape(x, one), sim.state)
+    else:
+        mesh = Mesh(topo.devices[:world], (E.AXIS,))
+        specs = sim.engine.state_specs()
+        fn = E._shard_map(summary_row, mesh, (specs,), P(E.AXIS))
+        args = jax.tree.map(
+            lambda x, s: _shape(x, NamedSharding(mesh, s)), sim.state, specs
+        )
+    text = jax.jit(fn).lower(args).compile().as_text()
+    assert not re.search(
+        r"\b(all-reduce|all-gather|all-to-all|collective-permute)", text
+    )
